@@ -47,10 +47,12 @@ test:
 # enumerates the rules; suppress per line with
 # `# raftlint: disable=<rule> -- why`.
 vet:
-	$(PY) -m compileall -q raftsql_tpu tests bench.py __graft_entry__.py \
+	$(PY) -m compileall -q raftsql_tpu tests bench.py chip_smoke.py __graft_entry__.py \
 	      scripts
 	$(PY) scripts/vet.py
 
+# Needs a chip: an unpinned parent whose probe does not report a tpu
+# exits non-zero and prints no result (BENCH_PLATFORM=cpu = development).
 bench:
 	$(PY) bench.py
 
@@ -260,7 +262,7 @@ tsan:
 
 clean:
 	rm -f test.out flight-*.json raftsql_tpu/native/_native_*.so \
-	      raftsql_tpu/native/_wal_stress_* raftsql_tpu/native/_http_load
+	      raftsql_tpu/native/_wal_stress_* raftsql_tpu/native/_http_load*
 	find . -name __pycache__ -type d -exec rm -rf {} +
 
 # The durable product paths, quick local shapes (one JSON line each).

@@ -15,7 +15,7 @@ from __future__ import annotations
 # Default target set for `make vet` / `python -m raftsql_tpu.analysis`.
 # ---------------------------------------------------------------------
 DEFAULT_PATHS = ["raftsql_tpu", "scripts", "tests", "bench.py",
-                 "__graft_entry__.py"]
+                 "chip_smoke.py", "__graft_entry__.py"]
 
 # ---------------------------------------------------------------------
 # determinism: modules whose behavior feeds chaos/bench digests must

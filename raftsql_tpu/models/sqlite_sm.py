@@ -188,12 +188,15 @@ class SQLiteStateMachine:
         not carry.  Caller holds the lock; the mode flip checkpoints,
         which is fine at InstallSnapshot cadence.
 
-        `Connection.serialize` only exists on Python 3.11+; older
-        interpreters fall back to `VACUUM INTO` a temp file (SQLite ≥
-        3.27) — the vacuum output is always a standalone DELETE-mode
-        image, so no journal flip is needed on that path."""
-        if not hasattr(self._conn, "serialize"):
-            return self._vacuum_image()
+        A database nothing has been written to has NO pages, and
+        SQLite refuses to serialize it ("unable to serialize 'main'") —
+        which is every group's state at boot in parity mode.  An empty
+        write transaction allocates page 1 (the header and an empty
+        schema) without changing what any query sees, so the image is
+        a valid one-page database."""
+        if not self._conn.execute("PRAGMA page_count").fetchone()[0]:
+            self._conn.execute("BEGIN IMMEDIATE")
+            self._conn.execute("COMMIT")
         wal = self.has_durable_snapshot
         if wal:
             self._conn.execute("PRAGMA journal_mode=DELETE")
@@ -202,23 +205,6 @@ class SQLiteStateMachine:
         finally:
             if wal:
                 self._conn.execute("PRAGMA journal_mode=WAL")
-
-    def _vacuum_image(self) -> bytes:
-        """Point-in-time image via `VACUUM INTO` (the py3.10 fallback
-        for Connection.serialize): SQLite writes a consistent, compacted
-        copy of the whole database to a fresh file inside one internal
-        read transaction — the same snapshot guarantee serialize gives.
-        Caller holds the lock."""
-        import tempfile
-        d = tempfile.mkdtemp(prefix="raftsql-snap-")
-        target = os.path.join(d, "image.db")   # must not pre-exist
-        try:
-            self._conn.execute("VACUUM INTO ?", (target,))
-            with open(target, "rb") as f:
-                return f.read()
-        finally:
-            import shutil
-            shutil.rmtree(d, ignore_errors=True)
 
     def serialize(self) -> bytes:
         """Consistent point-in-time image of the database (the blob of an
@@ -264,28 +250,8 @@ class SQLiteStateMachine:
                             pass
                 finally:
                     self._conn = self._connect()
-            elif hasattr(self._conn, "deserialize"):
-                self._conn.deserialize(blob)
             else:
-                # py3.10 fallback (Connection.deserialize is 3.11+):
-                # land the image in a temp file and copy it over the
-                # live in-memory database with Connection.backup, which
-                # replaces the destination's entire content — the same
-                # all-state-swap contract deserialize gives.
-                import tempfile
-                d = tempfile.mkdtemp(prefix="raftsql-snap-")
-                tmp2 = os.path.join(d, "image.db")
-                try:
-                    with open(tmp2, "wb") as f:
-                        f.write(blob)
-                    src = sqlite3.connect(tmp2)
-                    try:
-                        src.backup(self._conn)
-                    finally:
-                        src.close()
-                finally:
-                    import shutil
-                    shutil.rmtree(d, ignore_errors=True)
+                self._conn.deserialize(blob)
             if self.resume:
                 self._conn.execute(
                     "CREATE TABLE IF NOT EXISTS _raft_meta "

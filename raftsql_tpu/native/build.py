@@ -1,16 +1,22 @@
 """Build + load the native components (ctypes, on-demand g++ compile).
 
 pybind11 is not available in this environment, so the native pieces expose
-a plain C ABI consumed through ctypes.  The shared object is compiled
-next to the source on first use and cached by source mtime; failures of
-any kind (no compiler, read-only checkout) degrade to the pure-Python
-implementations.
+a plain C ABI consumed through ctypes.  Each artifact is compiled next to
+its source on first use and NAMED BY A HASH OF ITS SOURCES
+(`_native_wal.<hash>.so`, `_http_load.<hash>`): an object can be loaded
+only if it was built from the source beside it.  Modification times
+prove nothing — a fresh checkout gives every file the same one, and a
+copied working tree carries ignored artifacts along.  Failures of any
+kind (no compiler, read-only checkout) degrade to the pure-Python
+implementations; `/healthz` says which one is serving (`native_wal`).
 
 Set RAFTSQL_TPU_NATIVE=0 to force the Python fallbacks.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -51,23 +57,46 @@ def _compile(src: str, dest: str, link_args: tuple) -> bool:
             os.unlink(tmp)
 
 
+def _artifact(stem: str, srcs: list, flags: tuple, suffix: str = ""):
+    """Path of `stem`'s artifact built from `srcs` (first is the
+    translation unit handed to g++) with `flags`, compiling it when no
+    object of exactly these sources exists; None when the build is
+    unavailable.  The name carries a hash of the sources and flags, so
+    a stale object beside changed source is never opened; superseded
+    objects of the same stem are unlinked after a successful build."""
+    h = hashlib.sha256(repr(flags).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    path = os.path.join(_DIR, f"{stem}.{h.hexdigest()[:16]}{suffix}")
+    if os.path.isfile(path):
+        return path
+    if not _compile(srcs[0], path, flags):
+        return None
+    for old in glob.glob(os.path.join(_DIR, f"{stem}.*{suffix}")):
+        if old != path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return path
+
+
 def _load(name: str):
-    """Compile (if stale) and dlopen native/<name>.cc -> CDLL or None."""
+    """Compile (if no object of this source exists) and dlopen
+    native/<name>.cc -> CDLL or None."""
     if os.environ.get("RAFTSQL_TPU_NATIVE", "1") == "0":
         return None
     with _lock:
         if name in _cache:
             return _cache[name]
-        src = os.path.join(_DIR, f"{name}.cc")
-        so = os.path.join(_DIR, f"_native_{name}.so")
         lib = None
         try:
-            if not os.path.isfile(so) or \
-                    os.path.getmtime(so) < os.path.getmtime(src):
-                if not _compile(src, so, ("-shared", "-fPIC")):
-                    _cache[name] = None
-                    return None
-            lib = ctypes.CDLL(so)
+            so = _artifact(f"_native_{name}",
+                           [os.path.join(_DIR, f"{name}.cc")],
+                           ("-shared", "-fPIC"), suffix=".so")
+            if so is not None:
+                lib = ctypes.CDLL(so)
         except OSError as e:
             log.warning("native %s load failed (%s); Python fallback",
                         name, e)
@@ -83,17 +112,12 @@ def build_http_load():
     client threads)."""
     if os.environ.get("RAFTSQL_TPU_NATIVE", "1") == "0":
         return None
-    src = os.path.join(_DIR, "http_load.cc")
-    exe = os.path.join(_DIR, "_http_load")
     with _lock:
         if "http_load" in _cache:
             return _cache["http_load"]
-        path = exe
         try:
-            if not os.path.isfile(exe) or \
-                    os.path.getmtime(exe) < os.path.getmtime(src):
-                if not _compile(src, exe, ()):
-                    path = None
+            path = _artifact("_http_load",
+                             [os.path.join(_DIR, "http_load.cc")], ())
         except OSError as e:
             log.warning("http_load build unavailable (%s)", e)
             path = None
@@ -125,20 +149,13 @@ def build_wal_stress(sanitizer: str):
     flags = SANITIZERS[sanitizer]
     srcs = [os.path.join(_DIR, "wal_stress.cc"),
             os.path.join(_DIR, "wal.cc")]
-    exe = os.path.join(_DIR, f"_wal_stress_{sanitizer}")
     with _lock:
         key = f"wal_stress_{sanitizer}"
         if key in _cache:
             return _cache[key]
-        path = exe
         try:
-            stale = not os.path.isfile(exe) or any(
-                os.path.getmtime(exe) < os.path.getmtime(s)
-                for s in srcs)
-            if stale and not _compile(
-                    srcs[0], exe,
-                    ("-O1", "-g", *flags, "-fPIC", srcs[1])):
-                path = None
+            path = _artifact(f"_wal_stress_{sanitizer}", srcs,
+                             ("-O1", "-g", *flags, "-fPIC", srcs[1]))
         except OSError as e:
             log.warning("wal_stress %s build unavailable (%s)",
                         sanitizer, e)

@@ -33,13 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases; this
-# container pins an older jax, so resolve whichever spelling exists.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                              # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from raftsql_tpu.config import RaftConfig
 from raftsql_tpu.core.state import I32, Inbox, PeerState, StepInfo
 from raftsql_tpu.core.step import peer_step
@@ -178,6 +171,11 @@ def make_sharded_step_fn(cfg: RaftConfig, mesh: Mesh):
     return _step
 
 
+def prop_spec() -> P:
+    """PartitionSpec of the [P, G] per-tick proposal counts."""
+    return _spec2()
+
+
 def timer_spec() -> P:
     """PartitionSpec of the [P] per-peer timer advance vector: sharded
     with the owner-peer axis, replicated over groups."""
@@ -198,7 +196,7 @@ def make_sharded_cluster_step(cfg: RaftConfig, mesh: Mesh):
         return step(states, inboxes, prop_n,
                     jnp.ones((step.p_loc,), I32))
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         _lockstep, mesh=mesh,
         in_specs=(state_specs(), inbox_specs(), _spec2()),
         out_specs=(state_specs(), inbox_specs(), info_specs()))
@@ -236,7 +234,7 @@ def make_sharded_cluster_step_host(cfg: RaftConfig, mesh: Mesh):
             GROUPS_AXIS) > 0
         return states, ib, jax.vmap(pack_info)(infos), busy
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         _step, mesh=mesh,
         in_specs=(state_specs(), inbox_specs(), _spec2(), timer_spec()),
         out_specs=(state_specs(), inbox_specs(),
@@ -276,7 +274,7 @@ def make_sharded_cluster_run(cfg: RaftConfig, mesh: Mesh, num_ticks: int):
         return states, inboxes, total
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             _run, mesh=mesh,
             in_specs=(state_specs(), inbox_specs(),
                       P(None, PEERS_AXIS, GROUPS_AXIS)),

@@ -202,6 +202,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="",
                     help="write the result doc here (default stdout)")
     args = ap.parse_args(argv)
+    from raftsql_tpu.utils.device import select_device
+    select_device()         # JAX_PLATFORMS, or an accelerator — never
+    #                         a silent CPU (raftsql_tpu/utils/device.py)
     doc = run_equiv(args) if args.mode == "equiv" else run_bench(args)
     blob = json.dumps(doc, sort_keys=True)
     if args.out:

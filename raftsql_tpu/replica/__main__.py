@@ -16,15 +16,21 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 import sys
-
-from raftsql_tpu.replica.node import ReplicaDB, ReplicaSubscriber
-from raftsql_tpu.replica.stream import parse_hostport
+import threading
 
 log = logging.getLogger("raftsql.replica")
 
 
 def main(argv=None) -> int:
+    # A replica computes nothing on a device and may share a host with
+    # the engine, which owns the chip: set (not setdefault) the CPU
+    # platform before anything imports jax.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    from raftsql_tpu.replica.node import ReplicaDB, ReplicaSubscriber
+    from raftsql_tpu.replica.stream import parse_hostport
+
     ap = argparse.ArgumentParser(
         prog="python -m raftsql_tpu.replica",
         description="read replica: stream subscriber + HTTP read plane")
@@ -72,7 +78,7 @@ def main(argv=None) -> int:
         from raftsql_tpu.api.http import SQLServer
         srv = SQLServer(args.port, rdb, host=args.host,
                         timeout_s=args.timeout)
-    _install_graceful_shutdown(rdb, srv.stop)
+    _install_graceful_shutdown(rdb, srv.stop, threading.Event())
     log.info("replica serving on :%d (upstream %s, %s plane)",
              args.port, args.upstream, args.http_engine)
     srv.serve_forever()

@@ -1,16 +1,15 @@
 """Snapshot fork: split one SQLite image into two disjoint shards.
 
 A SPLIT freezes intake on the source group, takes a consistent image
-(`SQLiteStateMachine.serialize`, which already handles the py3.10
-`VACUUM INTO` fallback), and forks it by hash slot: every row of the
-keyed table whose key hashes into the moving slot set goes to the new
-group's image, the rest stay.  The two outputs are real standalone
+(`SQLiteStateMachine.serialize`), and forks it by hash slot: every row
+of the keyed table whose key hashes into the moving slot set goes to the
+new group's image, the rest stay.  The two outputs are real standalone
 SQLite files whose keyed-row union is exactly the source — the
 disjoint-union property tests/test_reshard.py pins.
 
-The fork works purely through file-backed connections and an ATTACHed
-source, so it runs identically on py3.10 (no Connection.serialize /
-deserialize) and newer interpreters.
+The fork works through file-backed connections and an ATTACHed source:
+the row filter is one SQL statement per table per side, and the outputs
+are read back as plain files.
 """
 from __future__ import annotations
 

@@ -44,6 +44,7 @@ from raftsql_tpu.transport.codec import is_conf_entry
 from raftsql_tpu.runtime.node import (CLOSED, RAW_BATCH, RAW_MANY,
                                       RAW_PLAIN)
 from raftsql_tpu.runtime.pipe import RaftPipe
+from raftsql_tpu.utils.device import device_doc
 from raftsql_tpu.utils.metrics import LatencyTimer
 
 log = logging.getLogger("raftsql_tpu.db")
@@ -139,6 +140,23 @@ def _expand_commit_item(item, node=None, dups=None):
     if len(item) == 3 and isinstance(item[2], str):
         return [item]
     raise TypeError(f"unrecognized commit_q item shape: {item!r:.120}")
+
+
+def _commit_item_tops(item):
+    """Yield (group, highest_log_index) for every batch of a commit_q
+    item, COUNTING the entries _expand_commit_item drops (no-ops,
+    scrubbed conf entries): the index up to which the stream has
+    delivered the group's log — see RaftDB._delivered."""
+    if item[0] is RAW_BATCH or item[0] is RAW_PLAIN:
+        yield item[1], item[2] + len(item[3])
+    elif item[0] is RAW_MANY:
+        for (g, base, datas) in item[1]:
+            yield g, base + len(datas)
+    elif len(item) == 2:
+        if item[1]:
+            yield item[0], item[1][-1][0]
+    else:
+        yield item[0], item[1]
 
 
 class NotLeaderError(Exception):
@@ -251,6 +269,16 @@ class RaftDB:
             pipe.node.snapshot_provider = self._snapshot_of
             pipe.node.snapshot_installer = self._install_snapshot
         self._mu = threading.Lock()
+        # Highest log index the commit stream has delivered per group,
+        # advanced only AFTER the run that carried it was applied.  A
+        # no-op or a scrubbed conf entry carries no command, so the
+        # state machine's applied index never covers it — but the
+        # target of a linear or follower read (the commit index) does.
+        # Without this mark a read on a group whose newest committed
+        # entry is a fresh leader's no-op — every group, right after a
+        # restart — waits for an apply that cannot happen.  Written by
+        # the reader thread only; readers tolerate a stale (lower) value.
+        self._delivered = [0] * num_groups
         self._q2cb: Dict[Tuple[int, str], deque] = defaultdict(deque)  # raftlint: guarded-by=_mu
         self._failed: Optional[Exception] = None
         self._closed = False
@@ -394,6 +422,7 @@ class RaftDB:
             # queue put per group and none of the per-entry Python.
             dups: list = []
             run = _expand_commit_item(item, self.pipe.node, dups)
+            tops = list(_commit_item_tops(item))
             stop = False
             if not replay:
                 while len(run) < 256:
@@ -414,8 +443,12 @@ class RaftDB:
                         break
                     run.extend(_expand_commit_item(nxt, self.pipe.node,
                                                    dups))
+                    tops.extend(_commit_item_tops(nxt))
             if run:
                 self._apply_run(run)
+            for g, top in tops:         # everything <= top is applied
+                if top > self._delivered[g]:
+                    self._delivered[g] = top
             for (group, index, query) in dups:
                 # A committed RETRY duplicate: its first copy applied
                 # (this run or earlier), so the retrying client's PUT
@@ -440,7 +473,11 @@ class RaftDB:
     def _snapshot_of(self, group: int):
         sm = self._sms[group]
         fn = getattr(sm, "serialize_with_index", None)
-        if fn is None:
+        if fn is None or sm.applied_index() <= 0:
+            # Nothing applied: there is no snapshot to hand out, so do
+            # not build one only to drop it (at boot that is every one
+            # of --groups databases, serialized under the shm plane's
+            # start while the tick thread competes for the interpreter).
             return None
         idx, blob = fn()
         return (idx, blob) if idx > 0 else None
@@ -604,8 +641,11 @@ class RaftDB:
 
     def _wait_applied(self, group: int, target: int, deadline: float,
                       tick: float, phase: str) -> None:
-        """Block until the local apply reaches `target` (bounded)."""
-        while self._sms[group].applied_index() < target:
+        """Block until the local apply reaches `target` (bounded):
+        the state machine applied it, or the commit stream delivered
+        past it (entries that carry no command — see _delivered)."""
+        while max(self._sms[group].applied_index(),
+                  self._delivered[group]) < target:
             if self._failed is not None:
                 raise self._failed
             now = time.monotonic()
@@ -868,6 +908,10 @@ class RaftDB:
         ovc = getattr(node, "overload", None)
         m["overload"] = (ovc.metrics_doc() if ovc is not None
                          else zero_metrics_doc())
+        # Which device the engine computes on (utils/device.py): the
+        # numeric fields (count, compile-cache hits/misses) also reach
+        # the Prometheus exposition.
+        m["device"] = device_doc()
         gcw = getattr(node, "_gcwal", None)
         if gcw is not None:
             # Group-commit batch histogram: peers coalesced per fsync
@@ -968,6 +1012,20 @@ class RaftDB:
                         max(lease_fn(g) - now, 0.0), 4)
         doc = {"id": int(getattr(node, "node_id", 0)),
                "ready": True, "groups": groups}
+        # Where this engine runs: the device as JAX reports it plus the
+        # JAX version and compile-cache traffic (utils/device.py), and
+        # whether the WAL writes through the native fast path or fell
+        # back to Python — so nothing outside the process has to guess
+        # whether it measured the chip and the native plane.
+        doc["device"] = device_doc()
+        wals = getattr(node, "wals", None) or [getattr(node, "wal", None)]
+        doc["native_wal"] = all(getattr(w, "is_native", False)
+                                for w in wals)
+        # Mesh deployment: the observed shard placement of the step's
+        # carry and output (runtime/mesh.py mesh_doc).
+        mesh_fn = getattr(node, "mesh_doc", None)
+        if mesh_fn is not None:
+            doc["mesh"] = mesh_fn()
         # Pod deployment (raftsql_tpu/pod/): topology + ownership.  The
         # `hosts` table lets a client pointed at ONE pod host discover
         # the sweep set; `pod_owned` on each group row names which rows

@@ -60,6 +60,7 @@ from raftsql_tpu.config import RaftConfig
 from raftsql_tpu.parallel.sharded import (GROUPS_AXIS, PEERS_AXIS,
                                           make_mesh,
                                           make_sharded_cluster_step_host,
+                                          prop_spec,
                                           shard_cluster_arrays,
                                           timer_spec)
 from raftsql_tpu.runtime.hostplane import ClusterHostPlane
@@ -192,6 +193,13 @@ class ShardedWAL:
             if wal_exists(d):
                 WAL.repair_epochs(d, committed)
 
+    @property
+    def is_native(self) -> bool:
+        """Every shard this host writes goes through the native fast
+        path (a pod host's null sinks for foreign shards do not count)."""
+        return all(s.is_native for s in self.shards
+                   if isinstance(s, WAL))
+
     # -- observability fan-out -----------------------------------------
 
     @property
@@ -307,6 +315,11 @@ class MeshClusterNode(ClusterHostPlane):
         self._steps = 1
         self._sharded_step = make_sharded_cluster_step_host(cfg, mesh)
         self._ti_spec = NamedSharding(mesh, timer_spec())
+        # Host inputs go straight to their shards: jnp.asarray would
+        # land the whole [P, G] block on the default device first and
+        # leave the re-layout to the dispatch.
+        self._prop_spec = NamedSharding(mesh, prop_spec())
+        self._placement: Optional[dict] = None
         self._ti_ones = jax.device_put(
             jnp.ones((cfg.num_peers,), jnp.int32), self._ti_spec)
         # Lay the freshly built (or replayed) cluster state out over the
@@ -385,9 +398,35 @@ class MeshClusterNode(ClusterHostPlane):
         if timer_inc is None:
             ti = self._ti_ones
         else:
-            ti = jax.device_put(
-                jnp.asarray(np.asarray(timer_inc, np.int32)),
-                self._ti_spec)
+            ti = jax.device_put(np.asarray(timer_inc, np.int32),
+                                self._ti_spec)
         self.states, self.inboxes, pinfo_dev, busy = self._sharded_step(
-            self.states, self.inboxes, jnp.asarray(prop_n), ti)
+            self.states, self.inboxes,
+            jax.device_put(prop_n, self._prop_spec), ti)
+        if self._placement is None:
+            self._placement = self._observe_placement(pinfo_dev)
         return pinfo_dev, busy
+
+    def _observe_placement(self, pinfo_dev) -> dict:
+        """Which devices hold the shards of the step's carry and of its
+        host-facing output, read off the FIRST dispatch's results (tick
+        thread only: the carry is donated every step).  The layout is
+        fixed by the shard_map specs from then on, so once is enough;
+        /healthz publishes it so a four-chip run can be told from one
+        that gathered everything onto the first device."""
+        def held_by(x):
+            return sorted(s.device.id for s in x.addressable_shards)
+        return {
+            "peer_shards": self.mesh.shape[PEERS_AXIS],
+            "group_shards": self._gg,
+            "mesh_devices": [int(d.id) for d in self.mesh.devices.flat],
+            "state_devices": held_by(self.states.commit),
+            "inbox_devices": held_by(self.inboxes.a_type),
+            "info_devices": held_by(pinfo_dev),
+            "state_shard_shape": list(
+                self.states.commit.addressable_shards[0].data.shape),
+        }
+
+    def mesh_doc(self) -> Optional[dict]:
+        """The observed placement (None until the first dispatch)."""
+        return self._placement

@@ -433,10 +433,18 @@ class RaftNode:
             # __init__ for why the uncommitted tail must NOT reach the
             # state machine here.
             upto = max(0, min(gl.log_len, gl.hard.commit) - gl.start)
+            tail_applies = False
             for i, (term, data) in enumerate(gl.entries[:upto]):
                 sql = self._decode_entry(g, data, gl.start + 1 + i)
-                if sql is not None:
+                tail_applies = sql is not None
+                if tail_applies:
                     self.commit_q.put((g, gl.start + 1 + i, sql))
+            if upto and not tail_applies:
+                # The committed prefix ends in an entry that carries no
+                # command: deliver its index as an empty batch so reads
+                # at the commit index do not wait on it (see _publish).
+                self.commit_q.put(
+                    (RAW_BATCH, g, gl.start + upto - 1, [b""]))
         self._replay_groups = {}
         self.commit_q.put(None)         # replay-complete sentinel
         # Adopt the transport's fault counters into this node's metrics
@@ -2102,14 +2110,16 @@ class RaftNode:
                     if mm.apply(g, idx, d) is not None:
                         self._patch_group_config(g)
                     datas[idx - a - 1] = b""
-            if any(datas):
-                # RAW batch, one queue put per group per tick: the
-                # per-entry unwrap/dedup/utf-8 chain (~2.5 µs each, the
-                # bulk of this phase at saturation) now runs on the
-                # CONSUMER thread (runtime/db.py _expand_commit_item),
-                # off the tick's critical path.  All-empty ranges
-                # (no-op/conf entries) publish nothing, as before.
-                self.commit_q.put((RAW_BATCH, g, a, datas))
+            # RAW batch, one queue put per group per tick: the
+            # per-entry unwrap/dedup/utf-8 chain (~2.5 µs each, the
+            # bulk of this phase at saturation) runs on the CONSUMER
+            # thread (runtime/db.py _expand_commit_item), off the
+            # tick's critical path.  All-empty ranges (no-op/conf
+            # entries) are delivered too: nothing is applied for them,
+            # but the consumer must learn the stream passed their index
+            # (RaftDB._delivered) or a linear read right after an
+            # election waits for an apply that cannot happen.
+            self.commit_q.put((RAW_BATCH, g, a, datas))
             self._applied[g] = c
             self.metrics.commits += c - a
             if self._local[g]:

@@ -388,19 +388,17 @@ class RingServer:
         # published stream complete (shm.py start docstring).  Env
         # gate RAFTSQL_SHM_READS=0 turns the plane off on both sides
         # (chaos digest baselines run with it compiled in but idle).
+        # With the gate on, a publisher that cannot start is a start-up
+        # error: a server that quietly serves every read through the
+        # ring is not the deployment that was asked for.
         self.shm = None
         self._shm_thread = None
         if os.environ.get("RAFTSQL_SHM_READS", "1") != "0" \
                 and hasattr(rdb, "_snapshot_of"):
-            try:
-                from raftsql_tpu.runtime.shm import ShmSnapshotPublisher
-                self.shm = ShmSnapshotPublisher(dirname, rdb.num_groups)
-                rdb.shm = self.shm
-                self.shm.start(rdb._snapshot_of, rdb.watermark)
-            except Exception:                           # noqa: BLE001
-                log.exception("shm snapshot plane disabled")
-                rdb.shm = None
-                self.shm = None
+            from raftsql_tpu.runtime.shm import ShmSnapshotPublisher
+            self.shm = ShmSnapshotPublisher(dirname, rdb.num_groups)
+            rdb.shm = self.shm
+            self.shm.start(rdb._snapshot_of, rdb.watermark)
         if self.shm is not None:
             self._shm_thread = threading.Thread(
                 target=self._shm_refresh, daemon=True,

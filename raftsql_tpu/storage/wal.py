@@ -1209,6 +1209,10 @@ class WALGroupView:
         return self._owner.base._h
 
     @property
+    def is_native(self) -> bool:
+        return self._owner.base.is_native
+
+    @property
     def _f(self):
         return self._owner.base._f
 

@@ -11,15 +11,13 @@ import pstats
 import sys
 import tempfile
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
-from raftsql_tpu.config import RaftConfig  # noqa: E402
-from raftsql_tpu.runtime.fused import FusedClusterNode  # noqa: E402
+from raftsql_tpu.config import RaftConfig
+from raftsql_tpu.runtime.fused import FusedClusterNode
+from raftsql_tpu.utils.device import select_device
 
 
 def main() -> None:
+    select_device()
     G = int(sys.argv[1]) if len(sys.argv) > 1 else 10000
     E = int(sys.argv[2]) if len(sys.argv) > 2 else 32
     ticks = int(sys.argv[3]) if len(sys.argv) > 3 else 8

@@ -119,3 +119,51 @@ def test_linear_read_blocked_without_quorum(cluster):
         dbs[lead].query("SELECT count(*) FROM t", linear=True, timeout=1.5)
     assert time.monotonic() - t0 < 10.0
     faults.heal()
+
+
+def test_reads_do_not_wait_on_an_entry_that_carries_no_command(cluster):
+    """Right after an election the newest committed entry is the new
+    leader's no-op.  The state machine applies nothing for it, so its
+    applied index stays one short of the commit index — and a linear or
+    follower read, whose target IS the commit index, used to wait for an
+    apply that could not happen (503 until the group's next write).
+    The commit stream now delivers the no-op's index too."""
+    dbs, _ = cluster
+    lead = leader_index(dbs)
+    node = dbs[lead].pipe.node
+    deadline = time.monotonic() + TIMEOUT
+    while int(node._hard_np[0, 2]) < 1:         # the no-op committed
+        assert time.monotonic() < deadline
+        time.sleep(0.02)
+    assert dbs[lead].watermark(0) == 0          # nothing was applied
+    assert dbs[lead].query("SELECT 1", linear=True, timeout=5) == "|1|\n"
+    assert dbs[lead].query("SELECT 1", mode="follower",
+                           timeout=5) == "|1|\n"
+
+
+def test_fused_reads_after_restart_do_not_wait_on_the_new_noop(
+        tmp_path, monkeypatch):
+    """The same on the co-located runtime, where it bit hardest: after a
+    restart EVERY group elects again, so every group's newest entry is a
+    no-op, and no linear read could be served until each group was
+    written to (chip_smoke.py's restart read-back found it)."""
+    monkeypatch.chdir(tmp_path)
+    from raftsql_tpu.server.main import build_fused_node
+
+    rdb = build_fused_node(groups=2, peers=3, tick=0.002)
+    try:
+        assert rdb.propose("CREATE TABLE t (v text)", 0).wait(30) is None
+        assert rdb.propose("INSERT INTO t (v) VALUES ('x')",
+                           0).wait(30) is None
+        # Group 1 was never written to: its only entry is a no-op.
+        assert rdb.query("SELECT 1", 1, linear=True, timeout=10) == "|1|\n"
+    finally:
+        rdb.close()
+    rdb = build_fused_node(groups=2, peers=3, tick=0.002)   # WAL replay
+    try:
+        assert rdb.query("SELECT v FROM t", 0, linear=True,
+                         timeout=10) == "|x|\n"
+        assert rdb.query("SELECT v FROM t", 0, mode="follower",
+                         timeout=10) == "|x|\n"
+    finally:
+        rdb.close()

@@ -51,6 +51,15 @@ def drain(node, peer=0):
     return out
 
 
+def by_group(rows):
+    """Applied (group, index, cmd) rows as {group: [(index, cmd), ...]},
+    each group's list in stream order."""
+    out = {}
+    for g, idx, cmd in rows:
+        out.setdefault(g, []).append((idx, cmd))
+    return out
+
+
 # -- MeshConfig ---------------------------------------------------------
 
 def test_mesh_config_validation():
@@ -176,7 +185,13 @@ def _run_pair(tmp_path, ticks, membership=None, skew_windows=(),
                     err_msg=f"publish cursors diverged at tick {t}")
                 applied_f.extend(drain(fused))
                 applied_m.extend(drain(meshn))
-                assert applied_f == applied_m, f"KV stream at tick {t}"
+                # Order WITHIN a group is the contract.  How the streams
+                # of different groups interleave is not: with the
+                # host-parallel plane on (any host with >= 4 cores, the
+                # chip host among them) each publish shard has its own
+                # worker, and their relative progress is scheduling.
+                assert by_group(applied_f) == by_group(applied_m), \
+                    f"KV stream at tick {t}"
         assert (fused._hard[:, :, 2] > 0).any(), "nothing ever committed"
         assert applied_f, "no applied KV to compare"
     finally:

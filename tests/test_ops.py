@@ -103,7 +103,7 @@ def test_pallas_quorum_matches_reference(seed, P, quorum):
             jnp.asarray(commit), jnp.asarray(term), jnp.asarray(is_leader))
     want = np.asarray(quorum_commit_index(*args, quorum=quorum, window=32))
     got = np.asarray(pallas_quorum_commit_index(
-        *args, quorum=quorum, window=32, block_g=32, interpret=True))
+        *args, quorum=quorum, window=32, block_g=32))
     np.testing.assert_array_equal(got, want)
 
 

@@ -172,10 +172,8 @@ def test_fork_disjoint_union(tmp_path):
     """Key-range fork: two standalone DBs, keyed rows disjoint by
     slot, union exactly the source; meta tables on BOTH sides,
     non-keyed tables stay with the source shard.  Runs through
-    `SQLiteStateMachine.serialize`, so it exercises the py3.10
-    `VACUUM INTO` fallback on interpreters without
-    Connection.serialize.  resume=True so the `_raft_meta` applied
-    floor exists — the meta table both forks must carry."""
+    `SQLiteStateMachine.serialize`.  resume=True so the `_raft_meta`
+    applied floor exists — the meta table both forks must carry."""
     from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
     sm = SQLiteStateMachine(str(tmp_path / "src.db"), resume=True)
     try:

@@ -119,3 +119,74 @@ def test_multi_group_over_real_processes(tmp_path):
             raise
     finally:
         c.stop_all()
+
+
+# The server entry point made to behave at 4 groups the way size alone
+# makes it behave at `--groups 10000`, once the test arms it by creating
+# the file `big`: a tick is always in flight and lasts (0.5 s pass
+# between its top and its durable phase; one tick is ~0.3 s there), and
+# closing the state machines at shutdown lasts (10,000 SQLite handles
+# there).
+_BIG_SERVER_AT_SMALL_G = """
+import os, sys, time
+import raftsql_tpu.models.sqlite_sm as sm
+import raftsql_tpu.runtime.hostplane as hp
+_tick = hp.ClusterHostPlane.tick
+def long_tick(self):
+    if not os.path.exists("big"):
+        return _tick(self)
+    time.sleep(0.5)
+    _tick(self)
+    self._tick_active = self._spin_hot = True      # never park
+hp.ClusterHostPlane.tick = long_tick
+_close = sm.SQLiteStateMachine.close
+def slow_close(self):
+    time.sleep(0.2)
+    return _close(self)
+sm.SQLiteStateMachine.close = slow_close
+from raftsql_tpu.server.main import main
+main(sys.argv[1:])
+"""
+
+
+def test_sigterm_with_a_tick_in_flight_is_a_clean_stop(tmp_path):
+    """SIGTERM while a long tick is in flight exits 0 with the WAL
+    flushed.  The engine used to die there with EXIT_CODE_FATAL
+    ("cannot schedule new futures after shutdown"): the main thread fell
+    off main() while the shutdown thread was still stopping the engine,
+    and interpreter finalization tore the WAL sync pool down under the
+    tick that was still running."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    import time
+
+    from tests.conftest import free_port
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    port = free_port()
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)
+    with open(tmp_path / "server.log", "wb") as logf:
+        proc = subprocess.Popen(
+            [sys.executable, "-c", _BIG_SERVER_AT_SMALL_G, "--fused",
+             "--groups", "4", "--port", str(port)],
+            cwd=tmp_path, env=env, stdout=logf, stderr=logf)
+    try:
+        cli = RaftSQLClient([f"127.0.0.1:{port}"], timeout_s=10.0)
+        cli.put("CREATE TABLE t (v text)", deadline_s=TIMEOUT)
+        cli.put("INSERT INTO t (v) VALUES ('acked')", deadline_s=TIMEOUT)
+        cli.close()         # no connection left for the HTTP plane to drain
+        (tmp_path / "big").touch()
+        time.sleep(1.2)             # a long tick is in flight by now
+        proc.send_signal(signal.SIGTERM)
+        rc = proc.wait(timeout=60)
+        log = (tmp_path / "server.log").read_text()
+        assert rc == 0, log[-2000:]
+        assert "consensus engine failed" not in log
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()

@@ -161,6 +161,11 @@ def test_seqlock_concurrent_publish_read_storm(tmp_path):
                 pub.publish_deltas(
                     {0: [(f"INSERT INTO t VALUES ({k}, 'v')", k + 2)]})
                 state["n"] = k + 1
+                # Paced: on a many-core host an unthrottled writer gets
+                # hundreds of thousands of deltas ahead inside the
+                # window, and the reader's last catch-up then takes
+                # minutes (121 s of the tier-1 budget on 8 cores).
+                time.sleep(0.0002)
         th = threading.Thread(target=writer, daemon=True)
         th.start()
         last = 0
