@@ -47,8 +47,11 @@ everything that needs it is a child, one at a time.  There is no CPU mode on
 the command line; tests import the phases and drive them against a
 `JAX_PLATFORMS=cpu` server at a small size.
 
-Last line of stdout, on success only:
-  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}, ...}
+Last line of stdout, once the probe has found the chips asked for (before
+that nothing is printed and the exit code alone says why), exactly:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+`ok` is false when a later phase failed.  What the run observed is the
+`chip_smoke: summary:` line above it.
 """
 from __future__ import annotations
 
@@ -136,6 +139,13 @@ def require_device(dev: dict, platform: str, chips: int, who: str) -> None:
         raise SmokeFailure(
             f"{who} runs on platform={dev.get('platform')!r} "
             f"count={dev.get('count')}; required {platform!r} x{chips}")
+
+
+def result_line(ok: bool, dev: dict) -> str:
+    """The contract's last line of stdout: these keys and no others."""
+    return json.dumps({"ok": ok, "device": {
+        "platform": str(dev["platform"]), "kind": str(dev["device_kind"]),
+        "count": int(dev["count"])}})
 
 
 def free_port() -> int:
@@ -455,15 +465,15 @@ def check_engine(doc: dict, platform: str, chips: int) -> None:
                     f"distinct devices")
 
 
-def smoke(shape: Shape, chips: int, seed: int, platform: str,
+def smoke(shape: Shape, chips: int, seed: int, dev: dict,
           data_dir: str, deadline: float) -> dict:
-    """Every phase, in order (module docstring).  Returns the summary;
-    raises on the first phase that fails."""
+    """Every phase after the probe, in order (module docstring), on the
+    device `dev` the probe found.  Returns the summary; raises on the
+    first phase that fails."""
     def left() -> float:
         return deadline - time.monotonic()
 
-    dev = probe_device()
-    require_device(dev, platform, chips, "the device probe")
+    platform = dev["platform"]
     say("platform", dev["platform"])
     say("device_kind", dev["device_kind"])
     say("device count", dev["count"])
@@ -578,10 +588,9 @@ def smoke(shape: Shape, chips: int, seed: int, platform: str,
         f"{dchild.get('tick_ms')}, peak bytes "
         f"{dchild.get('peak_bytes_in_use')}")
     return {
-        "ok": True,
-        "device": {"platform": dev["platform"], "kind": dev["device_kind"],
-                   "count": dev["count"]},
-        "jax": dev["jax"], "groups": shape.groups, "peers": shape.peers,
+        "platform": dev["platform"], "device_kind": dev["device_kind"],
+        "devices": dev["count"], "jax": dev["jax"],
+        "groups": shape.groups, "peers": shape.peers,
         "native_wal": health["native_wal"],
         "first_204_cold_s": round(cold_204_s, 2),
         "first_204_restart_s": round(warm_204_s, 2),
@@ -613,9 +622,16 @@ def main(argv=None) -> int:
     # Beside the checkout, not under /tmp: the WAL's fsyncs should hit
     # the machine's disk, not a tmpfs.  (`raftsql-*/` is git-ignored.)
     data_dir = tempfile.mkdtemp(prefix="raftsql-smoke-", dir=HERE)
+    dev = None          # set once the probe found the chips asked for
+    ok = False
     try:
-        summary = smoke(DEPLOYMENT, args.chips, args.seed, "tpu", data_dir,
+        probed = probe_device()
+        require_device(probed, "tpu", args.chips, "the device probe")
+        dev = probed
+        summary = smoke(DEPLOYMENT, args.chips, args.seed, dev, data_dir,
                         deadline)
+        say("summary", json.dumps(summary))
+        ok = True
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         log_path = os.path.join(data_dir, "server.log")
@@ -624,11 +640,11 @@ def main(argv=None) -> int:
                 tail = f.read()[-6000:].decode("utf-8", "replace")
             print(f"chip_smoke: end of the server's log:\n{tail}",
                   file=sys.stderr)
-        return 1
     finally:
         shutil.rmtree(data_dir, ignore_errors=True)
-    print(json.dumps(summary))
-    return 0
+        if dev is not None:     # no accelerator: no result at all
+            print(result_line(ok, dev), flush=True)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -34,11 +34,16 @@ def test_phases_against_cpu_server(tmp_path, monkeypatch):
                              device_groups=256, device_ticks=20)
     data = tmp_path / "data"
     data.mkdir()
-    out = chip_smoke.smoke(shape, chips=1, seed=7, platform="cpu",
+    dev = chip_smoke.probe_device()
+    chip_smoke.require_device(dev, "cpu", 1, "the device probe")
+    assert json.loads(chip_smoke.result_line(True, dev)) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    out = chip_smoke.smoke(shape, chips=1, seed=7, dev=dev,
                            data_dir=str(data),
                            deadline=time.monotonic() + 400)
-    assert out["ok"] is True
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert (out["platform"], out["device_kind"], out["devices"]) == \
+        ("cpu", "cpu", 1)
     assert out["native_wal"] is True
     assert out["requests"] == {"attempted": 16 + 64 + 1,
                                "acked": 16 + 64 + 1, "failed": 0}
@@ -63,6 +68,35 @@ def test_command_line_has_no_cpu_mode():
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
     assert "required 'tpu'" in r.stderr
+
+
+def _run_main(monkeypatch, capsys, smoke):
+    dev = {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1,
+           "jax": "0.9.0"}
+    monkeypatch.setattr(chip_smoke, "probe_device", lambda: dict(dev))
+    monkeypatch.setattr(chip_smoke, "smoke", smoke)
+    rc = chip_smoke.main([])
+    return rc, capsys.readouterr().out.splitlines()
+
+
+def test_last_line_is_the_contract_object_and_nothing_more(monkeypatch,
+                                                           capsys):
+    """The last line of stdout has exactly the keys `ok` and `device`
+    (`platform`, `kind`, `count`); what the run observed is on the
+    `summary` line before it.  A phase that fails after the probe found
+    the chip ends in `ok: false` and a non-zero exit."""
+    want = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    rc, lines = _run_main(monkeypatch, capsys,
+                          lambda *a, **k: {"groups": 10_000})
+    assert rc == 0
+    assert json.loads(lines[-1]) == {"ok": True, "device": want}
+    assert lines[-2] == 'chip_smoke: summary: {"groups": 10000}'
+
+    def failing(*a, **k):
+        raise chip_smoke.SmokeFailure("a phase failed")
+    rc, lines = _run_main(monkeypatch, capsys, failing)
+    assert rc == 1
+    assert json.loads(lines[-1]) == {"ok": False, "device": want}
 
 
 def test_statements_are_seeded_and_spread():
