@@ -28,11 +28,15 @@ from __future__ import annotations
 import asyncio
 import logging
 import threading
-from typing import Optional
+import time
+from typing import TYPE_CHECKING, Optional
 
 from raftsql_tpu.overload import (Overloaded, retry_after_header,
                                   retryable_refusal)
-from raftsql_tpu.runtime.db import NotLeaderError, RaftDB
+from raftsql_tpu.runtime.errors import NotLeaderError
+
+if TYPE_CHECKING:       # the worker's rdb is a RingClient: no engine here
+    from raftsql_tpu.runtime.db import RaftDB
 
 log = logging.getLogger("raftsql.api.aio")
 
@@ -164,7 +168,8 @@ class _Conn(asyncio.Protocol):
                     self._do_kv(method, path, headers, body))
             elif method == b"PUT":
                 self.busy = True
-                self.srv.loop.create_task(self._do_put(headers, body))
+                self.srv.loop.create_task(
+                    self._do_put(headers, body, time.monotonic()))
             elif method == b"GET":
                 if path == b"/healthz":
                     # Readiness probe — parity with api/http.py.
@@ -323,8 +328,15 @@ class _Conn(asyncio.Protocol):
                            b"deadline exceeded (edge)\n"))
         return True
 
-    async def _do_put(self, headers: dict, body: bytes) -> None:
+    async def _do_put(self, headers: dict, body: bytes,
+                      t_parsed: float = 0.0) -> None:
         rdb = self.srv.rdb
+        # An HTTP worker's legs of the write (obs/prof.py
+        # worker_stages.put.*; the in-process RaftDB has none here):
+        # edge_in = request parsed -> record on the propose ring,
+        # edge_out = completion popped -> response handed to the
+        # transport.  ring_rtt, between them, is RingClient's.
+        stages = getattr(rdb, "stages", None)
         try:
             query = body.decode("utf-8")
             group = int(headers["group"] or 0)
@@ -345,6 +357,8 @@ class _Conn(asyncio.Protocol):
             fut = rdb.propose(query, group, token=headers["token"],
                               **({} if dl is None
                                  else {"deadline_ms": dl}))
+            if stages is not None:
+                stages.stage("put.edge_in", fut.t_push - t_parsed)
             afut = self.srv.loop.create_future()
             fut.add_done_callback(
                 lambda err: self.srv.bridge.deliver(afut, err))
@@ -386,12 +400,11 @@ class _Conn(asyncio.Protocol):
             self._finish(_resp(400, b"Bad Request",
                                (str(e) + "\n").encode()))
             return
-        if err is not None:
-            if isinstance(err, Overloaded):
-                # Ring deployments surface admission refusals through
-                # the ack path (RingFuture._err) — same 429 contract.
-                self._finish(_refusal_resp(err))
-                return
+        if isinstance(err, Overloaded):
+            # Ring deployments surface admission refusals through
+            # the ack path (RingFuture._err) — same 429 contract.
+            self._finish(_refusal_resp(err))
+        elif err is not None:
             log.info("client error: %s", err)
             self._finish(_resp(400, b"Bad Request",
                                (str(err) + "\n").encode()))
@@ -407,6 +420,8 @@ class _Conn(asyncio.Protocol):
                              + b"\r\n\r\n")
             else:
                 self._finish(_204)
+        if stages is not None:
+            stages.stage("put.edge_out", time.monotonic() - fut.t_done)
 
     async def _do_members(self, body: bytes) -> None:
         """POST /members — membership admin write, parity with
@@ -660,7 +675,7 @@ class AioSQLServer:
     """Drop-in alternative to api/http.py's SQLServer: same constructor
     shape, same start()/stop() lifecycle, one event-loop thread."""
 
-    def __init__(self, port: int, rdb: RaftDB, host: str = "",
+    def __init__(self, port: int, rdb: "RaftDB", host: str = "",
                  timeout_s: float = 30.0, reuse_port: bool = False):
         self.port = port
         self.rdb = rdb

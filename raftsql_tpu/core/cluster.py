@@ -70,15 +70,21 @@ def cluster_step(cfg: RaftConfig, states: PeerState, inboxes: Inbox,
     Returns:
       (new_states, delivered_inboxes_for_next_tick, stacked_infos).
     """
-    self_ids = jnp.arange(cfg.num_peers, dtype=I32)
-    ti = jnp.broadcast_to(jnp.asarray(timer_inc, I32), (cfg.num_peers,))
-
     def _one(st, ib, pn, sid, t):
         return peer_step(cfg, st, ib, pn, sid, timer_inc=t)
 
-    new_states, outboxes, infos = jax.vmap(_one)(states, inboxes, prop_n,
-                                                 self_ids, ti)
-    return new_states, deliver(outboxes), infos
+    # A stable name for the whole step in the lowered program and in a
+    # device trace, around peer_step's own phase names (core/step.py
+    # STEP_SCOPES); metadata only.
+    with jax.named_scope("cluster_step"):
+        self_ids = jnp.arange(cfg.num_peers, dtype=I32)
+        ti = jnp.broadcast_to(jnp.asarray(timer_inc, I32),
+                              (cfg.num_peers,))
+        new_states, outboxes, infos = jax.vmap(_one)(
+            states, inboxes, prop_n, self_ids, ti)
+        with jax.named_scope("raft.deliver"):
+            delivered = deliver(outboxes)
+    return new_states, delivered, infos
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=(1, 2))
@@ -109,7 +115,9 @@ def cluster_step_host(cfg: RaftConfig, states: PeerState, inboxes: Inbox,
     busy = (jnp.any(ib.v_type != 0)
             | jnp.any((ib.a_type == MSG_REQ) & (ib.a_n > 0))
             | jnp.any((ib.a_type == MSG_RESP) & ~ib.a_success))
-    return st, ib, jax.vmap(pack_info)(infos), busy
+    with jax.named_scope("raft.pack"):
+        packed = jax.vmap(pack_info)(infos)
+    return st, ib, packed, busy
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1, 2))

@@ -46,6 +46,34 @@ from raftsql_tpu.ops.quorum import (masked_quorum_commit_index,
                                     quorum_match_index, vote_count)
 
 
+# The phases of peer_step as they are named in the lowered program and
+# in a device trace (jax.named_scope: metadata only, the computation and
+# every chaos digest are what they were).  core/cluster.py wraps them in
+# `cluster_step`; `raft.pack` is cluster_step_host's packing of the
+# host-facing info.
+STEP_SCOPES = ("raft.inbox", "raft.votes", "raft.append", "raft.commit",
+               "raft.timers", "raft.outbox")
+
+
+class _PhaseScope:
+    """`scope(name)` ends the named scope that is open and opens the
+    next, so that peer_step's phases carry names without each becoming
+    an indented block; peer_step closes the last one."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, name: str) -> None:
+        self.close()
+        self._open = jax.named_scope(name)
+        self._open.__enter__()
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
 def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
               prop_n: jax.Array, self_id: jax.Array,
               group_offset: jax.Array | int = 0,
@@ -92,8 +120,22 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
       accepted proposals, accepted append ranges) that drive WAL writes,
       payload mirroring, and apply.
     """
+    scope = _PhaseScope()
+    try:
+        return _peer_step(scope, cfg, state, inbox, prop_n, self_id,
+                          group_offset, timer_inc, force_bcast)
+    finally:
+        scope.close()
+
+
+def _peer_step(scope: _PhaseScope, cfg: RaftConfig, state: PeerState,
+               inbox: Inbox, prop_n, self_id, group_offset, timer_inc,
+               force_bcast) -> Tuple[PeerState, Outbox, StepInfo]:
+    """peer_step's body; `scope(name)` names the phases that follow it
+    (STEP_SCOPES)."""
     G, P, W, E = cfg.num_groups, cfg.num_peers, cfg.log_window, \
         cfg.max_entries_per_msg
+    scope("raft.inbox")     # masks, term catch-up, TimeoutNow receipt
     src_ids = jnp.arange(P, dtype=I32)[None, :]                  # [1, P]
     self_onehot = src_ids == self_id                             # [1, P]
 
@@ -203,6 +245,7 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
                       jnp.broadcast_to(self_onehot, (G, P)), votes)
     leader_hint = jnp.where(tnow_fire, NO_LEADER, leader_hint)
 
+    scope("raft.votes")    # vote and prevote requests, tallies
     # ---- Phase 2: RequestVote requests.  Grant at most one vote per group
     # per tick (voted_for is single-valued); re-granting to the same
     # candidate is idempotent.
@@ -280,6 +323,7 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
                          state.next_idx)
     match = jnp.where(become_leader[:, None], 0, state.match)
 
+    scope("raft.append")    # append requests, responses, proposals
     # ---- Phase 4: AppendEntries requests.  At most one current-term leader
     # exists (election safety), so picking one current-term append per group
     # loses nothing.
@@ -479,6 +523,7 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
     match = jnp.where(is_leader[:, None] & self_onehot, log_len[:, None],
                       match)
 
+    scope("raft.commit")    # the quorum reduction
     # ---- Phase 7: leader commit advance — the quorum reduction kernel
     # (selected by cfg.commit_rule; all implement raft Fig. 2's leader
     # rule, see ops/commit_scan.py and ops/pallas_quorum.py).
@@ -523,6 +568,7 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
             voters=voters, voters_joint=jvoters, window=W,
             term_of=term_of1, size=cfg.write_quorum)
 
+    scope("raft.timers")    # timers, election start, leases
     # ---- Phase 8: timers and election start.  tnow_fire counts as a
     # reset: the transfer target just started a REAL election (Phase 1b)
     # and must not immediately re-fire as a PRECANDIDATE on a stale
@@ -624,6 +670,7 @@ def peer_step(cfg: RaftConfig, state: PeerState, inbox: Inbox,
     else:
         lease_until = jnp.zeros((G,), I32)
 
+    scope("raft.outbox")    # the outbox and the host-facing info
     # ---- Phase 9: compose the outbox.  Write order = priority order:
     # responses first, then candidate vote-request broadcast, then leader
     # append broadcast.  A later write overriding a response is safe: every

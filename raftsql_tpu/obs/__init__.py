@@ -35,16 +35,29 @@ pure observers: chaos digests are pinned bit-identical with them on.
 Cross-process trace SEGMENTS (`export.py TraceSegmentWriter`) let
 `--workers N` HTTP worker processes land on the engine's /trace as one
 merged multi-process Perfetto timeline.
-"""
-from raftsql_tpu.obs.device_ring import EVENT_FIELDS, DeviceEventRing
-from raftsql_tpu.obs.export import (TraceSegmentWriter, chrome_trace,
-                                    collect_segments,
-                                    validate_chrome_trace)
-from raftsql_tpu.obs.flight import FlightRecorder
-from raftsql_tpu.obs.prof import PROF_PHASES, TickPhaseProfiler
-from raftsql_tpu.obs.spans import SpanTracer
 
-__all__ = ["EVENT_FIELDS", "DeviceEventRing", "SpanTracer",
-           "chrome_trace", "validate_chrome_trace", "FlightRecorder",
-           "TickPhaseProfiler", "PROF_PHASES", "TraceSegmentWriter",
-           "collect_segments"]
+The names below resolve on first use: an HTTP worker imports
+`obs.prof` (its stage pairs) and `obs.export` (its trace segments)
+without loading `device_ring`, and with it JAX.
+"""
+_HOME = {
+    "EVENT_FIELDS": "device_ring", "DeviceEventRing": "device_ring",
+    "TraceSegmentWriter": "export", "chrome_trace": "export",
+    "collect_segments": "export", "validate_chrome_trace": "export",
+    "FlightRecorder": "flight",
+    "PROF_PHASES": "prof", "TickPhaseProfiler": "prof",
+    "SpanTracer": "spans",
+}
+
+__all__ = sorted(_HOME)
+
+
+def __getattr__(name: str):
+    if name not in _HOME:
+        raise AttributeError(f"module {__name__!r} has no attribute "
+                             f"{name!r}")
+    import importlib
+    value = getattr(importlib.import_module(
+        f"{__name__}.{_HOME[name]}"), name)
+    globals()[name] = value
+    return value

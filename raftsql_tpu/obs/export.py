@@ -14,8 +14,9 @@ One document, three track families:
     SYNTHETIC tick axis (1 tick = `tick_us` microseconds), since device
     ticks carry no wall clock.  Separate pid, so the axes never mix;
   * pid 4 "tick phases": the tick-phase profiler's per-phase duration
-    tracks (obs/prof.py — pop / dispatch / wal_write / fsync / publish
-    / ring_drain, one thread per (phase, worker id));
+    tracks (obs/prof.py — pop / dispatch with its launch and readback
+    halves / wal_write with its wal_* parts / fsync / publish /
+    ring_drain, one thread per (phase, worker id));
   * real-pid process tracks: per-process trace SEGMENTS merged in from
     the serving plane's worker processes (TraceSegmentWriter /
     collect_segments below) — a `--workers N` deployment's /trace is

@@ -40,6 +40,8 @@ from raftsql_tpu.models.sqlite_sm import is_select
 from raftsql_tpu.overload import (Overloaded, deadline_steps,
                                   zero_metrics_doc)
 from raftsql_tpu.runtime.envelope import unwrap
+from raftsql_tpu.runtime.errors import (NotLeaderError,  # noqa: F401
+                                        ReadTimeout)
 from raftsql_tpu.transport.codec import is_conf_entry
 from raftsql_tpu.runtime.node import (CLOSED, RAW_BATCH, RAW_MANY,
                                       RAW_PLAIN)
@@ -157,33 +159,6 @@ def _commit_item_tops(item):
             yield item[0], item[1][-1][0]
     else:
         yield item[0], item[1]
-
-
-class NotLeaderError(Exception):
-    """A linearizable read hit a non-leader; retry at `leader` (1-based
-    node id, 0 = unknown)."""
-
-    def __init__(self, group: int, leader: int):
-        super().__init__(
-            f"group {group}: not the leader"
-            + (f"; leader is node {leader}" if leader > 0 else ""))
-        self.group = group
-        self.leader = leader
-
-
-class ReadTimeout(TimeoutError):
-    """A read could not be served within the request timeout — a TYPED,
-    RETRYABLE condition (quorum unreachable mid-ReadIndex round, apply
-    lagging the read point, a session watermark not yet replicated, or
-    leadership lost mid-round without a forward hint).  Subclasses
-    TimeoutError so both HTTP planes keep answering 503 Service
-    Unavailable (retry-at-will), never a 400; `phase` names which wait
-    ran out, so a client log pinpoints the stall."""
-
-    def __init__(self, group: int, phase: str, detail: str):
-        super().__init__(f"group {group}: {detail}")
-        self.group = group
-        self.phase = phase
 
 
 class AckFuture:
@@ -328,8 +303,15 @@ class RaftDB:
         after construction — resolve per use, it is one getattr)."""
         return getattr(getattr(self.pipe, "node", None), "tracer", None)
 
+    def _prof(self):
+        """The engine's telemetry plane (obs/prof.py: stages.put.*,
+        stages.get.*), or None where RAFTSQL_PROF=0 or the engine has
+        none."""
+        return getattr(getattr(self.pipe, "node", None), "prof", None)
+
     def _ack_one(self, group: int, query: str, err,
-                 commit_ts: Optional[float] = None) -> None:
+                 commit_ts: Optional[float] = None,
+                 acked: Optional[list] = None) -> None:
         if self.listener is not None:
             self.listener.put((group, query))
         tracer = self._node_tracer()
@@ -348,11 +330,20 @@ class RaftDB:
             if not cbs:
                 del self._q2cb[(group, query)]
         cb.set(err)
-        self.latency.record(time.monotonic() - cb.created)
+        now = time.monotonic()
+        self.latency.record(now - cb.created)
         if commit_ts is not None:
             # commit_ts is when this run was drained off the commit
             # queue — the commit observation point, before apply.
             self.latency_commit.record(commit_ts - cb.created)
+            if acked is not None:
+                # The same stamps as cumulative pairs, which a reader
+                # can confine to a window (the rings above cannot be):
+                # proposed -> commit observed -> applied and acked.
+                # The caller hands its run's pairs over in one call.
+                acked.append(("put.propose_commit",
+                              commit_ts - cb.created))
+                acked.append(("put.apply", now - commit_ts))
 
     def _apply_run(self, run) -> None:
         """Apply a drained run of commits with GROUP COMMIT: entries are
@@ -389,13 +380,17 @@ class RaftDB:
                 log.exception("shm delta publish failed; disabling")
                 self.shm = None
         tracer = self._node_tracer()
+        prof = self._prof()
+        acked: Optional[list] = None if prof is None else []
         pos = {g: 0 for g in per_g}
         for (group, index, query) in run:
             err = errs[group][pos[group]]
             pos[group] += 1
             if tracer is not None:
                 tracer.note_apply(group, index)
-            self._ack_one(group, query, err, commit_ts=commit_ts)
+            self._ack_one(group, query, err, commit_ts, acked)
+        if acked:
+            prof.stage_many(acked)
         for _ in run:
             self._maybe_compact()
 
@@ -449,12 +444,17 @@ class RaftDB:
             for g, top in tops:         # everything <= top is applied
                 if top > self._delivered[g]:
                     self._delivered[g] = top
-            for (group, index, query) in dups:
+            if dups:
                 # A committed RETRY duplicate: its first copy applied
                 # (this run or earlier), so the retrying client's PUT
                 # succeeded — ack success without re-applying.
-                self._ack_one(group, query, None,
-                              commit_ts=time.monotonic())
+                prof = self._prof()
+                acked: Optional[list] = None if prof is None else []
+                for (group, index, query) in dups:
+                    self._ack_one(group, query, None, time.monotonic(),
+                                  acked)
+                if acked:
+                    prof.stage_many(acked)
             if stop:
                 break
 
@@ -704,7 +704,8 @@ class RaftDB:
             # The client's end-to-end budget bounds every wait below;
             # a tighter server-side timeout still wins.
             timeout = min(timeout, max(float(deadline_ms) / 1000.0, 0.0))
-        deadline = time.monotonic() + timeout
+        t_in = time.monotonic()
+        deadline = t_in + timeout
         if info is not None:
             info["served"] = mode
         if mode == "local":
@@ -728,7 +729,15 @@ class RaftDB:
                               brownout=brownout, info=info)
         else:
             raise ValueError(f"unknown read mode {mode!r}")
-        return self._sms[group].query(query)
+        # stages.get.wait: arrival -> this mode's freshness established;
+        # stages.get.sql: the SELECT on SQLite (all modes together).
+        t_fresh = time.monotonic()
+        rows = self._sms[group].query(query)
+        prof = self._prof()
+        if prof is not None:
+            prof.stage_many((("get.wait", t_fresh - t_in),
+                             ("get.sql", time.monotonic() - t_fresh)))
+        return rows
 
     def _linear_wait(self, node, group: int, deadline: float,
                      tick: float, brownout: bool = False,
@@ -875,6 +884,10 @@ class RaftDB:
         prof = getattr(node, "prof", None)
         if prof is not None:
             m["phase_profile"] = prof.snapshot()
+            # The request's stages and the host plane's intake.* and
+            # wal.* counters (obs/prof.py), all cumulative.
+            m["stages"] = prof.stages_doc()
+            m.update(prof.counters_doc())
         traffic = getattr(node, "traffic", None)
         if traffic is not None:
             xg = getattr(node, "transferring_groups", None)

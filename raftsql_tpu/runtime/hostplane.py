@@ -73,7 +73,7 @@ from raftsql_tpu.runtime.node import (CLOSED, RAW_MANY, RAW_PLAIN,
 from raftsql_tpu.native.build import load_native_plog
 from raftsql_tpu.storage import fsio
 from raftsql_tpu.storage.log import NativePayloadLog, PayloadLog
-from raftsql_tpu.obs.prof import TickPhaseProfiler
+from raftsql_tpu.obs.prof import TickPhaseProfiler, span
 from raftsql_tpu.storage.wal import (WAL, split_uniform_runs,
                                      wal_exists, wal_mirror_all)
 from raftsql_tpu.utils.metrics import GroupTraffic, NodeMetrics
@@ -152,8 +152,12 @@ class ClusterHostPlane:
         # the hot path, never any control-flow influence: chaos digests
         # are pinned identical with RAFTSQL_PROF on and off).
         #   prof: per-phase tick wall-time rings -> /metrics
-        #     phase_profile + Perfetto phase tracks in /trace
-        #     (RAFTSQL_PROF=0 off, RAFTSQL_PROF_SAMPLE=N 1-in-N ticks);
+        #     phase_profile + Perfetto phase tracks in /trace, each
+        #     leaf phase also a `tick.<phase>` span on the JAX
+        #     profiler's timeline while a profiler session runs; the
+        #     request stages (stages.*) and
+        #     the intake.* / wal.* counters ride the same object
+        #     (RAFTSQL_PROF=0 turns all of it off);
         #   traffic: [G] propose/commit/ack counters + EWMA rates ->
         #     /metrics group_traffic top-K hot-groups table.
         self.prof = TickPhaseProfiler.from_env(G)
@@ -166,6 +170,22 @@ class ClusterHostPlane:
         self._pending_tick = 0
         self._fsync_dur = np.zeros(P, np.float64)   # parallel-path syncs
         self._fsync_span: Optional[tuple] = None    # (t0, dur) last tick
+        # The durable phase's parts for the profiler: seconds of
+        # [wal_plan, wal_append, wal_hardstate] summed over a
+        # dispatch's steps, the parallel path's per-peer times (its
+        # barrier costs the slowest peer, like the fsync), and what
+        # the wal.* counters count.
+        self._wal_split = [0.0, 0.0, 0.0]
+        self._mirror_dur = np.zeros(P, np.float64)
+        self._hard_dur = np.zeros(P, np.float64)
+        self._wal_records = 0
+        self._wal_hard: List[Optional[np.ndarray]] = [None] * P
+        self._wal_groups: set = set()
+        self._wal_wrote: Optional[Tuple[int, int]] = None   # last seen
+        # This tick's [backlog, offered, groups, accepted] (intake.*),
+        # and whether a profiler session ran at the tick's start.
+        self._intake = [0, 0, 0, 0]
+        self._ann = None
         self.dirs = [os.path.join(data_dir, f"p{i + 1}") for i in range(P)]
         # WAL group commit: multiplex all P peers' records into ONE
         # physical log (flat group id peer*G+g) so the durable barrier
@@ -1035,6 +1055,11 @@ class ClusterHostPlane:
         dead = []
         ov = self.overload
         now_step = self._device_steps
+        # intake.* (obs/prof.py): what the queues hold and what is
+        # offered at the moment of this pop; tick() counts them with
+        # what the device accepted, so a scrape never sees one without
+        # the other.
+        backlog = offered = groups = 0
         with self._prop_lock:
             for (p, g) in list(self._queued):  # snapshot: re-routes mutate
                 q = self._props[p][g]
@@ -1066,9 +1091,14 @@ class ClusterHostPlane:
                         if not q:
                             dead.append((p, g))
                             continue
-                prop_n[p, g] = min(len(q), cap)
+                n = len(q)
+                prop_n[p, g] = min(n, cap)
+                backlog += n
+                offered += min(n, cap)
+                groups += 1
             for k in dead:
                 self._queued.discard(k)
+        self._intake = [backlog, offered, groups, 0]
         if steps <= 1:
             return prop_n
         return np.stack([np.clip(prop_n - s * self._E, 0, self._E)
@@ -1091,18 +1121,19 @@ class ClusterHostPlane:
                 # sentinel must stay the queues' last item.
                 if item is not None and self.error is None:
                     pinfo, ptick = item
+                    # Per-shard publish workers tag their shard id —
+                    # the mesh runtime's N workers each get their own
+                    # Perfetto phase track.
+                    prof = self.prof
+                    ann = prof.annotation() if prof is not None else None
                     t0 = _t.monotonic()
-                    self._publish_shard(pinfo, shard)
+                    with span(ann, "tick.publish", ptick):
+                        self._publish_shard(pinfo, shard)
                     dur = _t.monotonic() - t0
                     with self._metrics_mu:
                         self.metrics.t_publish_ms += dur * 1e3
-                    prof = self.prof
-                    if prof is not None and prof.sampled(ptick):
-                        # Per-shard publish workers tag their shard id
-                        # — the mesh runtime's N workers each get their
-                        # own Perfetto phase track.
-                        prof.record("publish", ptick, t0, dur,
-                                    tid=shard)
+                    if prof is not None:
+                        prof.record("publish", ptick, t0, dur, tid=shard)
             except Exception as e:
                 self.error = e
                 for cq in self._commit_qs:
@@ -1200,6 +1231,7 @@ class ClusterHostPlane:
         self.wals[p].set_hardstates(changed, hs[changed, 0],
                                     hs[changed, 1], hs[changed, 2])
         self._hard[p][changed] = hs[changed]
+        self._wal_hard[p] = changed         # -> wal.* (_count_wal)
         return True
 
     def tick(self) -> None:
@@ -1214,37 +1246,41 @@ class ClusterHostPlane:
         it; publish always runs after the save of the tick it publishes.
         """
         import time as _t
+        # The telemetry plane (obs/prof.py): the clock is read in place
+        # and the tick's samples go over in one record_tick() below;
+        # `ann` is this tick's one test for a running profiler session,
+        # under which each leaf phase is also a `tick.<phase>` span.
         prof = self.prof
-        prof_on = prof is not None and prof.sampled(self._tick_no)
+        ann = self._ann = prof.annotation() if prof is not None else None
+        tick_no = self._tick_no
         t0 = _t.monotonic()
-        if self._xfer_req:
-            self._transfer_arm()     # latch visible to THIS dispatch
-        # Snapshot _queued: _build_prop_n may re-route into the set.
-        prop_n = self._build_prop_n(self._steps)
-        tb = _t.monotonic() if prof_on else t0
-        if prof_on:
-            prof.record("pop", self._tick_no, t0, tb - t0)
-        ti = self.timer_inc
-        if ti is not None:
-            # Skew accounting: how far this tick's timer advances
-            # deviate from lockstep, per peer, summed.
-            self.metrics.faults_skew_ticks += int(
-                np.abs(np.asarray(ti, np.int64) - 1).sum())
-        pinfo_dev, busy_dev = self._device_step(prop_n, ti)
-        if self.ring is not None:
-            # Device-plane event ring: one extra small fused program
-            # over arrays already resident (tracing-on cost only); the
-            # ring stays on device and drains to host in batches.  A
-            # multi-step dispatch records its final step — the ring is
-            # tick-indexed at dispatch granularity, like the runtime.
-            self.ring.record(self._tick_no,
-                             pinfo_dev if self._steps == 1
-                             else pinfo_dev[-1],
-                             self.states.votes, self.inboxes.v_type,
-                             self.inboxes.a_type, self._applied)
+        with span(ann, "tick.pop", tick_no):
+            if self._xfer_req:
+                self._transfer_arm()     # latch visible to THIS dispatch
+            # Snapshot _queued: _build_prop_n may re-route into the set.
+            prop_n = self._build_prop_n(self._steps)
+        tb = _t.monotonic()
+        with span(ann, "tick.launch", tick_no):
+            ti = self.timer_inc
+            if ti is not None:
+                # Skew accounting: how far this tick's timer advances
+                # deviate from lockstep, per peer, summed.
+                self.metrics.faults_skew_ticks += int(
+                    np.abs(np.asarray(ti, np.int64) - 1).sum())
+            pinfo_dev, busy_dev = self._device_step(prop_n, ti)
+            if self.ring is not None:
+                # Device-plane event ring: one extra small fused program
+                # over arrays already resident (tracing-on cost only);
+                # the ring stays on device and drains to host in
+                # batches.  A multi-step dispatch records its final
+                # step — the ring is tick-indexed at dispatch
+                # granularity, like the runtime.
+                self.ring.record(self._tick_no,
+                                 pinfo_dev if self._steps == 1
+                                 else pinfo_dev[-1],
+                                 self.states.votes, self.inboxes.v_type,
+                                 self.inboxes.a_type, self._applied)
         t1 = _t.monotonic()
-        if prof_on:
-            prof.record("dispatch", self._tick_no, tb, t1 - tb)
         # Double-buffered dispatch: the PREVIOUS tick's stashed durable
         # phase (WAL writes + fsync barrier + publish) runs HERE, inside
         # this dispatch's device window — tick t's disk time overlaps
@@ -1264,12 +1300,8 @@ class ClusterHostPlane:
             if self._host_parallel:
                 self._enqueue_publish(self._pending_pinfo)
             else:
-                tp = _t.monotonic()
-                self._publish(self._pending_pinfo)
-                pdur = _t.monotonic() - tp
-                self.metrics.t_publish_ms += pdur * 1e3
-                if prof is not None and prof.sampled(self._pending_tick):
-                    prof.record("publish", self._pending_tick, tp, pdur)
+                self._publish_inline(self._pending_pinfo,
+                                     self._pending_tick)
             self._pending_pinfo = None
         t2 = _t.monotonic()
         if self.overlap_hook is not None:
@@ -1279,18 +1311,15 @@ class ClusterHostPlane:
             t2b = _t.monotonic()
         else:
             t2b = t2
-        if busy_dev is not None:
-            pinfo, dev_busy = jax.device_get((pinfo_dev, busy_dev))
-            pinfo = np.asarray(pinfo)
-            dev_busy = bool(dev_busy)
-        else:
-            pinfo = np.asarray(jax.device_get(pinfo_dev))  # [P,G,NCOLS]
-            dev_busy = True
+        with span(ann, "tick.readback", tick_no):
+            if busy_dev is not None:
+                pinfo, dev_busy = jax.device_get((pinfo_dev, busy_dev))
+                pinfo = np.asarray(pinfo)
+                dev_busy = bool(dev_busy)
+            else:
+                pinfo = np.asarray(jax.device_get(pinfo_dev))  # [P,G,NCOLS]
+                dev_busy = True
         t3 = _t.monotonic()
-        if prof_on:
-            # The readback is dispatch time too: the host blocks on the
-            # device completing this tick's program.
-            prof.record("dispatch", self._tick_no, t2b, t3 - t2b)
 
         # Multi-step dispatch (RAFTSQL_FUSED_STEPS > 1): packed info
         # arrives stacked [S, P, G, C]; the host replays its durable
@@ -1313,11 +1342,26 @@ class ClusterHostPlane:
         # next _build_prop_n snapshot must see post-pop queue state —
         # that is what keeps the overlapped pipeline's trajectory
         # bit-identical to the serialized one.
-        ts0 = _t.monotonic() if prof_on else 0.0
-        staged = [self._stage_ranges(pi) for pi in step_infos]
-        if prof_on:
-            prof.record("pop", self._tick_no, ts0,
-                        _t.monotonic() - ts0)
+        ts0 = _t.monotonic()
+        with span(ann, "tick.pop", tick_no):
+            staged = [self._stage_ranges(pi) for pi in step_infos]
+        if prof is not None:
+            # `dispatch` is launch + readback (the host blocks on the
+            # device completing this tick's program): a sample each,
+            # and each half under its own name too.  intake.*: what
+            # _build_prop_n found queued and offered, what
+            # _stage_ranges popped as accepted.
+            ld, rd = t1 - tb, t3 - t2b
+            it = self._intake
+            prof.record_tick(
+                tick_no,
+                (("pop", t0, tb - t0), ("dispatch", tb, ld),
+                 ("launch", tb, ld), ("dispatch", t2b, rd),
+                 ("readback", t2b, rd),
+                 ("pop", ts0, _t.monotonic() - ts0)),
+                (("intake.backlog", it[0]), ("intake.offered", it[1]),
+                 ("intake.groups", it[2]), ("intake.accepted", it[3]))
+                if it[2] else ())
         if self.overload is not None:
             # Overload plane tick feed: drain-rate EWMA (Retry-After)
             # + queue-depth EWMA (the brownout governor's hysteresis).
@@ -1388,12 +1432,7 @@ class ClusterHostPlane:
                     pinfo[0][:, _C["commit"]] - self._applied[0],
                     0, None).sum())
                 if delta <= self._inline_publish_max:
-                    tp = _t.monotonic()
-                    self._publish(pinfo)
-                    pdur = _t.monotonic() - tp
-                    self.metrics.t_publish_ms += pdur * 1e3
-                    if prof_on:
-                        prof.record("publish", self._tick_no, tp, pdur)
+                    self._publish_inline(pinfo, self._tick_no)
                     self._pending_pinfo = None
                 else:
                     self._pending_pinfo = pinfo  # next tick overlaps
@@ -1406,12 +1445,7 @@ class ClusterHostPlane:
             if self._host_parallel:
                 self._enqueue_publish(pinfo)
             else:
-                tp = _t.monotonic()
-                self._publish(pinfo)
-                pdur = _t.monotonic() - tp
-                self.metrics.t_publish_ms += pdur * 1e3
-                if prof_on:
-                    prof.record("publish", self._tick_no, tp, pdur)
+                self._publish_inline(pinfo, self._tick_no)
             self._pending_pinfo = None
         self._tick_active = base_active
         self.metrics.t_device_ms += ((t1 - t0) + (t3 - t2b)) * 1e3
@@ -1423,7 +1457,6 @@ class ClusterHostPlane:
         """Run the stashed tick's durable phase + publish (the
         double-buffered pipeline's back half).  Caller order guarantees
         this precedes the NEXT durable phase and its publish."""
-        import time as _t
         step_infos, staged, stick = self._stash
         self._stash = None
         # Attribute the whole retired phase to its ORIGINATING tick.
@@ -1433,13 +1466,19 @@ class ClusterHostPlane:
         if self._host_parallel:
             self._enqueue_publish(pinfo)
         else:
-            tp = _t.monotonic()
+            self._publish_inline(pinfo, stick)
+
+    def _publish_inline(self, pinfo: np.ndarray, tick_no: int) -> None:
+        """Deliver on the tick thread (serial hosts), timed as the
+        `publish` phase of the tick that owns the commits."""
+        import time as _t
+        tp = _t.monotonic()
+        with span(self._ann, "tick.publish", tick_no):
             self._publish(pinfo)
-            pdur = _t.monotonic() - tp
-            self.metrics.t_publish_ms += pdur * 1e3
-            prof = self.prof
-            if prof is not None and prof.sampled(stick):
-                prof.record("publish", stick, tp, pdur)
+        pdur = _t.monotonic() - tp
+        self.metrics.t_publish_ms += pdur * 1e3
+        if self.prof is not None:
+            self.prof.record("publish", tick_no, tp, pdur)
 
     def _drain_pipeline(self) -> None:
         """Retire any stashed durable phase (manual-tick callers: the
@@ -1459,9 +1498,12 @@ class ClusterHostPlane:
         pinfo = step_infos[-1]
         prof = self.prof
         ptick = self._prof_tick
-        prof_on = prof is not None and prof.sampled(ptick)
-        td0 = _t.monotonic() if prof_on else 0.0
+        td0 = _t.monotonic()
         self._fsync_span = None
+        split = self._wal_split
+        split[0] = split[1] = split[2] = 0.0
+        if prof is not None and self._wal_wrote is None:
+            self._wal_wrote = self._wal_written()   # wal.* start here
         # Multi-step dispatches are epoch-framed (see _ensure_epoch_
         # begin / _commit_epoch): BEGIN lazily wraps each peer's first
         # write, END lands before its fsync, and the dispatch commits
@@ -1488,17 +1530,57 @@ class ClusterHostPlane:
             self._membership_advance(pinfo)
         if self._gcwal is not None:
             self.metrics.wal_group_commits = self._gcwal.group_commits
-        if prof_on and tick_active:
-            # wal_write = the durable back half minus the fsync barrier
-            # (the barrier was clocked where it ran, serial or across
-            # the per-peer workers — _durable_phases fills _fsync_span).
-            t_tot = _t.monotonic() - td0
-            fs = self._fsync_span
-            fdur = fs[1] if fs is not None else 0.0
-            prof.record("wal_write", ptick, td0, max(t_tot - fdur, 0.0))
-            if fs is not None:
-                prof.record("fsync", ptick, fs[0], fdur)
+        if prof is not None:
+            samples: list = []
+            if tick_active:
+                # wal_write = the durable back half minus the fsync
+                # barrier (the barrier was clocked where it ran, serial
+                # or across the per-peer workers — _durable_phases
+                # fills _fsync_span); its parts are _durable_phases'.
+                t_tot = _t.monotonic() - td0
+                fs = self._fsync_span
+                fdur = fs[1] if fs is not None else 0.0
+                samples = [("wal_write", td0, max(t_tot - fdur, 0.0)),
+                           ("wal_plan", td0, split[0]),
+                           ("wal_append", td0, split[1]),
+                           ("wal_hardstate", td0, split[2])]
+                if fs is not None:
+                    samples.append(("fsync", fs[0], fdur))
+            prof.record_tick(ptick, samples, self._wal_counts())
         return tick_active
+
+    def _wal_written(self) -> Tuple[int, int]:
+        """(bytes, flushing barriers) of every WAL this plane writes,
+        cumulative (storage/wal.py WAL.written; the group-commit views
+        share one log, counted once)."""
+        if self._gcwal is not None:
+            return self._gcwal.base.written()
+        got = [w.written() for w in self.wals]
+        return sum(b for b, _ in got), sum(n for _, n in got)
+
+    def _wal_counts(self) -> tuple:
+        """The wal.* increments of the durable phase that just ran:
+        what it handed to the WALs (tick thread; the per-peer workers
+        have returned), or () where it handed them nothing."""
+        hard = 0
+        groups = self._wal_groups
+        for p, changed in enumerate(self._wal_hard):
+            if changed is not None:
+                hard += changed.size
+                groups.update(changed.tolist())
+                self._wal_hard[p] = None
+        records, self._wal_records = self._wal_records, 0
+        wrote0, wrote1 = self._wal_wrote, self._wal_written()
+        if not (records or hard or wrote1 != wrote0):
+            return ()
+        self._wal_wrote = wrote1
+        n_groups = len(groups)
+        groups.clear()
+        return (("wal.records", records),
+                ("wal.bytes", wrote1[0] - wrote0[0]),
+                ("wal.hardstates", hard),
+                ("wal.groups_written", n_groups),
+                ("wal.fsyncs", wrote1[1] - wrote0[1]))
 
     def _stage_ranges(self, pinfo: np.ndarray) -> list:
         """Build one step's phase-2a write plan — per peer the
@@ -1575,7 +1657,9 @@ class ClusterHostPlane:
                 if confs:
                     for (cg, cidx, cd) in confs:
                         self._conf_note(cg, cidx, cd)
-                self.metrics.proposals += int(acc[ags].sum())
+                n_acc = int(acc[ags].sum())
+                self.metrics.proposals += n_acc
+                self._intake[3] += n_acc
                 # Per-group traffic: the accepted counts are already in
                 # hand per group — one vectorized add, no new walks.
                 self.traffic.add_propose(ags, acc[ags])
@@ -1611,38 +1695,55 @@ class ClusterHostPlane:
         which is the etcd wal.Save order at dispatch granularity.
         Returns tick_active (entries or hard states written)."""
         P = self.cfg.num_peers
-        m_peer: List[int] = []
-        m_src: List[int] = []
-        m_g: List[int] = []
-        m_start: List[int] = []
-        m_count: List[int] = []
-        m_newlen: List[int] = []
-        for p in range(P):
-            col = pinfo[p]
-            accepted = np.nonzero(col[:, _C["app_from"]] >= 0)[0]
-            if not accepted.size:
-                continue
-            sub = col[accepted]
-            m_peer.extend([p] * accepted.size)
-            m_g.extend(accepted.tolist())
-            m_src.extend(sub[:, _C["app_from"]].tolist())
-            m_start.extend(sub[:, _C["app_start"]].tolist())
-            m_count.extend(sub[:, _C["app_n"]].tolist())
-            m_newlen.extend(sub[:, _C["new_log_len"]].tolist())
+        import time as _t
+        # The phase's parts, each a span on the profiler's timeline
+        # while a session runs and, summed into _wal_split, a phase of
+        # phase_profile (recorded by _finish_durable): wal_plan,
+        # wal_append, wal_hardstate.
+        ann = self._ann
+        counting = self.prof is not None
+        ptick = self._prof_tick
+        split = self._wal_split
+        ta = _t.monotonic()
+        with span(ann, "tick.wal_plan", ptick):
+            m_peer: List[int] = []
+            m_src: List[int] = []
+            m_g: List[int] = []
+            m_start: List[int] = []
+            m_count: List[int] = []
+            m_newlen: List[int] = []
+            for p in range(P):
+                col = pinfo[p]
+                accepted = np.nonzero(col[:, _C["app_from"]] >= 0)[0]
+                if not accepted.size:
+                    continue
+                sub = col[accepted]
+                m_peer.extend([p] * accepted.size)
+                m_g.extend(accepted.tolist())
+                m_src.extend(sub[:, _C["app_from"]].tolist())
+                m_start.extend(sub[:, _C["app_start"]].tolist())
+                m_count.extend(sub[:, _C["app_n"]].tolist())
+                m_newlen.extend(sub[:, _C["new_log_len"]].tolist())
+            if counting and m_peer:
+                # wal.records / wal.groups_written: mirrored entries
+                # (an empty heartbeat ack mirrors none).
+                self._wal_records += sum(m_count)
+                self._wal_groups.update(
+                    [g for g, c in zip(m_g, m_count) if c])
 
-        if self.tracer is not None and m_peer:
-            # Replicate stamp: the mirrored range is landing in a
-            # follower's log this step (first stamp wins per index).
-            for g, st, c in zip(m_g, m_start, m_count):
-                if c:
-                    self.tracer.note_replicate(g, st + c - 1)
+            if self.tracer is not None and m_peer:
+                # Replicate stamp: the mirrored range is landing in a
+                # follower's log this step (first stamp wins per index).
+                for g, st, c in zip(m_g, m_start, m_count):
+                    if c:
+                        self.tracer.note_replicate(g, st + c - 1)
 
-        if self.witness_peers and m_peer:
-            # Witnesses never lead, so every entry they persist arrives
-            # here as a mirrored follower append.
-            self.metrics.witness_appends += sum(
-                c for p, c in zip(m_peer, m_count)
-                if c and p in self.witness_peers)
+            if self.witness_peers and m_peer:
+                # Witnesses never lead, so every entry they persist
+                # arrives here as a mirrored follower append.
+                self.metrics.witness_appends += sum(
+                    c for p, c in zip(m_peer, m_count)
+                    if c and p in self.witness_peers)
 
         # Phase 2a: leader appends (fresh-leader no-ops + accepted
         # proposals) as uniform-term RANGES per peer — the write plan
@@ -1652,33 +1753,44 @@ class ClusterHostPlane:
         # expands ranges to per-entry numpy columns for the classic
         # two-call path.
         tick_active = bool(m_peer)
-        for p in range(P):
-            r_g, r_start, r_count, r_term, w_d = staged[p]
-            if not r_g:
-                continue
-            tick_active = True
-            self._ensure_epoch_begin(p)
-            plog_native = (self.plogs[p]
-                           if hasattr(self.plogs[p], "handle") else None)
-            wrote = False
-            if plog_native is not None:
-                blob = b"".join(w_d)
-                lens = np.fromiter(map(len, w_d), np.uint32, len(w_d))
-                wrote = self.wals[p].append_ranges_uniform(
-                    plog_native, r_g, r_start, r_count, r_term, blob,
-                    lens)
-            if not wrote:
-                # Python plog path: RANGE records — one framed record
-                # per (group, start, term) run, not one per entry.
-                self.wals[p].append_ranges(r_g, r_start, r_count,
-                                           r_term, w_d)
-                puts = []
-                pos = 0
-                for g, s, c, tm in zip(r_g, r_start, r_count, r_term):
-                    puts.append((g, s, w_d[pos: pos + c], [tm] * c,
-                                 None))
-                    pos += c
-                self.plogs[p].put_ranges(puts)
+        tb = _t.monotonic()
+        split[0] += tb - ta
+        with span(ann, "tick.wal_append", ptick):
+            for p in range(P):
+                r_g, r_start, r_count, r_term, w_d = staged[p]
+                if not r_g:
+                    continue
+                tick_active = True
+                if counting:
+                    self._wal_records += sum(r_count)
+                    self._wal_groups.update(r_g)
+                self._ensure_epoch_begin(p)
+                plog_native = (self.plogs[p] if hasattr(
+                    self.plogs[p], "handle") else None)
+                wrote = False
+                if plog_native is not None:
+                    blob = b"".join(w_d)
+                    lens = np.fromiter(map(len, w_d), np.uint32,
+                                       len(w_d))
+                    wrote = self.wals[p].append_ranges_uniform(
+                        plog_native, r_g, r_start, r_count, r_term, blob,
+                        lens)
+                if not wrote:
+                    # Python plog path: RANGE records — one framed
+                    # record per (group, start, term) run, not one per
+                    # entry.
+                    self.wals[p].append_ranges(r_g, r_start, r_count,
+                                               r_term, w_d)
+                    puts = []
+                    pos = 0
+                    for g, s, c, tm in zip(r_g, r_start, r_count,
+                                           r_term):
+                        puts.append((g, s, w_d[pos: pos + c], [tm] * c,
+                                     None))
+                        pos += c
+                    self.plogs[p].put_ranges(puts)
+        tc = _t.monotonic()
+        split[1] += tc - tb
 
         # Phases 2b+2c+fsync, PARALLEL per peer when the native plane
         # is up: worker p runs [mirrors INTO peer p] + [peer p's hard
@@ -1698,107 +1810,121 @@ class ClusterHostPlane:
                   and hasattr(self.wals[0]._lib, "walplog_mirror_all")
                   and all(w._lib is not None for w in self.wals)
                   and all(hasattr(pl, "handle") for pl in self.plogs))
-        if par_ok and m_peer:
-            # Per-group disjointness holds per LEADER, and a leader can
-            # change within a tick: group g's old leader X may accept
-            # from new leader Y (mirror INTO plog[X], with truncation)
-            # in the same tick another peer still mirrors g FROM
-            # plog[X].  Concurrent workers would then write a source
-            # mid-read.  Detect it (a group whose mirror source is also
-            # one of its mirror dests) and take the serial staged path
-            # for this tick — it is an election-tick rarity.
-            dests: Dict[int, set] = {}
-            for g, p in zip(m_g, m_peer):
-                dests.setdefault(g, set()).add(p)
-            for g, s in zip(m_g, m_src):
-                if s in dests.get(g, ()):
-                    par_ok = False
-                    break
+        with span(ann, "tick.wal_plan", ptick):
+            if par_ok and m_peer:
+                # Per-group disjointness holds per LEADER, and a leader
+                # can change within a tick: group g's old leader X may
+                # accept from new leader Y (mirror INTO plog[X], with
+                # truncation) in the same tick another peer still
+                # mirrors g FROM plog[X].  Concurrent workers would
+                # then write a source mid-read.  Detect it (a group
+                # whose mirror source is also one of its mirror dests)
+                # and take the serial staged path for this tick — it
+                # is an election-tick rarity.
+                dests: Dict[int, set] = {}
+                for g, p in zip(m_g, m_peer):
+                    dests.setdefault(g, set()).add(p)
+                for g, s in zip(m_g, m_src):
+                    if s in dests.get(g, ()):
+                        par_ok = False
+                        break
+            if par_ok:
+                by_peer: List[List[int]] = [[] for _ in range(P)]
+                for i, mp in enumerate(m_peer):
+                    by_peer[mp].append(i)
+        td = _t.monotonic()
+        split[0] += td - tc
         if par_ok:
-            by_peer: List[List[int]] = [[] for _ in range(P)]
-            for i, mp in enumerate(m_peer):
-                by_peer[mp].append(i)
-
-            import time as _t
 
             def _host_peer(p: int) -> bool:
                 idx = by_peer[p]
-                if idx:
-                    self._ensure_epoch_begin(p)
-                    wal_mirror_all(
-                        self.wals, self.plogs,
-                        [m_peer[i] for i in idx],
-                        [m_src[i] for i in idx],
-                        [m_g[i] for i in idx],
-                        [m_start[i] for i in idx],
-                        [m_count[i] for i in idx],
-                        [m_newlen[i] for i in idx])
-                changed = self._save_hard(p, pinfo)
-                if self._ep_begun[p]:
-                    self.wals[p].epoch_mark(self._ep_no_this, end=True)
-                ts = _t.monotonic()
-                self.wals[p].sync()
-                self._fsync_dur[p] = _t.monotonic() - ts
+                t0 = _t.monotonic()
+                with span(ann, "tick.wal_append", ptick):
+                    if idx:
+                        self._ensure_epoch_begin(p)
+                        wal_mirror_all(
+                            self.wals, self.plogs,
+                            [m_peer[i] for i in idx],
+                            [m_src[i] for i in idx],
+                            [m_g[i] for i in idx],
+                            [m_start[i] for i in idx],
+                            [m_count[i] for i in idx],
+                            [m_newlen[i] for i in idx])
+                t1 = _t.monotonic()
+                with span(ann, "tick.wal_hardstate", ptick):
+                    changed = self._save_hard(p, pinfo)
+                    if self._ep_begun[p]:
+                        self.wals[p].epoch_mark(self._ep_no_this,
+                                                end=True)
+                t2 = _t.monotonic()
+                with span(ann, "tick.fsync", ptick):
+                    self.wals[p].sync()
+                self._fsync_dur[p] = _t.monotonic() - t2
+                self._mirror_dur[p] = t1 - t0
+                self._hard_dur[p] = t2 - t1
                 return changed
 
-            tm0 = _t.monotonic()
             for act in self._sync_pool.map(_host_peer, range(P)):
                 tick_active = tick_active or act
             # The barrier cost is max, not sum: the per-peer syncs ran
             # concurrently on the pool (see _finish_durable's profiler
-            # attribution).
-            self._fsync_span = (tm0, float(self._fsync_dur[:P].max()))
+            # attribution); so did the mirrors and the hard states.
+            self._fsync_span = (td, float(self._fsync_dur[:P].max()))
+            split[1] += float(self._mirror_dur[:P].max())
+            split[2] += float(self._hard_dur[:P].max())
         elif m_peer:
-            for p in sorted(set(m_peer)):
-                self._ensure_epoch_begin(p)
-            if not wal_mirror_all(self.wals, self.plogs, m_peer, m_src,
-                                  m_g, m_start, m_count, m_newlen):
-                # Python two-pass fallback: ALL source reads first (the
-                # staging contract), then one batched write per peer.
-                reads = [self.plogs[s].slice_columns(g, st, c)
-                         if c else ([], [])
-                         for (s, g, st, c) in zip(m_src, m_g, m_start,
-                                                  m_count)]
-                for p in range(P):
-                    b_g: List[int] = []
-                    b_start: List[int] = []
-                    b_count: List[int] = []
-                    b_terms: List[int] = []
-                    b_d: List[bytes] = []
-                    puts = []
-                    for (mp, g, st, c, nl), (terms, datas) in zip(
-                            zip(m_peer, m_g, m_start, m_count,
-                                m_newlen), reads):
-                        if mp != p:
-                            continue
-                        puts.append((g, st, datas, terms, nl))
-                        if c:
-                            b_g.append(g)
-                            b_start.append(st)
-                            b_count.append(c)
-                            b_terms.extend(terms)
-                            b_d.extend(datas)
-                    if puts:
-                        self.plogs[p].put_ranges(puts)
-                    if b_g:
-                        # Mirrored batches may cross term boundaries;
-                        # RANGE records are uniform-term, so split each
-                        # mirror at its term changes (rare: elections).
-                        s_g: List[int] = []
-                        s_start: List[int] = []
-                        s_count: List[int] = []
-                        s_term: List[int] = []
-                        pos = 0
-                        for g, st0, c in zip(b_g, b_start, b_count):
-                            for (rs, rc, rt) in split_uniform_runs(
-                                    st0, b_terms[pos: pos + c]):
-                                s_g.append(g)
-                                s_start.append(rs)
-                                s_count.append(rc)
-                                s_term.append(rt)
-                            pos += c
-                        self.wals[p].append_ranges(s_g, s_start, s_count,
-                                                   s_term, b_d)
+            with span(ann, "tick.wal_append", ptick):
+                for p in sorted(set(m_peer)):
+                    self._ensure_epoch_begin(p)
+                if not wal_mirror_all(self.wals, self.plogs, m_peer, m_src,
+                                      m_g, m_start, m_count, m_newlen):
+                    # Python two-pass fallback: ALL source reads first (the
+                    # staging contract), then one batched write per peer.
+                    reads = [self.plogs[s].slice_columns(g, st, c)
+                             if c else ([], [])
+                             for (s, g, st, c) in zip(m_src, m_g, m_start,
+                                                      m_count)]
+                    for p in range(P):
+                        b_g: List[int] = []
+                        b_start: List[int] = []
+                        b_count: List[int] = []
+                        b_terms: List[int] = []
+                        b_d: List[bytes] = []
+                        puts = []
+                        for (mp, g, st, c, nl), (terms, datas) in zip(
+                                zip(m_peer, m_g, m_start, m_count,
+                                    m_newlen), reads):
+                            if mp != p:
+                                continue
+                            puts.append((g, st, datas, terms, nl))
+                            if c:
+                                b_g.append(g)
+                                b_start.append(st)
+                                b_count.append(c)
+                                b_terms.extend(terms)
+                                b_d.extend(datas)
+                        if puts:
+                            self.plogs[p].put_ranges(puts)
+                        if b_g:
+                            # Mirrored batches may cross term boundaries;
+                            # RANGE records are uniform-term, so split each
+                            # mirror at its term changes (rare: elections).
+                            s_g: List[int] = []
+                            s_start: List[int] = []
+                            s_count: List[int] = []
+                            s_term: List[int] = []
+                            pos = 0
+                            for g, st0, c in zip(b_g, b_start, b_count):
+                                for (rs, rc, rt) in split_uniform_runs(
+                                        st0, b_terms[pos: pos + c]):
+                                    s_g.append(g)
+                                    s_start.append(rs)
+                                    s_count.append(rc)
+                                    s_term.append(rt)
+                                pos += c
+                            self.wals[p].append_ranges(s_g, s_start, s_count,
+                                                       s_term, b_d)
+            split[1] += _t.monotonic() - td
 
         # Phase 2c (serial path only — the parallel path folded hard
         # states + fsync into its per-peer workers): hard states after
@@ -1807,22 +1933,25 @@ class ClusterHostPlane:
         # entries), then the per-peer fsync that is the durable barrier
         # before the next dispatch.
         if final and not par_ok:
-            for p in range(P):
-                tick_active = self._save_hard(p, pinfo) or tick_active
-            if self._ep_active:
+            th = _t.monotonic()
+            with span(ann, "tick.wal_hardstate", ptick):
                 for p in range(P):
-                    if self._ep_begun[p]:
-                        self.wals[p].epoch_mark(self._ep_no_this,
-                                                end=True)
+                    tick_active = self._save_hard(p, pinfo) or tick_active
+                if self._ep_active:
+                    for p in range(P):
+                        if self._ep_begun[p]:
+                            self.wals[p].epoch_mark(self._ep_no_this,
+                                                    end=True)
             # The durable barrier: every peer fsynced before this
             # tick's messages can be observed (the next dispatch).  The
             # P fsyncs are independent files — run them concurrently
             # (os.fsync and the native wal_sync both release the GIL),
             # so the barrier costs one fsync wall-time, not P.  A peer
             # with nothing pending returns immediately.
-            import time as _t
             tf0 = _t.monotonic()
-            list(self._sync_pool.map(lambda w: w.sync(), self.wals))
+            split[2] += tf0 - th
+            with span(ann, "tick.fsync", ptick):
+                list(self._sync_pool.map(lambda w: w.sync(), self.wals))
             self._fsync_span = (tf0, _t.monotonic() - tf0)
         return tick_active
 

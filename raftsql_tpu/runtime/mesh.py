@@ -268,6 +268,11 @@ class ShardedWAL:
         for s in self.shards:
             s.sync()
 
+    def written(self) -> Tuple[int, int]:
+        """Summed over the shards this host writes (WAL.written)."""
+        got = [s.written() for s in self.shards if isinstance(s, WAL)]
+        return sum(b for b, _ in got), sum(n for _, n in got)
+
     def compact(self, floors, hard) -> int:
         deleted = 0
         for j, s in enumerate(self.shards):

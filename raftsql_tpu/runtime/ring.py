@@ -75,6 +75,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from raftsql_tpu.obs.prof import WORKER_STAGES, StageSet, enabled
+
 log = logging.getLogger("raftsql_tpu.ring")
 
 _MAGIC = 0x52494E47                   # "RING"
@@ -510,9 +512,14 @@ class RingServer:
         except Exception:                               # noqa: BLE001
             return 0
 
+    def _prof(self):
+        """The engine's telemetry plane (obs/prof.py), or None."""
+        return getattr(self._prof_node, "prof", None)
+
     def _handle_put(self, worker: int, req_id: int, group: int,
                     token: int, body: bytes,
-                    deadline_ms: Optional[float] = None) -> None:
+                    deadline_ms: Optional[float] = None,
+                    t_pop: float = 0.0) -> None:
         entry = None
         if token:
             with self._tok_mu:
@@ -570,6 +577,11 @@ class RingServer:
                 entry, worker, req_id,
                 None if err is None else self._err_body(err),
                 self._watermark(group) if err is None else 0)
+            # stages.put.engine: the engine's whole residence, from the
+            # drain's pop to the completion record pushed.
+            prof = self._prof()
+            if prof is not None and t_pop:
+                prof.stage("put.engine", time.monotonic() - t_pop)
 
         fut.add_done_callback(_done)
 
@@ -595,9 +607,10 @@ class RingServer:
 
     def _handle_get(self, worker: int, req_id: int, group: int,
                     flags: int, token: int, body: bytes,
-                    deadline_ms: Optional[float] = None) -> None:
+                    deadline_ms: Optional[float] = None,
+                    t_pop: float = 0.0) -> None:
         from raftsql_tpu.overload import Overloaded
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
         # Flags bit 0 = linear, bit 1 = session (token carries the
         # watermark), bit 2 = follower; no bit = stale local read.
         mode = ("linear" if flags & 1 else
@@ -605,6 +618,12 @@ class RingServer:
                 "follower" if flags & 4 else "local")
 
         def _run():
+            # stages.get.queue: popped off the ring -> a read-pool
+            # thread takes it (the wait and the SELECT are timed where
+            # they happen, in RaftDB.query).
+            prof = self._prof()
+            if prof is not None and t_pop:
+                prof.stage("get.queue", time.monotonic() - t_pop)
             try:
                 rows = self.rdb.query(
                     body.decode("utf-8"), group, mode=mode,
@@ -661,7 +680,7 @@ class RingServer:
 
     def _handle_member(self, worker: int, req_id: int,
                        body: bytes) -> None:
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
 
         def _run():
             try:
@@ -684,7 +703,7 @@ class RingServer:
 
     def _handle_transfer(self, worker: int, req_id: int,
                          body: bytes) -> None:
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
 
         def _run():
             try:
@@ -742,6 +761,7 @@ class RingServer:
                 view = ring.pop()
                 if view is None:
                     break
+                t_pop = time.monotonic()    # stages.{put.engine,get.queue}
                 op, req_id, group, flags, token, wire_dl, body = \
                     decode_request(view)
                 ring.pop_commit()       # bytes copied out; release early
@@ -752,7 +772,7 @@ class RingServer:
                 # before any WAL/fsync cost, counted shed_ring.
                 deadline_ms = None
                 if wire_dl:
-                    remain = wire_dl - time.monotonic() * 1000.0
+                    remain = wire_dl - t_pop * 1000.0
                     if remain <= 0:
                         ov = self._overload()
                         if ov is not None:
@@ -765,10 +785,10 @@ class RingServer:
                 try:
                     if op == OP_PUT:
                         self._handle_put(worker, req_id, group, token,
-                                         body, deadline_ms)
+                                         body, deadline_ms, t_pop)
                     elif op == OP_GET:
                         self._handle_get(worker, req_id, group, flags,
-                                         token, body, deadline_ms)
+                                         token, body, deadline_ms, t_pop)
                     elif op == OP_DOC:
                         self._handle_doc(worker, req_id, body)
                     elif op == OP_MEMBER:
@@ -788,12 +808,11 @@ class RingServer:
                 # ring_drain phase sample (obs/prof.py): how long this
                 # batch of popped requests took to hand off, tagged
                 # with the worker id it drained.
-                prof = getattr(self._prof_node, "prof", None)
+                prof = self._prof()
                 if prof is not None:
                     tick = int(getattr(self._prof_node, "_tick_no", 0))
-                    if prof.sampled(tick):
-                        prof.record("ring_drain", tick, t_b0,
-                                    last - t_b0, tid=worker)
+                    prof.record("ring_drain", tick, t_b0,
+                                last - t_b0, tid=worker)
             else:
                 delay = _spin_wait(last)
                 if delay:
@@ -852,7 +871,14 @@ class RingClient:
         # engine's /trace merges every segment into ONE multi-process
         # Perfetto timeline (obs/export.py TraceSegmentWriter).
         self._obs = None
-        self._t0s: Dict[int, Tuple[float, str]] = {}
+        # Telemetry plane, worker side (obs/prof.py, default on,
+        # RAFTSQL_PROF=0 off): this process's legs of a request —
+        # put.edge_in / put.ring_rtt / put.edge_out / get.ring_rtt —
+        # as cumulative pairs, folded into the /metrics document it
+        # relays as `worker_stages`.  The submit stamp is the one the
+        # --trace segments use (RingFuture.t_push).
+        self.stages: Optional[StageSet] = \
+            StageSet(WORKER_STAGES) if enabled() else None
         if trace:
             from raftsql_tpu.obs.export import TraceSegmentWriter
             self._obs = TraceSegmentWriter(
@@ -865,6 +891,16 @@ class RingClient:
         self._shm = None
         self._shm_hits = 0
         self._shm_fallbacks = 0
+        # Why a read did not come from the mapping, by reason
+        # (shm.py FALLBACK_REASONS + no_mapping/broken here): one
+        # counter each, their sum is _shm_fallbacks.  Reads run on the
+        # HTTP plane's pool threads, hence the lock.
+        self._reads_mu = threading.Lock()
+        self._shm_reasons: Optional[Dict[str, int]] = None
+        if self.stages is not None:
+            from raftsql_tpu.runtime.shm import FALLBACK_REASONS
+            self._shm_reasons = dict.fromkeys(
+                FALLBACK_REASONS + ("no_mapping", "broken"), 0)
         if os.environ.get("RAFTSQL_SHM_READS", "1") != "0":
             try:
                 from raftsql_tpu.runtime.shm import ShmSnapshotReader
@@ -881,6 +917,7 @@ class RingClient:
     _OP_NAMES = {OP_PUT: "ring.put", OP_GET: "ring.get",
                  OP_DOC: "ring.doc", OP_MEMBER: "ring.member",
                  OP_XFER: "ring.transfer", OP_RESHARD: "ring.reshard"}
+    _RTT_STAGE = {OP_PUT: "put.ring_rtt", OP_GET: "get.ring_rtt"}
 
     def _submit(self, op: int, group: int, flags: int, token: int,
                 body: bytes, deadline_s: Optional[float] = None,
@@ -897,17 +934,17 @@ class RingClient:
         wire_dl = 0 if deadline_ms is None else \
             max(1, int(time.monotonic() * 1000.0 + deadline_ms))
         fut = RingFuture()
+        fut.op = op
         with self._mu:
             req_id = self._next_id
             self._next_id += 1
             self._pending[req_id] = fut
             self._req_group[req_id] = group
-            if self._obs is not None:
-                # Submit stamp: the span closes when the completion
-                # pops (the client-visible ring round trip — HTTP
-                # parse happened just before, the ack rides after).
-                self._t0s[req_id] = (time.monotonic(),
-                                     self._OP_NAMES.get(op, "ring.op"))
+            # Submit stamp: the round trip closes when the completion
+            # pops (the client-visible ring round trip — HTTP parse
+            # happened just before, the ack rides after).  One stamp,
+            # two readers: the --trace segment and the ring_rtt stage.
+            fut.t_push = time.monotonic()
             ok = self._req.push(encode_request(op, req_id, group, flags,
                                                token, body, wire_dl))
         if not ok:
@@ -947,13 +984,16 @@ class RingClient:
                     if leader > self._wm.get(g, 0):
                         self._wm[g] = leader
                 if fut is not None:
+                    fut.t_done = now = time.monotonic()
                     fut._resolve(status, leader, body)
-                if self._obs is not None:
-                    got = self._t0s.pop(req_id, None)
-                    if got is not None:
-                        now = time.monotonic()
-                        self._obs.note(got[1], got[0], now - got[0],
-                                       tid=0, status=status)
+                    rtt = now - fut.t_push
+                    stage = self._RTT_STAGE.get(fut.op)
+                    if stage is not None and self.stages is not None:
+                        self.stages.stage(stage, rtt)
+                    if self._obs is not None:
+                        self._obs.note(
+                            self._OP_NAMES.get(fut.op, "ring.op"),
+                            fut.t_push, rtt, tid=0, status=status)
             if worked:
                 last = time.monotonic()
                 if self._obs is not None:
@@ -1019,7 +1059,7 @@ class RingClient:
         so a browned-out lease miss surfaces as Overloaded (429) here
         and the client backs off or retries another node."""
         from raftsql_tpu.overload import Overloaded
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
         if deadline_ms is not None:
             timeout = min(timeout, max(deadline_ms / 1000.0, 0.0))
         if info is not None:
@@ -1031,26 +1071,36 @@ class RingClient:
                  "follower": 4}.get(mode)
         if flags is None:
             raise ValueError(f"unknown read mode {mode!r}")
-        if self._shm is not None:
-            # Zero-round-trip fast path: serve from the mapped
-            # snapshot when it PROVES this mode's freshness contract
-            # (shm.py module docstring); anything unprovable — stale
-            # epoch, uncovered watermark, lapsed lease, SQL error —
-            # falls through to the authoritative ring path below.
-            got = None
+        # Zero-round-trip fast path: serve from the mapped snapshot
+        # when it PROVES this mode's freshness contract (shm.py module
+        # docstring); anything unprovable — stale epoch, uncovered
+        # watermark, lapsed lease, SQL error — falls through to the
+        # authoritative ring path below, counted by its reason.
+        shm = self._shm
+        got = None
+        if shm is None:
+            why = "no_mapping"
+        else:
             try:
-                got = self._shm.try_read(mode, group, query,
-                                         max(int(watermark), 0))
+                got = shm.try_read(mode, group, query,
+                                   max(int(watermark), 0))
+                why = shm.last_miss()
             except Exception:                           # noqa: BLE001
-                self._shm.close()      # release the mmap, don't leak
+                shm.close()            # release the mmap, don't leak
                 self._shm = None       # a broken mapping is dead
+                why = "broken"
+        with self._reads_mu:
             if got is not None:
-                rows, wm = got
                 self._shm_hits += 1
-                if wm > self._wm.get(group, 0):
-                    self._wm[group] = wm
-                return rows
-            self._shm_fallbacks += 1
+            else:
+                self._shm_fallbacks += 1
+                if self._shm_reasons is not None:
+                    self._shm_reasons[why] += 1
+        if got is not None:
+            rows, wm = got
+            if wm > self._wm.get(group, 0):
+                self._wm[group] = wm
+            return rows
         fut = self._submit(OP_GET, group, flags,
                            max(int(watermark), 0),
                            query.encode("utf-8"),
@@ -1069,7 +1119,7 @@ class RingClient:
         raise ValueError(text)
 
     def member_change(self, group: int, op: str, peer: int) -> dict:
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
         fut = self._submit(OP_MEMBER, group, 0, 0,
                            json.dumps({"group": group, "op": op,
                                        "peer": peer}).encode(),
@@ -1084,7 +1134,7 @@ class RingClient:
     def transfer(self, group: int, target: int) -> dict:
         """POST /transfer over the ring (op 5): arm a leadership
         transfer at the engine — same surface as RaftDB.transfer."""
-        from raftsql_tpu.runtime.db import NotLeaderError
+        from raftsql_tpu.runtime.errors import NotLeaderError
         fut = self._submit(OP_XFER, group, 0, 0,
                            json.dumps({"group": group,
                                        "target": target}).encode(),
@@ -1125,9 +1175,15 @@ class RingClient:
         and prom renders, so scripts/check_prom.py's round-trip check
         stays exact."""
         r = doc.setdefault("reads", {})
-        r["shm_hits"] = int(r.get("shm_hits", 0)) + self._shm_hits
-        r["shm_fallbacks"] = (int(r.get("shm_fallbacks", 0))
-                              + self._shm_fallbacks)
+        with self._reads_mu:
+            r["shm_hits"] = int(r.get("shm_hits", 0)) + self._shm_hits
+            r["shm_fallbacks"] = (int(r.get("shm_fallbacks", 0))
+                                  + self._shm_fallbacks)
+            if self._shm_reasons is not None:
+                r["shm_fallback_reasons"] = dict(self._shm_reasons)
+        if self.stages is not None:
+            # This worker's own legs of a request (obs/prof.py).
+            doc["worker_stages"] = self.stages.stages_doc()
         return doc
 
     def render_metrics(self) -> str:
@@ -1160,6 +1216,12 @@ class RingFuture:
     """AckFuture-compatible result carrier for ring round trips: PUT
     consumers use add_done_callback(err)/wait(err contract); raw
     consumers (GET/DOC) read (status, leader, body)."""
+
+    # Stamps of the worker's telemetry (RingClient._submit/_consume):
+    # the op, when its record was pushed, when its completion popped.
+    op = 0
+    t_push = 0.0
+    t_done = 0.0
 
     def __init__(self):
         self._evt = threading.Event()

@@ -413,6 +413,25 @@ class _GroupReplica:
         self.consumed = 0        # log bytes already fed
 
 
+# Why try_read() declined, one name per `return None` (last_miss()):
+# the caller counts them (reads.shm_fallback_reasons on /metrics).
+FALLBACK_REASONS = (
+    "not_select",        # not a SELECT: the engine's 400 class
+    "no_snapshot",       # seqlock/epoch: no consistent table
+    "log_full",          # the delta log overflowed: out for good, and
+    #                      the reason of every read after it
+    "keymap_epoch",      # reshard flipped the keyspace under us
+    "group_range",       # group outside the mapping
+    "behind_watermark",  # session: applied < the client's watermark
+    "behind_commit",     # follower/linear: applied < commit
+    "no_lease",          # linear: no provable leader lease
+    "stale_heartbeat",   # linear: publisher heartbeat too old
+    "bad_mode",          # unknown read mode
+    "catch_up",          # the log ran out before the replica's target
+    "sql_error",         # the SELECT raised: surface it via the ring
+)
+
+
 class ShmSnapshotReader:
     """Worker side: maps the snapshot region read-only and serves
     reads from per-group replicas.  Every public method FAILS CLOSED —
@@ -429,6 +448,8 @@ class ShmSnapshotReader:
             os.close(fd)
         self._lock = threading.Lock()
         self._dead = False
+        self._dead_why = "no_snapshot"   # what killed it: every later miss
+        self._why = threading.local()    # last_miss(), per thread
         hdr = self._read_header_raw()
         if hdr is None or hdr[0] != _MAGIC or hdr[1] != _VERSION:
             self.close()         # don't leak the mapping on a failed
@@ -521,42 +542,47 @@ class ShmSnapshotReader:
         local/session/follower/linear with the contracts documented in
         the module docstring."""
         from raftsql_tpu.models.sqlite_sm import is_select
+        miss = self._miss
         if not is_select(query):
-            return None          # engine's 400 class — and NEVER let a
-            #                      write mutate the worker-side replica
+            return miss("not_select")   # engine's 400 class — and NEVER
+            #                      let a write mutate the worker's replica
         snap = self._snapshot_table()
         if snap is None:
-            return None
+            # A reader that is out for good keeps giving the reason
+            # that put it out (a restarted engine's epoch counts as
+            # no_snapshot).
+            return miss(self._dead_why if self._dead else "no_snapshot")
         hdr, rows = snap
         if hdr[2] & _FLAG_LOG_FULL:
             self._dead = True                # overflow: permanently out
-            return None
+            self._dead_why = "log_full"
+            return miss("log_full")
         if hdr[9] != self._kmap_epoch:
             # The router moved the keyspace (reshard flip) under this
             # worker's cached mapping: fail closed to the ring path —
             # the engine routes by the CURRENT mapping — until the
             # worker refreshes and calls note_keymap_epoch.
-            return None
+            return miss("keymap_epoch")
         if not 0 <= group < self.num_groups:
-            return None
+            return miss("group_range")
         applied, commit, _base, lease_ns, _leader, _pad = rows[group]
         if mode == "local":
             target = applied
         elif mode == "session":
             if applied < watermark:
-                return None                  # engine blocks, we don't
+                return miss("behind_watermark")  # engine blocks, we don't
             target = max(applied, watermark)
         elif mode == "follower":
             if applied < commit:
-                return None
+                return miss("behind_commit")
             target = commit
         elif mode == "linear":
             if lease_ns <= 0 or time.monotonic_ns() >= lease_ns:
-                return None                  # no provable lease
+                return miss("no_lease")      # no provable lease
             if applied < commit:
-                return None
+                return miss("behind_commit")
             if time.monotonic_ns() - hdr[8] > PUB_STALE_NS:
-                return None                  # publisher heartbeat stale
+                return miss("stale_heartbeat")   # publisher heartbeat
             # Serve at `applied`, NOT the published commit column: the
             # apply thread publishes applied before acks fire, so it
             # covers every acked write, while commit is only restamped
@@ -567,19 +593,29 @@ class ShmSnapshotReader:
             # evidence sound.
             target = applied
         else:
-            return None
+            return miss("bad_mode")
         with self._lock:
             rep = self._replicas.get(group)
             if rep is None:
                 rep = _GroupReplica(group)
                 self._replicas[group] = rep
             if not self._catch_up(rep, target, hdr[6]):
-                return None
+                return miss("catch_up")
             try:
                 out = rep.sm.query(query)
             except Exception:                # noqa: BLE001
-                return None                  # surface SQL errors via ring
+                return miss("sql_error")     # surface SQL errors via ring
             return out, int(rep.sm.applied_index())
+
+    def _miss(self, reason: str) -> None:
+        """try_read's `return None`, remembering why (for this thread)."""
+        self._why.reason = reason
+        return None
+
+    def last_miss(self) -> str:
+        """Why this thread's last try_read() returned None: one of
+        FALLBACK_REASONS."""
+        return getattr(self._why, "reason", "no_snapshot")
 
     def leader_of(self, group: int) -> int:  # raftlint: fail-closed
         """Published 1-based leader hint (0 unknown), for worker-side

@@ -312,6 +312,10 @@ class WAL:
         # next restart.  Truncate to the last whole record before opening
         # for append (etcd's repair path does the same).
         self._bytes = self._repair_tail(self.path)
+        # Cumulative feeds of written(): bytes of the segments rotated
+        # away, and barriers that had something to flush.
+        self._rotated = 0
+        self.syncs = 0
         # Active-segment stats accumulate as we write; closed segments
         # written before this process are scanned lazily (compact()).
         self._active_stats = _SegStats()
@@ -370,6 +374,13 @@ class WAL:
     @property
     def is_native(self) -> bool:
         return self._lib is not None
+
+    def written(self) -> Tuple[int, int]:
+        """(bytes appended, barriers that flushed something) since this
+        handle opened, plus the active segment's size at open: both only
+        grow, so a caller on the writing thread takes differences (the
+        host plane's wal.bytes / wal.fsyncs counters)."""
+        return self._rotated + self._bytes, self.syncs
 
     # -- write path ------------------------------------------------------
 
@@ -736,6 +747,7 @@ class WAL:
         else:
             fsio.fsync_file(self._f)
         self.last_sync_s = _t.monotonic() - t0
+        self.syncs += 1
         if self.obs is not None:
             self.obs.note_event("wal.fsync", dur_s=self.last_sync_s,
                                 dir=self.dirname)
@@ -752,6 +764,7 @@ class WAL:
         self._active_stats = _SegStats()
         self._seq += 1
         self.path = os.path.join(self.dirname, f"wal-{self._seq}.log")
+        self._rotated += self._bytes
         self._bytes = 0
         self._open_active()
         _fsync_dir(self.dirname)
