@@ -102,12 +102,13 @@ _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 # The names every document carries from boot (a series that appears
 # only after its first sample cannot be told from one that was lost).
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
-                 "get.queue", "get.wait", "get.sql")
+                 "put.apply_batch", "get.queue", "get.wait", "get.sql")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
 ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
                    "intake.groups", "wal.records", "wal.bytes",
-                   "wal.hardstates", "wal.groups_written", "wal.fsyncs")
+                   "wal.hardstates", "wal.groups_written", "wal.fsyncs",
+                   "apply.runs", "apply.groups", "apply.fanout_runs")
 
 
 # Appends a deque may hold before the appending thread folds them in
@@ -257,6 +258,11 @@ class TickPhaseProfiler(StageSet):
         if len(self._new_ticks) >= FOLD_AT:
             with self._mu:
                 self._fold()
+
+    def count(self, counts: Sequence[Tuple[str, int]]) -> None:
+        """(counter, n) increments that no tick owns (an apply run's),
+        in one call."""
+        self.record_tick(-1, (), counts)
 
     def _fold(self) -> None:
         super()._fold()

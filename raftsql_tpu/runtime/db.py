@@ -29,10 +29,12 @@ from __future__ import annotations
 
 import json
 import logging
+import os
 import queue
 import threading
 import time
 from collections import defaultdict, deque
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Callable, Dict, Optional, Tuple
 
 from raftsql_tpu.models.base import StateMachine
@@ -50,6 +52,15 @@ from raftsql_tpu.utils.device import device_doc
 from raftsql_tpu.utils.metrics import LatencyTimer
 
 log = logging.getLogger("raftsql_tpu.db")
+
+# Width of the apply pool: the groups of one drained run apply side by
+# side on this many threads (RaftDB._apply_run).  A SQLite transaction
+# is a handful of short calls that each drop the interpreter, wait for
+# the database file and must win the interpreter back from the tick,
+# WAL and ring threads: 34 ms a one-statement transaction in a served
+# 10,000-group engine, nearly all of it waiting (PERF.md, PR 27).  The
+# waits of different groups overlap; the statements' own work is small.
+APPLY_WORKERS = min(8, os.cpu_count() or 1)
 
 
 def iter_plain_entries(base, datas):
@@ -159,6 +170,20 @@ def _commit_item_tops(item):
             yield item[0], item[1][-1][0]
     else:
         yield item[0], item[1]
+
+
+def _apply_group(sm: StateMachine, items: list) -> Tuple[list, float]:
+    """One group's batch of a run on its state machine: the error list
+    (one Optional[Exception] per item) and the wall time it took,
+    measured inside the thread that ran it.  Runs on the reader thread
+    or on an apply worker, so it touches nothing but `sm`."""
+    t0 = time.monotonic()
+    batch_fn = getattr(sm, "apply_batch", None)
+    if batch_fn is not None:
+        errs = batch_fn(items)
+    else:
+        errs = [sm.apply(qy, ix) for (qy, ix) in items]
+    return errs, time.monotonic() - t0
 
 
 class AckFuture:
@@ -288,6 +313,11 @@ class RaftDB:
         # apply consumer — commit + publish, before apply): the
         # histogram /metrics exports as propose_commit_p50/p95/p99_ms.
         self.latency_commit = LatencyTimer()
+        # The apply workers (see _apply_run); threads start with the
+        # first run that holds two groups, so a one-group deployment
+        # never has any.
+        self._apply_pool = ThreadPoolExecutor(
+            max_workers=APPLY_WORKERS, thread_name_prefix="raftdb-apply")
 
         # Synchronous replay consumption (db.go:40): apply until the
         # sentinel so reads see the replayed state before we return.
@@ -353,19 +383,30 @@ class RaftDB:
         the state machine itself skips entries at or below its durable
         applied index (atomically under its own lock, racing snapshot
         installs safely) and returns None — so skipped-but-committed
-        entries still resolve their acks."""
+        entries still resolve their acks.
+
+        The groups of a run apply SIDE BY SIDE on the apply workers and
+        are joined before anything else of the run happens: every group
+        has its own state machine and lock, a run holds one batch a
+        group and runs never overlap, so nothing orders them but this
+        loop.  A run of one group (every run of a one-group deployment,
+        and of the replay pass) applies here, with no hop."""
         commit_ts = time.monotonic()    # commit observation point
         per_g: Dict[int, list] = defaultdict(list)
         for (group, index, query) in run:
             per_g[group].append((query, index))
-        errs: Dict[int, list] = {}
-        for group, items in per_g.items():
-            sm = self._sms[group]
-            batch_fn = getattr(sm, "apply_batch", None)
-            if batch_fn is not None:
-                errs[group] = batch_fn(items)
-            else:
-                errs[group] = [sm.apply(qy, ix) for (qy, ix) in items]
+        fanout = len(per_g) > 1
+        if fanout:
+            futs = [self._apply_pool.submit(_apply_group, self._sms[g],
+                                            items)
+                    for g, items in per_g.items()]
+            wait(futs)                  # the barrier: all, then the rest
+            done = [f.result() for f in futs]   # a worker's raise, here
+        else:
+            done = [_apply_group(self._sms[g], items)
+                    for g, items in per_g.items()]
+        errs: Dict[int, list] = {
+            g: d[0] for g, d in zip(per_g, done)}
         if self.shm is not None:
             # Mirror the applied run into the worker-mapped snapshot
             # log BEFORE acks fire: a client whose PUT just acked may
@@ -381,7 +422,8 @@ class RaftDB:
                 self.shm = None
         tracer = self._node_tracer()
         prof = self._prof()
-        acked: Optional[list] = None if prof is None else []
+        acked: Optional[list] = None if prof is None else [
+            ("put.apply_batch", d[1]) for d in done]
         pos = {g: 0 for g in per_g}
         for (group, index, query) in run:
             err = errs[group][pos[group]]
@@ -391,6 +433,8 @@ class RaftDB:
             self._ack_one(group, query, err, commit_ts, acked)
         if acked:
             prof.stage_many(acked)
+            prof.count((("apply.runs", 1), ("apply.groups", len(per_g)),
+                        ("apply.fanout_runs", int(fanout))))
         for _ in run:
             self._maybe_compact()
 
@@ -1154,6 +1198,7 @@ class RaftDB:
             self.replica_plane = None
         err = self.pipe.close()
         self._reader.join(timeout=10)
+        self._apply_pool.shutdown()
         for sm in self._sms.values():
             sm.close()
         return err

@@ -369,6 +369,7 @@ def test_multistep_dispatch_equals_single_step_ticks(tmp_path):
                 assert list(ta_) == list(tb_) and list(da_) == list(db_)
         # ...identical published commit streams (as applied KV state).
         def applied_state(node):
+            node.publish_flush()    # the publish workers deliver async
             sms = [KVStateMachine() for _ in range(cfg.num_groups)]
             items, _ = drain(node, 0)
             for (g, idx, cmd) in items:

@@ -138,8 +138,9 @@ def served(tmp_path_factory):
 def test_metrics_document_holds_the_new_keys(served):
     doc = served.settled()
     assert set(doc["stages"]["put"]) == {"engine", "propose_commit",
-                                         "apply"}
+                                         "apply", "apply_batch"}
     assert set(doc["stages"]["get"]) == {"queue", "wait", "sql"}
+    assert set(doc["apply"]) == {"runs", "groups", "fanout_runs"}
     assert set(doc["intake"]) == {"backlog", "offered", "accepted",
                                   "groups"}
     assert set(doc["wal"]) == {"records", "bytes", "hardstates",
@@ -195,6 +196,16 @@ def test_wal_counters_grow_only_on_ticks_that_write(served):
     assert b["intake"] == a["intake"]
 
 
+def test_apply_counters_count_runs_and_their_groups(served):
+    doc = served.settled()
+    apply, batch = doc["apply"], doc["stages"]["put"]["apply_batch"]
+    # One apply_batch a group a run; every acknowledged write was in one.
+    assert batch["n"] == apply["groups"]
+    assert 0 <= apply["fanout_runs"] <= apply["runs"] <= apply["groups"] \
+        <= served.acked
+    assert 0 < batch["total_ms"] and batch["max_ms"] <= batch["total_ms"]
+
+
 def test_read_stages_and_fallback_reasons(served):
     before = served.settled()
     # A session read whose watermark is ahead of what is applied: the
@@ -242,6 +253,9 @@ def test_prom_round_trips_through_a_worker(served):
     assert {"raftsql_stages_put_engine_total_ms",
             "raftsql_worker_stages_put_edge_in_n",
             "raftsql_intake_accepted", "raftsql_wal_bytes",
+            "raftsql_stages_put_apply_batch_total_ms",
+            "raftsql_stages_put_apply_batch_n", "raftsql_apply_runs",
+            "raftsql_apply_groups", "raftsql_apply_fanout_runs",
             "raftsql_reads_shm_fallback_reasons_log_full"} <= names
     assert ("raftsql_tick_phase_ms_count",
             frozenset({("phase", "wal_hardstate")})) in samples
@@ -288,6 +302,32 @@ def test_worker_processes_never_import_jax(served):
 
 # -- in process ----------------------------------------------------------
 
+def test_apply_series_are_in_the_document_from_boot(tmp_path):
+    """Before the first write: the run counters and the apply_batch pair
+    are there and zero, in the JSON and in the exposition."""
+    from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
+    from raftsql_tpu.runtime.db import RaftDB
+    from raftsql_tpu.runtime.fused import FusedPipe
+
+    node = FusedClusterNode(mkcfg(groups=2), str(tmp_path / "data"))
+    rdb = RaftDB(lambda g: SQLiteStateMachine(
+        str(tmp_path / f"g{g}.db")), FusedPipe(node), num_groups=2)
+    try:
+        doc = json.loads(rdb.render_metrics())
+        assert doc["apply"] == {"runs": 0, "groups": 0, "fanout_runs": 0}
+        assert doc["stages"]["put"]["apply_batch"] == {
+            "total_ms": 0.0, "n": 0, "max_ms": 0.0}
+        check_prom = _load_check_prom()
+        samples = check_prom.parse_prom(rdb.render_metrics_prom())
+        assert not check_prom.check_round_trip(doc, samples)
+        for name in ("raftsql_apply_runs", "raftsql_apply_groups",
+                     "raftsql_apply_fanout_runs",
+                     "raftsql_stages_put_apply_batch_n"):
+            assert samples[(name, frozenset())] == 0
+    finally:
+        rdb.close()
+
+
 def test_prof_off_leaves_the_new_keys_out(tmp_path, monkeypatch):
     """RAFTSQL_PROF=0: no profiler in the engine, nothing recorded, no
     new key in the engine's document nor in what a worker folds in."""
@@ -311,7 +351,7 @@ def test_prof_off_leaves_the_new_keys_out(tmp_path, monkeypatch):
         assert rc.query("SELECT count(*) FROM t",
                         mode="linear").strip() == "|0|"
         doc = json.loads(rc.render_metrics())
-        for key in ("stages", "intake", "wal", "phase_profile",
+        for key in ("stages", "intake", "wal", "apply", "phase_profile",
                     "worker_stages"):
             assert key not in doc, key
         assert "shm_fallback_reasons" not in doc["reads"]
@@ -459,6 +499,39 @@ ENTRY %main.9 (a: s32[3,2]) -> s32[3,2] {
     assert sum(sums.values()) == pytest.approx(0.040)
 
 
+# -- the readers of the apply counters (benchmarks/layers/) -------------
+
+def _apply_scrape(k, groups, fanned, batch_ms, has=True):
+    """A scrape after k runs of `groups` groups each, `fanned` of every
+    four fanned out; `has=False` is a program from before the counters."""
+    doc = {"ticks": 10 * k, "stages": {"put": {"apply": {
+        "total_ms": 50.0 * k, "n": 5 * k, "max_ms": 20.0}}}}
+    if has:
+        doc["apply"] = {"runs": k, "groups": groups * k,
+                        "fanout_runs": fanned * k // 4}
+        doc["stages"]["put"]["apply_batch"] = {
+            "total_ms": batch_ms * groups * k, "n": groups * k,
+            "max_ms": 2 * batch_ms}
+    return {"t": float(k), "engine": doc, "workers": [doc]}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("apply_batch_ms", 12.5),           # 12.5 ms a group's batch
+    ("apply_groups_per_run", 7.0),      # 280 groups / 40 runs
+    ("apply_fanout_pct", 75.0),         # 30 of the window's 40 runs
+])
+def test_apply_readers_on_a_pair_of_scrapes(monkeypatch, name, want):
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmarks"))
+    reader = importlib.import_module("layers." + name)
+    before, after = (_apply_scrape(k, 7, 3, 12.5) for k in (20, 60))
+    assert reader.read(before, after, {}, None) == pytest.approx(want)
+    # Nothing applied in the window; a parent's scrapes (no such keys).
+    assert reader.read(after, after, {}, None) is None
+    old = [_apply_scrape(k, 7, 3, 12.5, has=False) for k in (20, 60)]
+    assert reader.read(old[0], old[1], {}, None) is None
+    assert reader.read(old[0], after, {}, None) is None
+
+
 # -- the mechanism -------------------------------------------------------
 
 def test_stage_set_pairs():
@@ -564,6 +637,10 @@ def test_a_record_never_waits_for_a_scrape():
     assert p.snapshot()["launch"]["total_ms"] == 2.0
     assert p.counters_doc()["wal"]["bytes"] == 7
     assert p.stages_doc()["put"]["apply"]["n"] == 1
+    p.count((("apply.runs", 1), ("apply.groups", 3)))   # owned by no tick
+    assert p.counters_doc()["apply"] == {"runs": 1, "groups": 3,
+                                         "fanout_runs": 0}
+    assert p.phase_ticks("launch") == [1]
 
 
 def test_rings_wrap_and_exports_see_only_filled_slots():
