@@ -305,12 +305,14 @@ def set_group_config(state: PeerState, g: jax.Array,
     )
 
 
-def restore_peer_state(cfg: RaftConfig, self_id: int,
-                       log_terms: dict, hard: dict,
-                       seed: int | None = None,
-                       starts: dict | None = None) -> PeerState:
-    """Rebuild boot state from a replayed WAL (the reference's RestartNode
-    path, raft.go:122-134, 161-163).
+def restored_leaves(cfg: RaftConfig, log_terms: dict, hard: dict,
+                    starts: dict | None = None) -> dict:
+    """The leaves a replayed WAL decides, as HOST arrays [G, ...] by
+    PeerState field name (every other leaf is `init_peer_state`'s).
+    Nothing here touches a device: the co-located runtimes stack these
+    per peer and place the stack under the layout their step runs in
+    (runtime/hostplane.py `_build_cluster_arrays`), so a restart never
+    stages a peer's state on the default device first.
 
     Args:
       log_terms: {group: [term of entry start+1, start+2, ...]}
@@ -322,7 +324,6 @@ def restore_peer_state(cfg: RaftConfig, self_id: int,
     """
     import numpy as np
 
-    st = init_peer_state(cfg, self_id, seed)
     g_, k_ = cfg.num_groups, cfg.term_table_slots
     # Honor the keep_ring stub contract: the restored pytree must have
     # the same leaf shapes as init_peer_state's, or the post-restart jit
@@ -367,11 +368,21 @@ def restore_peer_state(cfg: RaftConfig, self_id: int,
         # The snapshot floor is committed by construction; hard.commit can
         # trail it only if the marker postdates the last hardstate record.
         commit[g] = min(max(commit[g], start), log_len[g])
-    return st._replace(
-        term=jnp.asarray(term), voted_for=jnp.asarray(voted),
-        commit=jnp.asarray(commit), log_len=jnp.asarray(log_len),
-        log_term=jnp.asarray(window),
-        tbl_pos=jnp.asarray(tbl_pos), tbl_term=jnp.asarray(tbl_term))
+    return dict(term=term, voted_for=voted, commit=commit,
+                log_len=log_len, log_term=window, tbl_pos=tbl_pos,
+                tbl_term=tbl_term)
+
+
+def restore_peer_state(cfg: RaftConfig, self_id: int,
+                       log_terms: dict, hard: dict,
+                       seed: int | None = None,
+                       starts: dict | None = None) -> PeerState:
+    """Rebuild boot state from a replayed WAL (the reference's RestartNode
+    path, raft.go:122-134, 161-163): `init_peer_state` with the leaves
+    `restored_leaves` (which documents the arguments) decides."""
+    leaves = restored_leaves(cfg, log_terms, hard, starts)
+    return init_peer_state(cfg, self_id, seed)._replace(
+        **{k: jnp.asarray(v) for k, v in leaves.items()})
 
 
 @functools.partial(jax.jit, donate_argnums=0)

@@ -16,6 +16,8 @@ tick:
                recorded apart as
       launch     the dispatch half: stage inputs, launch the program
       readback   the half that blocks on the device's packed info
+      mesh_put   (mesh runtime only; absent elsewhere) the host's
+                 inputs laid over the mesh's shards, before launch
     wal_write  WAL entry/hardstate writes (the durable phase minus
                fsync), whose parts are also recorded apart as
       wal_plan      mirror metadata + the parallel-path plan
@@ -36,16 +38,19 @@ cover the newest `cap` samples, whenever they were taken.
 STAGES — named cumulative pairs `{total_ms, n, max_ms}` for the legs of
 a request (`stages.put.*`, `stages.get.*` on the engine;
 `worker_stages.*` in an HTTP worker, which folds its own into the
-document it relays).  No ring, no percentile, no per-request object.
+document it relays) and of a tick's commits on their way to the apply
+plane (`stages.publish.queue`: handed to a publish worker -> taken up
+by it, one sample a worker a tick).  No ring, no percentile, no per-request object.
 
 COUNTERS — plain cumulative integers (`intake.*`: what the host plane's
 queues held, offered to the device and got accepted, per tick summed;
 `wal.*`: records, bytes, hard states, groups and fsyncs of the durable
-phase).
+phase, the shard streams a sharded WAL flushed, the follower ranges
+handed to the mirror and those of them that took the Python mirror).
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
-opens each LEAF phase of the tick (pop, launch, readback, wal_plan,
-wal_append, wal_hardstate, fsync, publish) as a
+opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
+wal_plan, wal_append, wal_hardstate, fsync, publish) as a
 `jax.profiler.TraceAnnotation` named `tick.<phase>` carrying `tick=<n>`
 (`annotation()` is the one flag test a tick makes; `span()` gives the
 shared no-op context when it says no), so a device trace names the
@@ -94,21 +99,23 @@ import numpy as np
 # the serving plane's drain threads and is reported but excluded from
 # the tick-share denominators, as are the finer phases that lie INSIDE
 # dispatch (launch, readback) and wal_write (wal_*).
-PROF_PHASES = ("pop", "dispatch", "launch", "readback", "wal_write",
-               "wal_plan", "wal_append", "wal_hardstate", "fsync",
-               "publish", "ring_drain")
+PROF_PHASES = ("pop", "dispatch", "launch", "readback", "mesh_put",
+               "wal_write", "wal_plan", "wal_append", "wal_hardstate",
+               "fsync", "publish", "ring_drain")
 _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 
 # The names every document carries from boot (a series that appears
 # only after its first sample cannot be told from one that was lost).
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
-                 "put.apply_batch", "get.queue", "get.wait", "get.sql")
+                 "put.apply_batch", "get.queue", "get.wait", "get.sql",
+                 "publish.queue")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
 ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
                    "intake.groups", "wal.records", "wal.bytes",
                    "wal.hardstates", "wal.groups_written", "wal.fsyncs",
-                   "apply.runs", "apply.groups", "apply.fanout_runs")
+                   "wal.shard_syncs", "wal.mirror_rows",
+                   "wal.mirror_fallback_rows", "apply.runs", "apply.groups", "apply.fanout_runs")
 
 
 # Appends a deque may hold before the appending thread folds them in

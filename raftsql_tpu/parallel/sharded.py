@@ -99,12 +99,20 @@ def info_specs() -> StepInfo:
         floor=s2, timer_margin=P(PEERS_AXIS))
 
 
+def cluster_shardings(mesh: Mesh):
+    """(PeerState, Inbox) trees of the NamedSharding each leaf lives
+    under on `mesh`: what a jitted initialiser takes as `out_shardings`
+    and a host array as its `device_put` target."""
+    named = lambda specs: jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda s: isinstance(s, P))
+    return named(state_specs()), named(inbox_specs())
+
+
 def shard_cluster_arrays(mesh: Mesh, states: PeerState, inboxes: Inbox,
                          prop_n: jax.Array | None = None):
     """Place host-built stacked arrays onto the mesh with the right layout."""
-    put = lambda tree, specs: jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs)
-    out = [put(states, state_specs()), put(inboxes, inbox_specs())]
+    out = list(jax.device_put((states, inboxes), cluster_shardings(mesh)))
     if prop_n is not None:
         out.append(jax.device_put(prop_n, NamedSharding(mesh, _spec2())))
     return tuple(out)
@@ -158,13 +166,17 @@ def make_sharded_step_fn(cfg: RaftConfig, mesh: Mesh):
             lambda st, ib, pn, sid, ti: peer_step(
                 local_cfg, st, ib, pn, sid, goff, timer_inc=ti))(
                     states, inboxes, prop_n, self_ids, timer_inc)
-        delivered = jax.tree.map(lambda x: _route(x, pp), outboxes)
+        # Names as in core/cluster.py, for obs/scopes.py (metadata
+        # only); the mesh's own collectives are `raft.mesh_reduce`.
+        with jax.named_scope("raft.deliver"):
+            delivered = jax.tree.map(lambda x: _route(x, pp), outboxes)
         # timer_margin is a per-(peer, group-shard) min; the host wants
         # the per-peer min over ALL groups, so reduce it over the group
         # axis here — that also makes the P(PEERS_AXIS) out_spec's
         # replication-over-groups claim true by construction.
-        infos = infos._replace(timer_margin=jax.lax.pmin(
-            infos.timer_margin, GROUPS_AXIS))
+        with jax.named_scope("raft.mesh_reduce"):
+            infos = infos._replace(timer_margin=jax.lax.pmin(
+                infos.timer_margin, GROUPS_AXIS))
         return new_states, delivered, infos
 
     _step.p_loc = p_loc
@@ -229,10 +241,13 @@ def make_sharded_cluster_step_host(cfg: RaftConfig, mesh: Mesh):
                 | jnp.any((ib.a_type == MSG_REQ) & (ib.a_n > 0))
                 | jnp.any((ib.a_type == MSG_RESP) & ~ib.a_success))
         # OR across every mesh shard: replicated scalar (out_spec P()).
-        busy = jax.lax.pmax(
-            jax.lax.pmax(busy.astype(I32), PEERS_AXIS),
-            GROUPS_AXIS) > 0
-        return states, ib, jax.vmap(pack_info)(infos), busy
+        with jax.named_scope("raft.mesh_reduce"):
+            busy = jax.lax.pmax(
+                jax.lax.pmax(busy.astype(I32), PEERS_AXIS),
+                GROUPS_AXIS) > 0
+        with jax.named_scope("raft.pack"):
+            packed = jax.vmap(pack_info)(infos)
+        return states, ib, packed, busy
 
     mapped = jax.shard_map(
         _step, mesh=mesh,

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from raftsql_tpu.config import NO_XFER, RaftConfig
 from raftsql_tpu.core.cluster import (empty_cluster_inbox,
                                       init_cluster_state)
-from raftsql_tpu.core.state import (restore_peer_state,
+from raftsql_tpu.core.state import (restored_leaves,
                                     set_group_config_stacked,
                                     set_transfer_target_stacked)
 from raftsql_tpu.core.step import INFO_FIELDS
@@ -141,6 +141,13 @@ class ClusterHostPlane:
     # column); None on unsharded runtimes, a method on MeshClusterNode.
     _group_shard_of = None
 
+    # (prop_n, timer_inc) -> the same two as the step takes them, where
+    # the host's inputs have to be laid over several devices before the
+    # program can start: a method on MeshClusterNode, timed apart from
+    # `launch` as the `mesh_put` phase.  None where the step takes host
+    # arrays as they are (the fused runtime).
+    _put_inputs = None
+
     def __init__(self, cfg: RaftConfig, data_dir: str,
                  seed: Optional[int] = None,
                  group_commit: Optional[bool] = None):
@@ -179,6 +186,10 @@ class ClusterHostPlane:
         self._mirror_dur = np.zeros(P, np.float64)
         self._hard_dur = np.zeros(P, np.float64)
         self._wal_records = 0
+        # Follower ranges handed to the mirror, and those of them that
+        # took the Python two-pass mirror (wal.mirror_*).
+        self._wal_mirror = [0, 0]
+        self._wal_shard_syncs = 0       # last seen (sharded WALs only)
         self._wal_hard: List[Optional[np.ndarray]] = [None] * P
         self._wal_groups: set = set()
         self._wal_wrote: Optional[Tuple[int, int]] = None   # last seen
@@ -437,30 +448,30 @@ class ClusterHostPlane:
             if self._wal_exists(d):
                 self._wal_repair_epochs(d, self._epoch_no)
 
-        states = []
+        replayed: List[Optional[dict]] = []
         for p in range(P):
             d = self.dirs[p]
             if self._wal_exists(d):
-                states.append(self._replay_peer(p, d, seed))
+                replayed.append(self._replay_peer(p, d))
             else:
                 os.makedirs(d, exist_ok=True)
                 self.wals.append(self._new_wal(d))
                 self.plogs.append(
                     NativePayloadLog(G, self._plog_lib)
                     if self._plog_lib is not None else PayloadLog(G))
-                states.append(None)
+                replayed.append(None)
             # Replay-complete sentinel, replayed-or-not (the reference's
             # nil on commitC, raft.go:131-132).
             self._commit_qs[p].put(None)
-        if all(s is None for s in states):
-            self.states = init_cluster_state(cfg, seed)
-        else:
-            per_peer = [s if s is not None
-                        else restore_peer_state(cfg, p, {}, {}, seed)
-                        for p, s in enumerate(states)]
-            self.states = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                       *per_peer)
-        self.inboxes = empty_cluster_inbox(cfg)
+        restored = None
+        if any(r is not None for r in replayed):
+            # A peer without a WAL beside peers with one restores from
+            # nothing: the leaves a fresh peer has.
+            blank = restored_leaves(cfg, {}, {})
+            restored = {k: np.stack([(r or blank)[k] for r in replayed])
+                        for k in blank}
+        self.states, self.inboxes = self._build_cluster_arrays(
+            restored, seed)
         self._E = cfg.max_entries_per_msg
         self._gc_replay = None          # free the boot replay cache
 
@@ -474,6 +485,19 @@ class ClusterHostPlane:
         Implemented by the concrete runtime — the durable host plane in
         this class is identical either way."""
         raise NotImplementedError
+
+    def _build_cluster_arrays(self, restored: Optional[dict], seed):
+        """The boot (states, inboxes) where the step will run them:
+        the fresh cluster, with the leaves a replay decides (`restored`:
+        {PeerState field: HOST array [P, G, ...]}, None on a first
+        boot) laid over it.  Here: the default device.  The mesh
+        runtime builds every leaf under its NamedSharding instead, so
+        that no chip ever holds the whole cluster."""
+        states = init_cluster_state(self.cfg, seed)
+        if restored is not None:
+            states = states._replace(
+                **{k: jnp.asarray(v) for k, v in restored.items()})
+        return states, empty_cluster_inbox(self.cfg)
 
     def _new_wal(self, dirname: str) -> WAL:
         """Construct a peer's durable log handle.  The mesh runtime
@@ -529,10 +553,12 @@ class ClusterHostPlane:
 
     # -- boot -----------------------------------------------------------
 
-    def _replay_peer(self, p: int, d: str, seed):
+    def _replay_peer(self, p: int, d: str) -> dict:
         """Rebuild peer p from its WAL (RestartNode, raft.go:122-134):
-        device state, payload log, and the replayed committed prefix
-        published to its commit stream."""
+        payload log, the replayed committed prefix published to its
+        commit stream, and — returned, as host arrays — the leaves of
+        its device state that the replay decides
+        (core/state.py restored_leaves)."""
         logs = self._wal_replay(d)
         self._replayed_conf[p] = {g: gl.conf for g, gl in logs.items()
                                   if gl.conf is not None}
@@ -563,8 +589,8 @@ class ClusterHostPlane:
             # path in _publish_shard advances its cursor the same way).
             if datas and g_peer_publishes:
                 self._commit_qs[p].put((RAW_PLAIN, g, gl.start, datas))
-        return restore_peer_state(self.cfg, p, log_terms, hard, seed,
-                                  starts=starts or None)
+        return restored_leaves(self.cfg, log_terms, hard,
+                               starts=starts or None)
 
     # -- client plane ---------------------------------------------------
 
@@ -1120,13 +1146,18 @@ class ClusterHostPlane:
                 # never hang) but publish nothing more: the CLOSED
                 # sentinel must stay the queues' last item.
                 if item is not None and self.error is None:
-                    pinfo, ptick = item
+                    pinfo, ptick, t_enq = item
                     # Per-shard publish workers tag their shard id —
                     # the mesh runtime's N workers each get their own
                     # Perfetto phase track.
                     prof = self.prof
                     ann = prof.annotation() if prof is not None else None
                     t0 = _t.monotonic()
+                    if prof is not None:
+                        # Handed over by the tick -> taken up by this
+                        # worker (the wait for room in a full queue
+                        # included): inside a write's propose_commit.
+                        prof.stage("publish.queue", t0 - t_enq)
                     with span(ann, "tick.publish", ptick):
                         self._publish_shard(pinfo, shard)
                     dur = _t.monotonic() - t0
@@ -1147,8 +1178,11 @@ class ClusterHostPlane:
         """Hand a durable tick's packed info to every publish worker
         (each delivers only its own group block).  The owning tick id
         (`self._prof_tick`, set by the caller) rides the queue item so
-        the workers' publish phases attribute to the right tick."""
-        item = (pinfo, self._prof_tick)
+        the workers' publish phases attribute to the right tick, and
+        the hand-over's time so each worker can say how long the item
+        waited for it (`stages.publish.queue`)."""
+        import time as _t
+        item = (pinfo, self._prof_tick, _t.monotonic())
         for q in self._pub_qs:
             q.put(item)
 
@@ -1259,15 +1293,24 @@ class ClusterHostPlane:
                 self._transfer_arm()     # latch visible to THIS dispatch
             # Snapshot _queued: _build_prop_n may re-route into the set.
             prop_n = self._build_prop_n(self._steps)
-        tb = _t.monotonic()
+        tb = tl = _t.monotonic()
+        ti = self.timer_inc
+        put = self._put_inputs
+        if put is not None:
+            # A leaf of its own, not inside tick.launch: a gap goes to
+            # the event that covers most of it (obs/prof.py).
+            with span(ann, "tick.mesh_put", tick_no):
+                dev_in = put(prop_n, ti)
+            tl = _t.monotonic()
+        else:
+            dev_in = (prop_n, ti)
         with span(ann, "tick.launch", tick_no):
-            ti = self.timer_inc
             if ti is not None:
                 # Skew accounting: how far this tick's timer advances
                 # deviate from lockstep, per peer, summed.
                 self.metrics.faults_skew_ticks += int(
                     np.abs(np.asarray(ti, np.int64) - 1).sum())
-            pinfo_dev, busy_dev = self._device_step(prop_n, ti)
+            pinfo_dev, busy_dev = self._device_step(*dev_in)
             if self.ring is not None:
                 # Device-plane event ring: one extra small fused program
                 # over arrays already resident (tracing-on cost only);
@@ -1348,17 +1391,21 @@ class ClusterHostPlane:
         if prof is not None:
             # `dispatch` is launch + readback (the host blocks on the
             # device completing this tick's program): a sample each,
-            # and each half under its own name too.  intake.*: what
-            # _build_prop_n found queued and offered, what
-            # _stage_ranges popped as accepted.
-            ld, rd = t1 - tb, t3 - t2b
+            # and each half under its own name too; on a mesh the
+            # inputs' way to their shards is a third part, `mesh_put`.
+            # intake.*: what _build_prop_n found queued and offered,
+            # what _stage_ranges popped as accepted.
+            ld, rd = t1 - tl, t3 - t2b
             it = self._intake
+            samples = [("pop", t0, tb - t0), ("dispatch", tl, ld),
+                       ("launch", tl, ld), ("dispatch", t2b, rd),
+                       ("readback", t2b, rd),
+                       ("pop", ts0, _t.monotonic() - ts0)]
+            if put is not None:
+                samples += (("dispatch", tb, tl - tb),
+                            ("mesh_put", tb, tl - tb))
             prof.record_tick(
-                tick_no,
-                (("pop", t0, tb - t0), ("dispatch", tb, ld),
-                 ("launch", tb, ld), ("dispatch", t2b, rd),
-                 ("readback", t2b, rd),
-                 ("pop", ts0, _t.monotonic() - ts0)),
+                tick_no, samples,
                 (("intake.backlog", it[0]), ("intake.offered", it[1]),
                  ("intake.groups", it[2]), ("intake.accepted", it[3]))
                 if it[2] else ())
@@ -1570,17 +1617,28 @@ class ClusterHostPlane:
                 groups.update(changed.tolist())
                 self._wal_hard[p] = None
         records, self._wal_records = self._wal_records, 0
+        mirror = self._wal_mirror
+        rows, fell_back = mirror
+        mirror[0] = mirror[1] = 0
         wrote0, wrote1 = self._wal_wrote, self._wal_written()
-        if not (records or hard or wrote1 != wrote0):
+        if not (records or hard or rows or wrote1 != wrote0):
             return ()
         self._wal_wrote = wrote1
         n_groups = len(groups)
         groups.clear()
+        # A sharded WAL (runtime/mesh.py ShardedWAL) counts the shard
+        # streams its barriers flushed; a WAL of one stream has none.
+        shard0 = self._wal_shard_syncs
+        shard1 = self._wal_shard_syncs = sum(
+            getattr(w, "shard_syncs", 0) for w in self.wals)
         return (("wal.records", records),
                 ("wal.bytes", wrote1[0] - wrote0[0]),
                 ("wal.hardstates", hard),
                 ("wal.groups_written", n_groups),
-                ("wal.fsyncs", wrote1[1] - wrote0[1]))
+                ("wal.fsyncs", wrote1[1] - wrote0[1]),
+                ("wal.shard_syncs", shard1 - shard0),
+                ("wal.mirror_rows", rows),
+                ("wal.mirror_fallback_rows", fell_back))
 
     def _stage_ranges(self, pinfo: np.ndarray) -> list:
         """Build one step's phase-2a write plan — per peer the
@@ -1726,7 +1784,9 @@ class ClusterHostPlane:
                 m_newlen.extend(sub[:, _C["new_log_len"]].tolist())
             if counting and m_peer:
                 # wal.records / wal.groups_written: mirrored entries
-                # (an empty heartbeat ack mirrors none).
+                # (an empty heartbeat ack mirrors none); every range,
+                # empty or not, is a row the mirror is handed.
+                self._wal_mirror[0] += len(m_peer)
                 self._wal_records += sum(m_count)
                 self._wal_groups.update(
                     [g for g, c in zip(m_g, m_count) if c])
@@ -1880,6 +1940,8 @@ class ClusterHostPlane:
                                       m_g, m_start, m_count, m_newlen):
                     # Python two-pass fallback: ALL source reads first (the
                     # staging contract), then one batched write per peer.
+                    if counting:
+                        self._wal_mirror[1] += len(m_peer)
                     reads = [self.plogs[s].slice_columns(g, st, c)
                              if c else ([], [])
                              for (s, g, st, c) in zip(m_src, m_g, m_start,

@@ -53,15 +53,16 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from raftsql_tpu.config import RaftConfig
+from raftsql_tpu.core.cluster import (empty_cluster_inbox,
+                                      init_cluster_state)
 from raftsql_tpu.parallel.sharded import (GROUPS_AXIS, PEERS_AXIS,
+                                          cluster_shardings,
                                           make_mesh,
                                           make_sharded_cluster_step_host,
                                           prop_spec,
-                                          shard_cluster_arrays,
                                           timer_spec)
 from raftsql_tpu.runtime.hostplane import ClusterHostPlane
 from raftsql_tpu.storage.wal import (DEFAULT_SEGMENT_BYTES, WAL,
@@ -268,6 +269,14 @@ class ShardedWAL:
         for s in self.shards:
             s.sync()
 
+    @property
+    def shard_syncs(self) -> int:
+        """Shard streams that had something to flush at a barrier,
+        cumulative over the shards this host writes (the host plane's
+        wal.shard_syncs counter; a WAL of one stream has no such
+        attribute)."""
+        return sum(s.syncs for s in self.shards if isinstance(s, WAL))
+
     def written(self) -> Tuple[int, int]:
         """Summed over the shards this host writes (WAL.written)."""
         got = [s.written() for s in self.shards if isinstance(s, WAL)]
@@ -292,11 +301,12 @@ class MeshClusterNode(ClusterHostPlane):
     """The durable runtime SPMD over a multi-chip mesh.
 
     Same host plane as FusedClusterNode (runtime/hostplane.py) — WALs,
-    payload mirroring, fsync-before-next-dispatch, publish — with three
+    payload mirroring, fsync-before-next-dispatch, publish — with four
     mesh-specific choices (see module docstring): the shard_map'd
-    device step with per-peer `timer_inc` sharded alongside, per-peer
-    WALs split per group shard (ShardedWAL), and one publish worker per
-    group shard.
+    device step with per-peer `timer_inc` sharded alongside, a boot
+    state whose every leaf is born on its shards, per-peer WALs split
+    per group shard (ShardedWAL), and one publish worker per group
+    shard.
     """
 
     # The per-shard WAL layout supersedes the single-file group-commit
@@ -326,11 +336,7 @@ class MeshClusterNode(ClusterHostPlane):
         self._prop_spec = NamedSharding(mesh, prop_spec())
         self._placement: Optional[dict] = None
         self._ti_ones = jax.device_put(
-            jnp.ones((cfg.num_peers,), jnp.int32), self._ti_spec)
-        # Lay the freshly built (or replayed) cluster state out over the
-        # mesh; subsequent steps keep the sharding (donated in/out).
-        self.states, self.inboxes = shard_cluster_arrays(
-            mesh, self.states, self.inboxes)
+            np.ones((cfg.num_peers,), np.int32), self._ti_spec)
 
     @staticmethod
     def _check_mesh_meta(data_dir: str, gg: int) -> None:
@@ -384,6 +390,26 @@ class MeshClusterNode(ClusterHostPlane):
     def _wal_repair_epochs(self, dirname: str, committed: int) -> None:
         ShardedWAL.repair_epochs(dirname, committed, self._gg)
 
+    def _build_cluster_arrays(self, restored: Optional[dict], seed):
+        """Every leaf of the boot state is BORN under the sharding the
+        step keeps it in (donated in and out from then on): the fresh
+        cluster by one jitted initialiser whose outputs are sharded, so
+        each device fills in its own [P/pp, G/gg, ...] blocks, and what
+        a replay decides straight from host memory to its shards.  No
+        device ever holds a whole [P, G, ...] array, on a first boot or
+        on a restart."""
+        cfg = self.cfg
+        st_sh, ib_sh = cluster_shardings(self.mesh)
+        states, inboxes = jax.jit(
+            lambda: (init_cluster_state(cfg, seed),
+                     empty_cluster_inbox(cfg)),
+            out_shardings=(st_sh, ib_sh))()
+        if restored is not None:
+            states = states._replace(
+                **{k: jax.device_put(v, getattr(st_sh, k))
+                   for k, v in restored.items()})
+        return states, inboxes
+
     def _pub_shard_groups(self) -> List[np.ndarray]:
         # One ordered publish worker per group shard, each owning the
         # shard's contiguous group block (disjoint by construction, so
@@ -393,21 +419,26 @@ class MeshClusterNode(ClusterHostPlane):
 
     # -- the device step ------------------------------------------------
 
-    def _device_step(self, prop_n: np.ndarray,
-                     timer_inc: Optional[np.ndarray] = None):
-        """One SPMD tick over the mesh.  `timer_inc` is the per-peer
-        [P] timer advance (chaos skew schedules; None = lockstep) —
-        sharded over the `peers` axis so each device block advances
-        exactly its own peers' clocks, bit-identically to the fused
-        runtime's cluster_step."""
+    def _put_inputs(self, prop_n: np.ndarray,
+                    timer_inc: Optional[np.ndarray] = None):
+        """The tick's host inputs on their shards (the host plane times
+        this apart, as `mesh_put`): `prop_n` [P, G] over the mesh and
+        `timer_inc`, the per-peer [P] timer advance (chaos skew
+        schedules; None = lockstep), over the `peers` axis, so each
+        device block advances exactly its own peers' clocks,
+        bit-identically to the fused runtime's cluster_step."""
         if timer_inc is None:
             ti = self._ti_ones
         else:
             ti = jax.device_put(np.asarray(timer_inc, np.int32),
                                 self._ti_spec)
+        return jax.device_put(prop_n, self._prop_spec), ti
+
+    def _device_step(self, prop_n: jax.Array, timer_inc: jax.Array):
+        """One SPMD tick over the mesh, on inputs `_put_inputs` laid
+        out."""
         self.states, self.inboxes, pinfo_dev, busy = self._sharded_step(
-            self.states, self.inboxes,
-            jax.device_put(prop_n, self._prop_spec), ti)
+            self.states, self.inboxes, prop_n, timer_inc)
         if self._placement is None:
             self._placement = self._observe_placement(pinfo_dev)
         return pinfo_dev, busy

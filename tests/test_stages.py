@@ -144,7 +144,10 @@ def test_metrics_document_holds_the_new_keys(served):
     assert set(doc["intake"]) == {"backlog", "offered", "accepted",
                                   "groups"}
     assert set(doc["wal"]) == {"records", "bytes", "hardstates",
-                               "groups_written", "fsyncs"}
+                               "groups_written", "fsyncs", "shard_syncs",
+                               "mirror_rows", "mirror_fallback_rows"}
+    assert set(doc["stages"]["publish"]) == {"queue"}
+    assert "mesh_put" not in doc["phase_profile"]   # the mesh's alone
     assert {"launch", "readback", "wal_plan", "wal_append",
             "wal_hardstate", "dispatch", "wal_write"} \
         <= set(doc["phase_profile"])
@@ -182,6 +185,12 @@ def test_intake_counts_every_accepted_entry(served):
     assert doc["proposals"] == intake["accepted"]
 
 
+def _wrote(wal):
+    """The wal.* counters of what was WRITTEN (mirror_rows counts what
+    the mirror was handed, empty heartbeat acks too)."""
+    return {k: v for k, v in wal.items() if not k.startswith("mirror_")}
+
+
 def test_wal_counters_grow_only_on_ticks_that_write(served):
     a = served.settled()
     # Every entry lands in 3 peers' logs (+ one no-op a group).
@@ -192,8 +201,14 @@ def test_wal_counters_grow_only_on_ticks_that_write(served):
     time.sleep(0.5)                     # idle: heartbeats only
     b = served.metrics()
     assert b["ticks"] > a["ticks"]
-    assert b["wal"] == a["wal"]
+    assert _wrote(b["wal"]) == _wrote(a["wal"])
     assert b["intake"] == a["intake"]
+    # What an idle tick does cost: every follower's (empty) heartbeat
+    # ack is a row the mirror is handed, and the served deployment's
+    # payload log is the Python one, so each takes the Python mirror.
+    assert b["wal"]["mirror_rows"] > a["wal"]["mirror_rows"]
+    assert b["wal"]["mirror_fallback_rows"] == b["wal"]["mirror_rows"]
+    assert b["wal"]["shard_syncs"] == 0     # one stream, no shards
 
 
 def test_apply_counters_count_runs_and_their_groups(served):
@@ -414,7 +429,7 @@ def test_intake_and_wal_counters_in_process(tmp_path):
 
         def counters():
             doc = node.prof.counters_doc()
-            return doc["intake"], doc["wal"]
+            return doc["intake"], _wrote(doc["wal"])
 
         for _ in range(5):              # the no-ops reach every peer
             node.tick()
