@@ -46,7 +46,9 @@ COUNTERS — plain cumulative integers (`intake.*`: what the host plane's
 queues held, offered to the device and got accepted, per tick summed;
 `wal.*`: records, bytes, hard states, groups and fsyncs of the durable
 phase, the shard streams a sharded WAL flushed, the follower ranges
-handed to the mirror and those of them that took the Python mirror).
+handed to the mirror, those of them that took the Python mirror, and
+`wal.mirror_skipped_rows`: the accepted appends that could change no
+log, empty heartbeat acks, and were dropped before any was listed).
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
@@ -115,7 +117,8 @@ ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
                    "intake.groups", "wal.records", "wal.bytes",
                    "wal.hardstates", "wal.groups_written", "wal.fsyncs",
                    "wal.shard_syncs", "wal.mirror_rows",
-                   "wal.mirror_fallback_rows", "apply.runs", "apply.groups", "apply.fanout_runs")
+                   "wal.mirror_fallback_rows", "wal.mirror_skipped_rows",
+                   "apply.runs", "apply.groups", "apply.fanout_runs")
 
 
 # Appends a deque may hold before the appending thread folds them in

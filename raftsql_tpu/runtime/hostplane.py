@@ -186,9 +186,11 @@ class ClusterHostPlane:
         self._mirror_dur = np.zeros(P, np.float64)
         self._hard_dur = np.zeros(P, np.float64)
         self._wal_records = 0
-        # Follower ranges handed to the mirror, and those of them that
-        # took the Python two-pass mirror (wal.mirror_*).
-        self._wal_mirror = [0, 0]
+        # Follower ranges handed to the mirror, those of them that
+        # took the Python two-pass mirror, and the accepted appends
+        # that never became a range because they could change no log
+        # (wal.mirror_rows, .mirror_fallback_rows, .mirror_skipped_rows).
+        self._wal_mirror = [0, 0, 0]
         self._wal_shard_syncs = 0       # last seen (sharded WALs only)
         self._wal_hard: List[Optional[np.ndarray]] = [None] * P
         self._wal_groups: set = set()
@@ -1618,10 +1620,10 @@ class ClusterHostPlane:
                 self._wal_hard[p] = None
         records, self._wal_records = self._wal_records, 0
         mirror = self._wal_mirror
-        rows, fell_back = mirror
-        mirror[0] = mirror[1] = 0
+        rows, fell_back, skipped = mirror
+        mirror[0] = mirror[1] = mirror[2] = 0
         wrote0, wrote1 = self._wal_wrote, self._wal_written()
-        if not (records or hard or rows or wrote1 != wrote0):
+        if not (records or hard or rows or skipped or wrote1 != wrote0):
             return ()
         self._wal_wrote = wrote1
         n_groups = len(groups)
@@ -1638,7 +1640,8 @@ class ClusterHostPlane:
                 ("wal.fsyncs", wrote1[1] - wrote0[1]),
                 ("wal.shard_syncs", shard1 - shard0),
                 ("wal.mirror_rows", rows),
-                ("wal.mirror_fallback_rows", fell_back))
+                ("wal.mirror_fallback_rows", fell_back),
+                ("wal.mirror_skipped_rows", skipped))
 
     def _stage_ranges(self, pinfo: np.ndarray) -> list:
         """Build one step's phase-2a write plan — per peer the
@@ -1730,11 +1733,32 @@ class ClusterHostPlane:
             out.append((r_g, r_start, r_count, r_term, w_d))
         return out
 
+    def _mirror_keep(self, pinfo: np.ndarray
+                     ) -> Tuple[int, Tuple[np.ndarray, np.ndarray]]:
+        """One step's accepted appends: how many `(peer, group)` took
+        one, and the (peers, groups) index arrays of those that go to
+        the mirror, peer-major, groups ascending: the ones that carry
+        an entry.  An EMPTY accepted append (a heartbeat's ack: every
+        follower of a steady group, every tick) can change no log.  On
+        the device (core/step.py Phase 4) `a_n == 0` gives no overlap,
+        so no conflict, and `log_len = max(log_len, prev)` with
+        `prev <= log_len` by `prev_ok`: its `new_log_len` is the length
+        the log had, which is the payload log's (the mirror of the
+        device's log).  On the host such a row writes no record, puts
+        nothing and truncates to the length that is there.
+        tests/test_mirror_rows.py holds that, row by dropped row.
+        `app_n` is 0 wherever no append was accepted.  Nothing more
+        of [P, G] size than this: what a numpy call on a column costs
+        the tick thread of a served engine is in PERF.md (PR 29)."""
+        n_took = int(np.count_nonzero(pinfo[:, :, _C["app_from"]] >= 0))
+        return n_took, np.nonzero(pinfo[:, :, _C["app_n"]])
+
     def _durable_phases(self, pinfo: np.ndarray, final: bool,
                         staged: list) -> bool:
         """The durable host phases for ONE step's packed info [P,G,C]:
         phase 1 collects mirror METADATA (peer, src, group, start,
-        count, new_len) with no reads; phase 2a writes leader appends
+        count, new_len) with no reads, of the accepted appends that
+        can change a log (_mirror_keep); phase 2a writes leader appends
         (fresh-leader no-ops + accepted proposals, pre-popped into
         `staged` by _stage_ranges) as uniform-term RANGES; phase 2b
         mirrors follower appends.  Mirror-source
@@ -1764,29 +1788,21 @@ class ClusterHostPlane:
         split = self._wal_split
         ta = _t.monotonic()
         with span(ann, "tick.wal_plan", ptick):
-            m_peer: List[int] = []
-            m_src: List[int] = []
-            m_g: List[int] = []
-            m_start: List[int] = []
-            m_count: List[int] = []
-            m_newlen: List[int] = []
-            for p in range(P):
-                col = pinfo[p]
-                accepted = np.nonzero(col[:, _C["app_from"]] >= 0)[0]
-                if not accepted.size:
-                    continue
-                sub = col[accepted]
-                m_peer.extend([p] * accepted.size)
-                m_g.extend(accepted.tolist())
-                m_src.extend(sub[:, _C["app_from"]].tolist())
-                m_start.extend(sub[:, _C["app_start"]].tolist())
-                m_count.extend(sub[:, _C["app_n"]].tolist())
-                m_newlen.extend(sub[:, _C["new_log_len"]].tolist())
-            if counting and m_peer:
+            # Only the accepted appends that can change a log become
+            # rows, picked out BEFORE anything is listed.
+            n_took, kept = self._mirror_keep(pinfo)
+            m_peer, m_g = kept[0].tolist(), kept[1].tolist()
+            sub = pinfo[kept]                           # [rows, C]
+            m_src: List[int] = sub[:, _C["app_from"]].tolist()
+            m_start: List[int] = sub[:, _C["app_start"]].tolist()
+            m_count: List[int] = sub[:, _C["app_n"]].tolist()
+            m_newlen: List[int] = sub[:, _C["new_log_len"]].tolist()
+            if counting and n_took:
                 # wal.records / wal.groups_written: mirrored entries
-                # (an empty heartbeat ack mirrors none); every range,
-                # empty or not, is a row the mirror is handed.
+                # (an empty heartbeat ack mirrors none); the ranges the
+                # mirror is handed, and the appends that made none.
                 self._wal_mirror[0] += len(m_peer)
+                self._wal_mirror[2] += n_took - len(m_peer)
                 self._wal_records += sum(m_count)
                 self._wal_groups.update(
                     [g for g, c in zip(m_g, m_count) if c])
@@ -1805,6 +1821,8 @@ class ClusterHostPlane:
                     c for p, c in zip(m_peer, m_count)
                     if c and p in self.witness_peers)
 
+        # Any accepted append, empty or not, keeps the tick active.
+        tick_active = bool(n_took)
         # Phase 2a: leader appends (fresh-leader no-ops + accepted
         # proposals) as uniform-term RANGES per peer — the write plan
         # was staged (and the payloads popped) by _stage_ranges; one
@@ -1812,7 +1830,6 @@ class ClusterHostPlane:
         # payload-log range (wal.append_ranges_uniform); the fallback
         # expands ranges to per-entry numpy columns for the classic
         # two-call path.
-        tick_active = bool(m_peer)
         tb = _t.monotonic()
         split[0] += tb - ta
         with span(ann, "tick.wal_append", ptick):
