@@ -21,11 +21,12 @@ native:
 	          lib = load_native_wal(); \
 	          print('native wal:', 'ok' if lib else 'UNAVAILABLE')"
 
-# Build-check the native GROUP-COMMIT path (wal.cc walplog_* group
-# bias): write through per-peer views of one shared native WAL, replay,
-# and assert the per-peer split round-trips.  Fails if the toolchain is
-# present but the group-commit ABI is broken; degrades to a SKIP where
-# no compiler exists (the Python backend covers those hosts).
+# Build-check the native GROUP-COMMIT path (the views' group bias over
+# wal.cc): write through per-peer views of one shared native WAL,
+# replay, and assert the per-peer split round-trips.  Fails if the
+# toolchain is present but the group-commit ABI is broken; degrades to
+# a SKIP where no compiler exists (the Python backend covers those
+# hosts).
 native-check:
 	$(PY) scripts/check_native_gc.py
 

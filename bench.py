@@ -1411,29 +1411,10 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
     tmp = tempfile.mkdtemp(prefix=f"bench-{runtime}-")
     # BENCH_SM=sqlite: the reference-parity apply engine (one SQLite
     # database per group, group-committed transactions) — the FULL
-    # product stack on the fused runtime.  Default: the C++ KV plane
-    # (models/kv_native.py) applied straight from the native payload
-    # log — the measured fastest durable deployment (525k vs 329k
-    # commits/s at G=1000/E=32 on one CPU core).  BENCH_DURABLE_APPLY=
-    # python forces the Python-resident KV consumer; =native makes a
-    # missing toolchain an error instead of a fallback.
-    apply_req = os.environ.get("BENCH_DURABLE_APPLY", "")
-    if apply_req == "native" and os.environ.get("BENCH_SM") == "sqlite":
-        raise RuntimeError(
-            "BENCH_DURABLE_APPLY=native conflicts with BENCH_SM=sqlite "
-            "(the native plane is the KV apply engine)")
-    # The mesh runtime publishes from one worker PER GROUP SHARD; the
-    # in-process C KV apply is a single-consumer design, so the mesh
-    # rung defaults to the queue-drain apply path (opt back in with
-    # BENCH_DURABLE_APPLY=native once the C plane is audited for
-    # concurrent disjoint-group applies).
-    native_apply = (apply_req != "python"
-                    and os.environ.get("BENCH_SM") != "sqlite"
-                    and (runtime != "mesh" or apply_req == "native"))
-    if native_apply:
-        os.environ["RAFTSQL_FUSED_NATIVE_PLOG"] = "1"
-    sm_kind = ("sqlite" if os.environ.get("BENCH_SM") == "sqlite"
-               else ("kv-native" if native_apply else "kv"))
+    # product stack on the fused runtime.  Default: the Python KV
+    # state machine.  Either way the commit stream is drained off peer
+    # 0's queue and applied here, as server.main's RaftDB does.
+    sm_kind = os.environ.get("BENCH_SM", "kv")
     if sm_kind == "sqlite":
         sms = [SQLiteStateMachine(os.path.join(tmp, f"sm-{g}.db"))
                for g in range(groups)]
@@ -1506,16 +1487,6 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
             group_commit=os.environ.get(
                 "BENCH_WAL_GROUP_COMMIT", "1") == "1")
     node.publish_peers = {0}       # the drain consumes peer 0's stream
-    kv_native = None
-    if native_apply and not hasattr(node.plogs[0], "handle"):
-        if apply_req == "native":
-            raise RuntimeError(
-                "BENCH_DURABLE_APPLY=native needs the native plog")
-        native_apply, sm_kind = False, "kv"     # toolchain-less host
-    if native_apply:
-        from raftsql_tpu.models.kv_native import NativeKV
-        kv_native = NativeKV(groups, node._plog_lib)
-        node.native_kv = kv_native
     try:
         for t in range(40 * cfg.election_ticks):
             node.tick()
@@ -1562,7 +1533,6 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
                 nonlocal applied
                 applied += drain(node, apply=True)
 
-            base_applied = kv_native.total_applied if kv_native else 0
             node.overlap_hook = hook
             t0 = time.perf_counter()
             for _ in range(ticks):
@@ -1572,10 +1542,6 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
             # commit only once it reached the apply plane.
             node.publish_flush()
             committed = applied + drain(node, apply=True)
-            if kv_native is not None:
-                # The C plane applied inside _publish; the queue drain
-                # above only flushed stragglers (normally zero).
-                committed += kv_native.total_applied - base_applied
             dt = time.perf_counter() - t0
             rate = committed / dt
             _log(f"  {committed} fused durable commits in {dt:.3f}s -> "
@@ -1593,25 +1559,8 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
         lats: list = []
         for _ in range(8):
             node.tick()
-            if drain(node, apply=True) == 0 and kv_native is None:
+            if drain(node, apply=True) == 0:
                 break
-            # native mode: run the full 8 flush ticks (the queue is
-            # always empty; prev_ap below absorbs the pipeline tail).
-
-        if kv_native is not None:
-            # The C plane applies inside _publish: ack by watching each
-            # active group's applied index advance.
-            prev_ap = [kv_native.applied_index(g)
-                       for g in range(lat_active)]
-
-        def settle_native():
-            now2 = time.perf_counter()
-            for g in range(lat_active):
-                a = kv_native.applied_index(g)
-                fifo = t0q[g]
-                for _ in range(min(a - prev_ap[g], len(fifo))):
-                    lats.append(now2 - fifo.popleft())
-                prev_ap[g] = a
 
         for t in range(lat_ticks):
             now = time.perf_counter()
@@ -1621,17 +1570,11 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
                 node.propose_many(g, cmds)
                 t0q[g].extend([now] * E)
             node.tick()
-            if kv_native is not None:
-                settle_native()
-            else:
-                drain(node, apply=True, t0q=t0q, lats=lats)
+            drain(node, apply=True, t0q=t0q, lats=lats)
         for _ in range(6):
             node.tick()
             node.publish_flush()    # acks land via the async publisher
-            if kv_native is not None:
-                settle_native()
-            else:
-                drain(node, apply=True, t0q=t0q, lats=lats)
+            drain(node, apply=True, t0q=t0q, lats=lats)
         censored = sum(len(q) for q in t0q)
         lat_stats = None
         if lats:

@@ -164,88 +164,6 @@ def build_wal_stress(sanitizer: str):
         return path
 
 
-def load_native_plog():
-    """ctypes handle to the native payload log + combined walplog entry
-    points (same shared object as the WAL), or None."""
-    lib = _load("wal")
-    if lib is None:
-        return None
-    c = ctypes
-    try:
-        lib.plog_new.restype = c.c_void_p
-        lib.plog_new.argtypes = [c.c_uint32]
-        lib.plog_free.restype = None
-        lib.plog_free.argtypes = [c.c_void_p]
-        for fn in ("plog_length", "plog_start", "plog_start_term"):
-            f = getattr(lib, fn)
-            f.restype = c.c_uint64
-            f.argtypes = [c.c_void_p, c.c_uint32]
-        lib.plog_set_start.restype = c.c_int
-        lib.plog_set_start.argtypes = [c.c_void_p, c.c_uint32,
-                                       c.c_uint64, c.c_uint64]
-        lib.plog_term_of.restype = c.c_uint64
-        lib.plog_term_of.argtypes = [c.c_void_p, c.c_uint32, c.c_uint64]
-        lib.plog_compact.restype = c.c_int
-        lib.plog_compact.argtypes = [c.c_void_p, c.c_uint32, c.c_uint64,
-                                     c.c_uint64]
-        lib.plog_put_range.restype = c.c_int
-        lib.plog_put_range.argtypes = [
-            c.c_void_p, c.c_uint32, c.c_uint64, c.c_uint32,
-            c.POINTER(c.c_uint64), c.c_char_p, c.POINTER(c.c_uint32),
-            c.c_int64]
-        lib.plog_range_bytes.restype = c.c_uint64
-        lib.plog_range_bytes.argtypes = [c.c_void_p, c.c_uint32,
-                                         c.c_uint64, c.c_uint32]
-        lib.plog_read_range.restype = c.c_int
-        lib.plog_read_range.argtypes = [
-            c.c_void_p, c.c_uint32, c.c_uint64, c.c_uint32,
-            c.POINTER(c.c_uint8), c.POINTER(c.c_uint32),
-            c.POINTER(c.c_uint64)]
-        lib.plog_ranges_bytes.restype = c.c_uint64
-        lib.plog_ranges_bytes.argtypes = [
-            c.c_void_p, c.c_uint32, c.POINTER(c.c_uint32),
-            c.POINTER(c.c_uint64), c.POINTER(c.c_uint32)]
-        lib.plog_read_groups.restype = c.c_int
-        lib.plog_read_groups.argtypes = [
-            c.c_void_p, c.c_uint32, c.POINTER(c.c_uint32),
-            c.POINTER(c.c_uint64), c.POINTER(c.c_uint32),
-            c.POINTER(c.c_uint8), c.POINTER(c.c_uint32)]
-        lib.walplog_put_uniform.restype = c.c_int
-        lib.walplog_put_uniform.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_uint32,
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint64),
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint64),
-            c.c_char_p, c.POINTER(c.c_uint32), c.c_uint32]
-        lib.walplog_mirror_all.restype = c.c_int
-        lib.walplog_mirror_all.argtypes = [
-            c.POINTER(c.c_void_p), c.POINTER(c.c_void_p), c.c_uint32,
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint32),
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint64),
-            c.POINTER(c.c_uint32), c.POINTER(c.c_int64),
-            c.POINTER(c.c_uint64), c.POINTER(c.c_uint32)]
-        lib.kv_new.restype = c.c_void_p
-        lib.kv_new.argtypes = [c.c_uint32]
-        lib.kv_free.restype = None
-        lib.kv_free.argtypes = [c.c_void_p]
-        lib.kv_apply_plog.restype = c.c_uint64
-        lib.kv_apply_plog.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_uint32,
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint64),
-            c.POINTER(c.c_uint32), c.POINTER(c.c_uint64)]
-        lib.kv_applied.restype = c.c_uint64
-        lib.kv_applied.argtypes = [c.c_void_p, c.c_uint32]
-        lib.kv_count.restype = c.c_uint64
-        lib.kv_count.argtypes = [c.c_void_p, c.c_uint32]
-        lib.kv_get.restype = c.c_int64
-        lib.kv_get.argtypes = [
-            c.c_void_p, c.c_uint32, c.c_char_p, c.c_uint32,
-            c.POINTER(c.c_uint8), c.c_uint32]
-    except AttributeError as e:     # pragma: no cover - stale build
-        log.warning("native plog ABI missing (%s); Python fallback", e)
-        return None
-    return lib
-
-
 def load_native_wal():
     """ctypes handle to the WAL fast path, or None."""
     lib = _load("wal")

@@ -117,7 +117,6 @@ class PodShardedWAL(ShardedWAL):
         self.shards = [WAL(dirs[j], segment_bytes=segment_bytes)
                        if j in self.owned else _NullShardWAL()
                        for j in range(num_shards)]
-        self._lib = None        # no cross-shard combined native calls
 
     @property
     def obs(self):

@@ -50,12 +50,11 @@ from raftsql_tpu.core.cluster import (cluster_multistep_host,
 # Re-exported for existing import sites (tests, tools): the host plane
 # moved to runtime/hostplane.py in the mesh-runtime split.
 from raftsql_tpu.runtime.hostplane import (_C,  # noqa: F401
-                                           _expand_ranges,
                                            _read_committed_epoch,
                                            ClusterHostPlane)
 
 __all__ = ["FusedClusterNode", "FusedPipe", "ClusterHostPlane",
-           "_C", "_expand_ranges", "_read_committed_epoch",
+           "_C", "_read_committed_epoch",
            "MeshClusterNode"]
 
 

@@ -70,12 +70,10 @@ from raftsql_tpu.transport.codec import (CONF_PREFIX as _CONF_PREFIX,
                                          is_conf_entry)
 from raftsql_tpu.runtime.node import (CLOSED, RAW_MANY, RAW_PLAIN,
                                       TransferRefused)
-from raftsql_tpu.native.build import load_native_plog
 from raftsql_tpu.storage import fsio
-from raftsql_tpu.storage.log import NativePayloadLog, PayloadLog
+from raftsql_tpu.storage.log import PayloadLog
 from raftsql_tpu.obs.prof import TickPhaseProfiler, span
-from raftsql_tpu.storage.wal import (WAL, split_uniform_runs,
-                                     wal_exists, wal_mirror_all)
+from raftsql_tpu.storage.wal import WAL, split_uniform_runs, wal_exists
 from raftsql_tpu.utils.metrics import GroupTraffic, NodeMetrics
 
 _C = {n: i for i, n in enumerate(INFO_FIELDS)}
@@ -100,19 +98,6 @@ def _read_committed_epoch(path: str) -> int:
         if zlib.crc32(blob[off:off + 8]) == crc:
             no = n
     return no
-
-
-def _expand_ranges(groups, starts, counts):
-    """Per-entry (group, index) columns from per-range lists — the
-    fallback form for WAL.append_entries when a combined native call is
-    unavailable."""
-    ca = np.asarray(counts)
-    sa = np.asarray(starts)
-    offs = np.cumsum(ca) - ca
-    tot = int(ca.sum())
-    ga = np.repeat(np.asarray(groups), ca)
-    ia = np.arange(tot) - np.repeat(offs, ca) + np.repeat(sa, ca)
-    return ga, ia, ca
 
 
 class ClusterHostPlane:
@@ -175,21 +160,19 @@ class ClusterHostPlane:
         # t's).  _pending_tick tags the deferred-publish pinfo.
         self._prof_tick = 0
         self._pending_tick = 0
-        self._fsync_dur = np.zeros(P, np.float64)   # parallel-path syncs
         self._fsync_span: Optional[tuple] = None    # (t0, dur) last tick
         # The durable phase's parts for the profiler: seconds of
         # [wal_plan, wal_append, wal_hardstate] summed over a
-        # dispatch's steps, the parallel path's per-peer times (its
-        # barrier costs the slowest peer, like the fsync), and what
-        # the wal.* counters count.
+        # dispatch's steps, and what the wal.* counters count.
         self._wal_split = [0.0, 0.0, 0.0]
-        self._mirror_dur = np.zeros(P, np.float64)
-        self._hard_dur = np.zeros(P, np.float64)
         self._wal_records = 0
-        # Follower ranges handed to the mirror, those of them that
-        # took the Python two-pass mirror, and the accepted appends
-        # that never became a range because they could change no log
-        # (wal.mirror_rows, .mirror_fallback_rows, .mirror_skipped_rows).
+        # Follower ranges listed for the mirror, those the mirror
+        # wrote, and the accepted appends that never became a range
+        # because they could change no log (wal.mirror_rows,
+        # .mirror_fallback_rows, .mirror_skipped_rows).  The second is
+        # the first by construction: benchmarks/layers/
+        # wal_mirror_fallback_pct.py reads it, and it goes when a
+        # `benchmark` PR retires that reader (ROADMAP.md).
         self._wal_mirror = [0, 0, 0]
         self._wal_shard_syncs = 0       # last seen (sharded WALs only)
         self._wal_hard: List[Optional[np.ndarray]] = [None] * P
@@ -277,11 +260,6 @@ class ClusterHostPlane:
         # materialized (cursor-advance only in _publish_shard) and
         # placement/transfer refuse them as leadership targets.
         self.witness_peers: frozenset = cfg.witness_set
-        # Native KV apply plane (models/kv_native.py): when set AND the
-        # payload plane is native, peer 0's committed ranges are applied
-        # inside one C call per publish instead of being materialized as
-        # Python bytes for a queue consumer.
-        self.native_kv = None
         # Overload-control plane (raftsql_tpu/overload/), attachment-
         # gated like tracer/membership: None keeps propose_many and the
         # staging path byte-identical to the pre-overload code (the
@@ -331,11 +309,9 @@ class ClusterHostPlane:
         from concurrent.futures import ThreadPoolExecutor
         self._sync_pool = ThreadPoolExecutor(
             max_workers=P, thread_name_prefix="wal-sync")
-        # Host-plane parallelism (per-peer mirror/hardstate/fsync
-        # workers + the async publishers): only pays when the host has
-        # cores to run them on — on a 1-core host the same threads just
-        # time-slice the tick thread's core and the serial path wins
-        # (measured: 652k vs 601k commits/s at G=1000/E=64).
+        # Host-plane parallelism (the async publishers): only pays
+        # when the host has cores to run them on — on a 1-core host the
+        # same threads just time-slice the tick thread's core.
         # RAFTSQL_FUSED_PARALLEL=1/0 overrides the autodetect.
         par_env = os.environ.get("RAFTSQL_FUSED_PARALLEL", "")
         self._host_parallel = (par_env == "1"
@@ -386,19 +362,6 @@ class ClusterHostPlane:
         # the next tick(); plumbed through the runtime's per-peer
         # timer_inc (core/cluster.py, parallel/sharded.py).
         self.timer_inc: Optional[np.ndarray] = None
-        # Native payload plane (native/wal.cc): combined WAL+payload-log
-        # C calls, OPT-IN via RAFTSQL_FUSED_NATIVE_PLOG=1.  Measured on
-        # the Python-consumer stack it LOSES to the columnar Python
-        # payload log (104k vs 239k commits/s at G=1000/E=32): the C
-        # store must materialize fresh bytes objects on every publish,
-        # while the Python store hands the consumer the very objects it
-        # already holds.  It wins only once the apply plane itself is
-        # C++-resident (reads bytes in place) — kept for that path, and
-        # every call site degrades per-call to the Python forms.
-        self._plog_lib = (load_native_plog()
-                          if os.environ.get("RAFTSQL_FUSED_NATIVE_PLOG")
-                          == "1" else None)
-
         # Double-buffered dispatch (RAFTSQL_OVERLAP_DISPATCH, default
         # on): tick t's heavy durable phase (WAL writes + the fsync
         # barrier) is STASHED at the end of tick t and retired inside
@@ -458,9 +421,7 @@ class ClusterHostPlane:
             else:
                 os.makedirs(d, exist_ok=True)
                 self.wals.append(self._new_wal(d))
-                self.plogs.append(
-                    NativePayloadLog(G, self._plog_lib)
-                    if self._plog_lib is not None else PayloadLog(G))
+                self.plogs.append(PayloadLog(G))
                 replayed.append(None)
             # Replay-complete sentinel, replayed-or-not (the reference's
             # nil on commitC, raft.go:131-132).
@@ -565,9 +526,7 @@ class ClusterHostPlane:
         self._replayed_conf[p] = {g: gl.conf for g, gl in logs.items()
                                   if gl.conf is not None}
         self.wals.append(self._new_wal(d))
-        plog = (NativePayloadLog(self.cfg.num_groups, self._plog_lib)
-                if self._plog_lib is not None
-                else PayloadLog(self.cfg.num_groups))
+        plog = PayloadLog(self.cfg.num_groups)
         self.plogs.append(plog)
         log_terms: Dict[int, list] = {}
         hard: Dict[int, tuple] = {}
@@ -1206,8 +1165,7 @@ class ClusterHostPlane:
         """Lazily open peer p's dispatch frame: the BEGIN marker is
         written only when the dispatch actually writes to that peer's
         WAL (an idle multi-step tick costs zero records and zero epoch
-        fsyncs).  Safe from the per-peer workers: each touches only its
-        own slot, and the epoch-number allocation is idempotent."""
+        fsyncs)."""
         if not self._ep_active or self._ep_begun[p]:
             return
         if self._ep_no_this is None:
@@ -1255,8 +1213,7 @@ class ClusterHostPlane:
         """Write peer p's changed hard states (term/vote/commit) to its
         WAL, AFTER the tick's entry records (etcd wal.Save order: a
         torn tail can then never leave a hard state referencing lost
-        entries).  Shared by the serial phase 2c and the parallel
-        per-peer workers; True when anything changed."""
+        entries).  True when anything changed."""
         col = pinfo[p]
         hs = np.stack([col[:, _C["term"]], col[:, _C["voted_for"]],
                        col[:, _C["commit"]]], axis=1)
@@ -1583,9 +1540,8 @@ class ClusterHostPlane:
             samples: list = []
             if tick_active:
                 # wal_write = the durable back half minus the fsync
-                # barrier (the barrier was clocked where it ran, serial
-                # or across the per-peer workers — _durable_phases
-                # fills _fsync_span); its parts are _durable_phases'.
+                # barrier (clocked where it ran: _durable_phases fills
+                # _fsync_span); its parts are _durable_phases'.
                 t_tot = _t.monotonic() - td0
                 fs = self._fsync_span
                 fdur = fs[1] if fs is not None else 0.0
@@ -1609,8 +1565,8 @@ class ClusterHostPlane:
 
     def _wal_counts(self) -> tuple:
         """The wal.* increments of the durable phase that just ran:
-        what it handed to the WALs (tick thread; the per-peer workers
-        have returned), or () where it handed them nothing."""
+        what it handed to the WALs, or () where it handed them
+        nothing."""
         hard = 0
         groups = self._wal_groups
         for p, changed in enumerate(self._wal_hard):
@@ -1755,21 +1711,27 @@ class ClusterHostPlane:
 
     def _durable_phases(self, pinfo: np.ndarray, final: bool,
                         staged: list) -> bool:
-        """The durable host phases for ONE step's packed info [P,G,C]:
-        phase 1 collects mirror METADATA (peer, src, group, start,
-        count, new_len) with no reads, of the accepted appends that
-        can change a log (_mirror_keep); phase 2a writes leader appends
-        (fresh-leader no-ops + accepted proposals, pre-popped into
-        `staged` by _stage_ranges) as uniform-term RANGES; phase 2b
-        mirrors follower appends.  Mirror-source
-        staging happens inside 2b AFTER 2a's appends — safe because 2a
-        writes are pure TAIL appends strictly above any mirrored range
-        (mirror ranges were composed from the source's ring at the end
-        of the PREVIOUS step), and the only same-step writes that can
-        truncate or overwrite a mirrored range are OTHER MIRRORS, which
-        both 2b paths stage fully before writing.  Any future 2a change
-        that is not a pure tail append breaks this argument and must
-        move 2a after 2b's staging.
+        """The durable host phases for ONE step's packed info [P,G,C],
+        one straight run.  Phase 1 (plan) collects mirror METADATA
+        (peer, src, group, start, count, new_len) with no reads, of the
+        accepted appends that can change a log (_mirror_keep).  Phase
+        2a writes leader appends (fresh-leader no-ops + accepted
+        proposals, pre-popped into `staged` by _stage_ranges) as
+        uniform-term RANGES into each leader's WAL and payload log.
+        Phase 2b mirrors follower appends in two passes: ALL source
+        reads, then one batched payload-log write and one WAL append
+        per destination peer.
+
+        The source reads happen inside 2b AFTER 2a's appends — safe
+        because 2a writes are pure TAIL appends strictly above any
+        mirrored range (mirror ranges were composed from the source's
+        ring at the end of the PREVIOUS step), and the only same-step
+        writes that can truncate or overwrite a mirrored range are
+        OTHER MIRRORS (a group's old leader accepts from its new one,
+        with truncation, in the step in which another peer still
+        mirrors from it), which 2b reads in full before it writes any.
+        Any future 2a change that is not a pure tail append breaks this
+        argument and must move 2a after 2b's reads.
 
         On the dispatch's FINAL step only, phase 2c (hard states) and
         the per-peer fsync barrier run — a multi-step dispatch saves
@@ -1823,16 +1785,13 @@ class ClusterHostPlane:
 
         # Any accepted append, empty or not, keeps the tick active.
         tick_active = bool(n_took)
-        # Phase 2a: leader appends (fresh-leader no-ops + accepted
-        # proposals) as uniform-term RANGES per peer — the write plan
-        # was staged (and the payloads popped) by _stage_ranges; one
-        # combined native call writes the WAL records and the
-        # payload-log range (wal.append_ranges_uniform); the fallback
-        # expands ranges to per-entry numpy columns for the classic
-        # two-call path.
         tb = _t.monotonic()
         split[0] += tb - ta
         with span(ann, "tick.wal_append", ptick):
+            # Phase 2a: leader appends as uniform-term RANGE records —
+            # one framed record per (group, start, term) run, not one
+            # per entry.  The write plan was staged (and the payloads
+            # popped) by _stage_ranges.
             for p in range(P):
                 r_g, r_start, r_count, r_term, w_d = staged[p]
                 if not r_g:
@@ -1842,177 +1801,74 @@ class ClusterHostPlane:
                     self._wal_records += sum(r_count)
                     self._wal_groups.update(r_g)
                 self._ensure_epoch_begin(p)
-                plog_native = (self.plogs[p] if hasattr(
-                    self.plogs[p], "handle") else None)
-                wrote = False
-                if plog_native is not None:
-                    blob = b"".join(w_d)
-                    lens = np.fromiter(map(len, w_d), np.uint32,
-                                       len(w_d))
-                    wrote = self.wals[p].append_ranges_uniform(
-                        plog_native, r_g, r_start, r_count, r_term, blob,
-                        lens)
-                if not wrote:
-                    # Python plog path: RANGE records — one framed
-                    # record per (group, start, term) run, not one per
-                    # entry.
-                    self.wals[p].append_ranges(r_g, r_start, r_count,
-                                               r_term, w_d)
-                    puts = []
-                    pos = 0
-                    for g, s, c, tm in zip(r_g, r_start, r_count,
-                                           r_term):
-                        puts.append((g, s, w_d[pos: pos + c], [tm] * c,
-                                     None))
-                        pos += c
-                    self.plogs[p].put_ranges(puts)
-        tc = _t.monotonic()
-        split[1] += tc - tb
-
-        # Phases 2b+2c+fsync, PARALLEL per peer when the native plane
-        # is up: worker p runs [mirrors INTO peer p] + [peer p's hard
-        # states] + [peer p's fsync].  Safe to run concurrently: phase
-        # 2a's appends are complete; a group's mirror source (its
-        # leader's plog) and dest (a follower's) are different peers,
-        # and since a group has ONE leader, worker A writing group g'
-        # into plog[X] can never touch the group-g ranges worker B
-        # reads FROM plog[X] — per-group data is disjoint across
-        # workers, and every C structure carries its own mutex.  This
-        # overlaps the 3x payload memcpy + write + fsync across cores
-        # instead of serializing them on the tick thread.
-        par_ok = (final
-                  and self._host_parallel
-                  and self.wals
-                  and self.wals[0]._lib is not None
-                  and hasattr(self.wals[0]._lib, "walplog_mirror_all")
-                  and all(w._lib is not None for w in self.wals)
-                  and all(hasattr(pl, "handle") for pl in self.plogs))
-        with span(ann, "tick.wal_plan", ptick):
-            if par_ok and m_peer:
-                # Per-group disjointness holds per LEADER, and a leader
-                # can change within a tick: group g's old leader X may
-                # accept from new leader Y (mirror INTO plog[X], with
-                # truncation) in the same tick another peer still
-                # mirrors g FROM plog[X].  Concurrent workers would
-                # then write a source mid-read.  Detect it (a group
-                # whose mirror source is also one of its mirror dests)
-                # and take the serial staged path for this tick — it
-                # is an election-tick rarity.
-                dests: Dict[int, set] = {}
-                for g, p in zip(m_g, m_peer):
-                    dests.setdefault(g, set()).add(p)
-                for g, s in zip(m_g, m_src):
-                    if s in dests.get(g, ()):
-                        par_ok = False
-                        break
-            if par_ok:
-                by_peer: List[List[int]] = [[] for _ in range(P)]
-                for i, mp in enumerate(m_peer):
-                    by_peer[mp].append(i)
-        td = _t.monotonic()
-        split[0] += td - tc
-        if par_ok:
-
-            def _host_peer(p: int) -> bool:
-                idx = by_peer[p]
-                t0 = _t.monotonic()
-                with span(ann, "tick.wal_append", ptick):
-                    if idx:
-                        self._ensure_epoch_begin(p)
-                        wal_mirror_all(
-                            self.wals, self.plogs,
-                            [m_peer[i] for i in idx],
-                            [m_src[i] for i in idx],
-                            [m_g[i] for i in idx],
-                            [m_start[i] for i in idx],
-                            [m_count[i] for i in idx],
-                            [m_newlen[i] for i in idx])
-                t1 = _t.monotonic()
-                with span(ann, "tick.wal_hardstate", ptick):
-                    changed = self._save_hard(p, pinfo)
-                    if self._ep_begun[p]:
-                        self.wals[p].epoch_mark(self._ep_no_this,
-                                                end=True)
-                t2 = _t.monotonic()
-                with span(ann, "tick.fsync", ptick):
-                    self.wals[p].sync()
-                self._fsync_dur[p] = _t.monotonic() - t2
-                self._mirror_dur[p] = t1 - t0
-                self._hard_dur[p] = t2 - t1
-                return changed
-
-            for act in self._sync_pool.map(_host_peer, range(P)):
-                tick_active = tick_active or act
-            # The barrier cost is max, not sum: the per-peer syncs ran
-            # concurrently on the pool (see _finish_durable's profiler
-            # attribution); so did the mirrors and the hard states.
-            self._fsync_span = (td, float(self._fsync_dur[:P].max()))
-            split[1] += float(self._mirror_dur[:P].max())
-            split[2] += float(self._hard_dur[:P].max())
-        elif m_peer:
-            with span(ann, "tick.wal_append", ptick):
+                self.wals[p].append_ranges(r_g, r_start, r_count,
+                                           r_term, w_d)
+                puts = []
+                pos = 0
+                for g, s, c, tm in zip(r_g, r_start, r_count, r_term):
+                    puts.append((g, s, w_d[pos: pos + c], [tm] * c,
+                                 None))
+                    pos += c
+                self.plogs[p].put_ranges(puts)
+            # Phase 2b: the mirror.  ALL source reads first (the
+            # staging contract), then one batched write per peer.
+            if m_peer:
+                if counting:
+                    self._wal_mirror[1] += len(m_peer)
                 for p in sorted(set(m_peer)):
                     self._ensure_epoch_begin(p)
-                if not wal_mirror_all(self.wals, self.plogs, m_peer, m_src,
-                                      m_g, m_start, m_count, m_newlen):
-                    # Python two-pass fallback: ALL source reads first (the
-                    # staging contract), then one batched write per peer.
-                    if counting:
-                        self._wal_mirror[1] += len(m_peer)
-                    reads = [self.plogs[s].slice_columns(g, st, c)
-                             if c else ([], [])
-                             for (s, g, st, c) in zip(m_src, m_g, m_start,
-                                                      m_count)]
-                    for p in range(P):
-                        b_g: List[int] = []
-                        b_start: List[int] = []
-                        b_count: List[int] = []
-                        b_terms: List[int] = []
-                        b_d: List[bytes] = []
-                        puts = []
-                        for (mp, g, st, c, nl), (terms, datas) in zip(
-                                zip(m_peer, m_g, m_start, m_count,
-                                    m_newlen), reads):
-                            if mp != p:
-                                continue
-                            puts.append((g, st, datas, terms, nl))
-                            if c:
-                                b_g.append(g)
-                                b_start.append(st)
-                                b_count.append(c)
-                                b_terms.extend(terms)
-                                b_d.extend(datas)
-                        if puts:
-                            self.plogs[p].put_ranges(puts)
-                        if b_g:
-                            # Mirrored batches may cross term boundaries;
-                            # RANGE records are uniform-term, so split each
-                            # mirror at its term changes (rare: elections).
-                            s_g: List[int] = []
-                            s_start: List[int] = []
-                            s_count: List[int] = []
-                            s_term: List[int] = []
-                            pos = 0
-                            for g, st0, c in zip(b_g, b_start, b_count):
-                                for (rs, rc, rt) in split_uniform_runs(
-                                        st0, b_terms[pos: pos + c]):
-                                    s_g.append(g)
-                                    s_start.append(rs)
-                                    s_count.append(rc)
-                                    s_term.append(rt)
-                                pos += c
-                            self.wals[p].append_ranges(s_g, s_start, s_count,
-                                                       s_term, b_d)
-            split[1] += _t.monotonic() - td
-
-        # Phase 2c (serial path only — the parallel path folded hard
-        # states + fsync into its per-peer workers): hard states after
-        # every ENTRY record of the tick (etcd wal.Save order: a torn
-        # tail can then never leave a hard state referencing lost
-        # entries), then the per-peer fsync that is the durable barrier
-        # before the next dispatch.
-        if final and not par_ok:
-            th = _t.monotonic()
+                reads = [self.plogs[s].slice_columns(g, st, c)
+                         if c else ([], [])
+                         for (s, g, st, c) in zip(m_src, m_g, m_start,
+                                                  m_count)]
+                for p in range(P):
+                    b_g: List[int] = []
+                    b_start: List[int] = []
+                    b_count: List[int] = []
+                    b_terms: List[int] = []
+                    b_d: List[bytes] = []
+                    puts = []
+                    for (mp, g, st, c, nl), (terms, datas) in zip(
+                            zip(m_peer, m_g, m_start, m_count,
+                                m_newlen), reads):
+                        if mp != p:
+                            continue
+                        puts.append((g, st, datas, terms, nl))
+                        if c:
+                            b_g.append(g)
+                            b_start.append(st)
+                            b_count.append(c)
+                            b_terms.extend(terms)
+                            b_d.extend(datas)
+                    if puts:
+                        self.plogs[p].put_ranges(puts)
+                    if b_g:
+                        # Mirrored batches may cross term boundaries;
+                        # RANGE records are uniform-term, so split each
+                        # mirror at its term changes (rare: elections).
+                        s_g: List[int] = []
+                        s_start: List[int] = []
+                        s_count: List[int] = []
+                        s_term: List[int] = []
+                        pos = 0
+                        for g, st0, c in zip(b_g, b_start, b_count):
+                            for (rs, rc, rt) in split_uniform_runs(
+                                    st0, b_terms[pos: pos + c]):
+                                s_g.append(g)
+                                s_start.append(rs)
+                                s_count.append(rc)
+                                s_term.append(rt)
+                            pos += c
+                        self.wals[p].append_ranges(s_g, s_start, s_count,
+                                                   s_term, b_d)
+        tc = _t.monotonic()
+        split[1] += tc - tb
+        if final:
+            # Phase 2c: hard states after every ENTRY record of the
+            # dispatch (etcd wal.Save order: a torn tail can then never
+            # leave a hard state referencing lost entries), then the
+            # per-peer fsync that is the durable barrier before the
+            # next dispatch.
             with span(ann, "tick.wal_hardstate", ptick):
                 for p in range(P):
                     tick_active = self._save_hard(p, pinfo) or tick_active
@@ -2028,7 +1884,7 @@ class ClusterHostPlane:
             # so the barrier costs one fsync wall-time, not P.  A peer
             # with nothing pending returns immediately.
             tf0 = _t.monotonic()
-            split[2] += tf0 - th
+            split[2] += tf0 - tc
             with span(ann, "tick.fsync", ptick):
                 list(self._sync_pool.map(lambda w: w.sync(), self.wals))
             self._fsync_span = (tf0, _t.monotonic() - tf0)
@@ -2090,38 +1946,17 @@ class ClusterHostPlane:
             gl = ready.tolist()
             cl = commit[ready].tolist()
             al = self._applied[p][ready].tolist()
-            if p == 0 and self.native_kv is not None \
-                    and self.membership is None:
-                # C-resident apply: one call, zero Python per entry.
-                self.native_kv.apply_plog(
-                    plog.handle, gl, [a + 1 for a in al],
-                    [c - a for c, a in zip(cl, al)])
-                self._applied[p][ready] = commit[ready]
-                deltas = commit[ready] - np.asarray(al)
-                self.traffic.add_commit(ready, deltas)
-                self._note_commits(int(deltas.sum()))
-                continue
             items = []
-            if hasattr(plog, "read_groups"):
-                # Native plog: every ready range in TWO ctypes calls.
-                per_range = plog.read_groups(
-                    gl, [a + 1 for a in al],
-                    [c - a for c, a in zip(cl, al)])
-                for g, a, datas in zip(gl, al, per_range):
-                    if self.membership is not None:
-                        datas = self._scrub_conf(g, a, list(datas))
-                    items.append((g, a, datas))
-            else:
-                sl = plog.slice
-                for g, a, c in zip(gl, al, cl):
-                    datas = sl(g, a + 1, c - a)
-                    if len(datas) != c - a:
-                        raise RuntimeError(
-                            f"peer {p} g{g}: payload log shorter than "
-                            f"commit ({a}+{len(datas)} < {c})")
-                    if self.membership is not None:
-                        datas = self._scrub_conf(g, a, datas)
-                    items.append((g, a, datas))
+            sl = plog.slice
+            for g, a, c in zip(gl, al, cl):
+                datas = sl(g, a + 1, c - a)
+                if len(datas) != c - a:
+                    raise RuntimeError(
+                        f"peer {p} g{g}: payload log shorter than "
+                        f"commit ({a}+{len(datas)} < {c})")
+                if self.membership is not None:
+                    datas = self._scrub_conf(g, a, datas)
+                items.append((g, a, datas))
             # All-empty ranges (a fresh leader's no-op, a scrubbed conf
             # entry) are delivered too: the consumer applies nothing
             # for them but must learn that the stream passed their
@@ -2215,9 +2050,6 @@ class ClusterHostPlane:
             self._epoch_f = None
         for w in self.wals:
             w.close()
-        for plog in self.plogs:
-            if hasattr(plog, "close"):
-                plog.close()
         for q in self._commit_qs:
             q.put(CLOSED)
 

@@ -138,11 +138,6 @@ class ShardedWAL:
     cross-shard atomicity is not needed because the host plane's
     barrier semantics are per-peer fsync-before-next-dispatch, and
     sync() here syncs every dirty shard before returning.
-
-    The combined native WAL+payload fast paths are per-directory and do
-    not span shards: `_lib` is None so wal_mirror_all and
-    append_ranges_uniform fall back to the (shard-routed) classic
-    calls.
     """
 
     def __init__(self, dirname: str, num_shards: int,
@@ -153,7 +148,6 @@ class ShardedWAL:
         self._gl = groups_per_shard
         self.shards = [WAL(d, segment_bytes=segment_bytes)
                        for d in self.shard_dirs(dirname, num_shards)]
-        self._lib = None        # no cross-shard combined native calls
 
     @staticmethod
     def shard_dirs(dirname: str, num_shards: int) -> List[str]:
@@ -232,12 +226,6 @@ class ShardedWAL:
             pos += c
         for j, b in by.items():
             self.shards[j].append_ranges(*b)
-
-    def append_ranges_uniform(self, plog, groups, starts, counts, terms,
-                              blob, lens) -> bool:
-        # The combined WAL+payload native call is per-directory; the
-        # caller falls back to append_ranges + plog.put_ranges.
-        return False
 
     def set_hardstates(self, groups, terms, votes, commits) -> None:
         ga = np.asarray(groups)

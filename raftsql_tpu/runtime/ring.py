@@ -1,11 +1,10 @@
 """Propose ring — shared-memory request plane for multi-worker serving.
 
-BENCH_r05 measured the fused engine committing 500k+ writes/s durable
-while ONE event-loop process served 5.8k HTTP req/s: request parsing,
-ack serialization, and the consensus tick all contend for a single
-GIL.  This module splits the serving plane across OS processes the way
-the reference splits peers (one process per concern) without giving up
-the single fused engine:
+With ONE event-loop process in front of the fused engine, request
+parsing, ack serialization, and the consensus tick all contend for a
+single GIL.  This module splits the serving plane across OS processes
+the way the reference splits peers (one process per concern) without
+giving up the single fused engine:
 
     worker 0 ─┐  request ring (mmap SPSC)  ┌─> RingServer drain ──┐
     worker 1 ─┼──────────────────────────>─┤   rdb.propose(...)    │ engine

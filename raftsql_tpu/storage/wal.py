@@ -203,70 +203,6 @@ def split_uniform_runs(start: int, terms) -> List[Tuple[int, int, int]]:
             for a, b in zip(edges[:-1], edges[1:])]
 
 
-def wal_mirror_all(wals, plogs, peers, srcs, groups, starts, counts,
-                   new_lens) -> bool:
-    """Cluster-wide follower mirror in ONE native call
-    (walplog_mirror_all): phase A stages every source range (the
-    read-all-before-write-all contract that makes same-tick source
-    truncation safe), phase B writes each destination peer's WAL ENTRY
-    records + payload-log range + truncation.  Returns False when the
-    native path is unavailable on any peer (caller falls back).
-
-    Destination WALs may be group-commit views (GroupCommitWAL below):
-    their `group_bias` flattens the record's group id into the shared
-    multiplexed stream, applied on the WAL side only."""
-    if not wals:
-        return True
-    lib = wals[0]._lib
-    if lib is None or not hasattr(lib, "walplog_mirror_all"):
-        return False
-    if any(w._lib is None for w in wals) \
-            or any(not hasattr(p, "handle") for p in plogs):
-        return False
-    import ctypes
-
-    import numpy as np
-    n = len(peers)
-    if n == 0:
-        return True
-    P = len(wals)
-    wh = (ctypes.c_void_p * P)(*[w._h for w in wals])
-    ph = (ctypes.c_void_p * P)(*[p.handle for p in plogs])
-    biases = np.asarray([getattr(w, "group_bias", 0) for w in wals],
-                        np.uint32)
-    pa = np.asarray(peers, np.uint32)
-    sa = np.asarray(srcs, np.uint32)
-    ga = np.asarray(groups, np.uint32)
-    ia = np.asarray(starts, np.uint64)
-    ca = np.asarray(counts, np.uint32)
-    na = np.asarray(new_lens, np.int64)
-    per_bytes = np.zeros(P, np.uint64)
-    rc = lib.walplog_mirror_all(
-        wh, ph, n,
-        pa.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-        sa.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-        ga.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-        ia.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        ca.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-        na.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        per_bytes.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        biases.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)))
-    if rc != 0:
-        raise ValueError("walplog_mirror_all: source range unavailable")
-    for i in range(n):
-        c = int(ca[i])
-        if c:
-            w = wals[int(pa[i])]
-            w._active_stats.bump(
-                int(ga[i]) + int(biases[int(pa[i])]), int(ia[i]) + c - 1)
-    for p in range(P):
-        b = int(per_bytes[p])
-        if b:
-            wals[p]._pending = True
-            wals[p]._bytes += b
-    return True
-
-
 def wal_exists(dirname: str) -> bool:
     return bool(_segment_paths(dirname))
 
@@ -506,57 +442,6 @@ class WAL:
                     + b"".join(datas[pos: pos + c]))
             pos += c
             self._write(body)
-
-    def append_ranges_uniform(self, plog, groups, starts, counts, terms,
-                              blob: bytes, lens,
-                              group_bias: int = 0) -> bool:
-        """Combined native write (walplog_put_uniform): for each range
-        (group, start, count, term) write ONE WAL RANGE record AND the
-        native payload-log range, all in one C call — zero per-entry
-        Python.  `blob` concatenates every range's payload bytes in
-        order; `lens` is per-entry.  Returns False when the native
-        combined path is unavailable (caller falls back to
-        append_entries + plog.put_ranges).  `group_bias` offsets the
-        WAL records' group ids only (the group-commit multiplexed
-        layout); the payload log is indexed by the raw group."""
-        if self._lib is None or plog is None \
-                or not hasattr(self._lib, "walplog_put_uniform"):
-            return False
-        import ctypes
-
-        import numpy as np
-        n_ranges = len(groups)
-        if n_ranges == 0:
-            return True
-        ga = np.asarray(groups, np.uint32)
-        sa = np.asarray(starts, np.uint64)
-        ca = np.asarray(counts, np.uint32)
-        ta = np.asarray(terms, np.uint64)
-        la = np.asarray(lens, np.uint32)
-        rc = self._lib.walplog_put_uniform(
-            self._h, plog.handle, n_ranges,
-            ga.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            sa.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            ca.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            ta.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            blob,
-            la.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            group_bias)
-        if rc != 0:
-            raise ValueError("walplog_put_uniform: payload gap")
-        bump = self._active_stats.bump
-        live = 0
-        for g, s, c in zip(ga.tolist(), sa.tolist(), ca.tolist()):
-            if c:             # native side skips empty runs entirely
-                bump(g + group_bias, s + c - 1)
-                live += 1
-        self._pending = True
-        # One RANGE record per non-empty run (native writes type-5 —
-        # keep _bytes matched to the file so rotation fires where
-        # segment_bytes intends).
-        self._bytes += live * (_HDR.size + _RANGE.size) \
-            + 4 * int(ca.sum()) + len(blob)
-        return True
 
     def set_hardstate(self, group: int, term: int, vote: int,
                       commit: int) -> None:
@@ -1210,17 +1095,6 @@ class WALGroupView:
         self.peer = peer
         self.group_bias = peer * owner.num_groups
 
-    # Shared-state delegation: the native mirror path (wal_mirror_all)
-    # talks to `_lib`/`_h` and writes `_pending`/`_bytes`/stat bumps —
-    # all live on the one shared base WAL.
-    @property
-    def _lib(self):
-        return self._owner.base._lib
-
-    @property
-    def _h(self):
-        return self._owner.base._h
-
     @property
     def is_native(self) -> bool:
         return self._owner.base.is_native
@@ -1228,28 +1102,6 @@ class WALGroupView:
     @property
     def _f(self):
         return self._owner.base._f
-
-    @property
-    def _active_stats(self):
-        return self._owner.base._active_stats
-
-    @property
-    def _pending(self):
-        return self._owner.base._pending
-
-    @_pending.setter
-    def _pending(self, v) -> None:
-        self._owner.base._pending = v
-        if v:
-            self._owner.note_write(self.peer)
-
-    @property
-    def _bytes(self):
-        return self._owner.base._bytes
-
-    @_bytes.setter
-    def _bytes(self, v) -> None:
-        self._owner.base._bytes = v
 
     @property
     def obs(self):
@@ -1284,13 +1136,6 @@ class WALGroupView:
         self._owner.base.append_ranges(
             [int(g) + self.group_bias for g in groups], starts, counts,
             terms, datas)
-
-    def append_ranges_uniform(self, plog, groups, starts, counts, terms,
-                              blob, lens) -> bool:
-        self._touch()
-        return self._owner.base.append_ranges_uniform(
-            plog, groups, starts, counts, terms, blob, lens,
-            group_bias=self.group_bias)
 
     def set_hardstate(self, group, term, vote, commit) -> None:
         self._touch()

@@ -1,10 +1,11 @@
 """Build-check the native WAL group-commit path (`make native-check`).
 
 Compiles native/wal.cc (via the ordinary loader), then exercises the
-group-commit plumbing end to end on the NATIVE backend: per-peer views
-of one shared WAL write biased records through the combined
-walplog_put_uniform call and the native payload log, one fsync covers
-all peers, and replay splits per peer.  Exits 0 on pass (or SKIP when
+group-commit plumbing end to end on the NATIVE backend, with the calls
+a served engine makes (runtime/hostplane.py _durable_phases): per-peer
+views of one shared WAL write biased RANGE records (append_ranges)
+beside a PayloadLog, one fsync covers all peers, and replay splits per
+peer.  Exits 0 on pass (or SKIP when
 no toolchain), 1 on any mismatch — CI runs this next to `make native`
 so a wal.cc change that breaks the bias ABI fails the build step, not
 a downstream serving run.
@@ -20,40 +21,29 @@ sys.path.insert(0, REPO)
 
 
 def main() -> int:
-    from raftsql_tpu.native.build import load_native_plog, load_native_wal
-    from raftsql_tpu.storage.log import NativePayloadLog
+    from raftsql_tpu.native.build import load_native_wal
+    from raftsql_tpu.storage.log import PayloadLog
     from raftsql_tpu.storage.wal import GroupCommitWAL
 
     if load_native_wal() is None:
         print("native-check: SKIP (no toolchain; Python backend covers "
               "this host)")
         return 0
-    plog_lib = load_native_plog()
-    if plog_lib is None:
-        print("native-check: FAIL: wal built but plog ABI missing",
-              file=sys.stderr)
-        return 1
     P, G = 3, 2
     with tempfile.TemporaryDirectory(prefix="native-gc-") as tmp:
         d = os.path.join(tmp, "gc")
         gw = GroupCommitWAL(d, num_peers=P, num_groups=G)
-        if gw.base._lib is None:
+        if not gw.base.is_native:
             print("native-check: FAIL: shared WAL fell back to Python",
                   file=sys.stderr)
             return 1
         views = [gw.view(p) for p in range(P)]
-        plogs = [NativePayloadLog(G, plog_lib) for _ in range(P)]
+        plogs = [PayloadLog(G) for _ in range(P)]
         for p, v in enumerate(views):
             datas = [f"p{p}e{i}".encode() for i in range(3)]
-            blob = b"".join(datas)
-            import numpy as np
-            lens = np.fromiter(map(len, datas), np.uint32, 3)
-            ok = v.append_ranges_uniform(plogs[p], [0, 1], [1, 1],
-                                         [2, 1], [1, 1], blob, lens)
-            if not ok:
-                print("native-check: FAIL: combined call unavailable",
-                      file=sys.stderr)
-                return 1
+            v.append_ranges([0, 1], [1, 1], [2, 1], [1, 1], datas)
+            plogs[p].put_ranges([(0, 1, datas[:2], [1, 1], None),
+                                 (1, 1, datas[2:], [1], None)])
             v.set_hardstates([0, 1], [1, 1], [-1, -1], [2, 1])
         for v in views:
             v.sync()

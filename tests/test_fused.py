@@ -294,33 +294,6 @@ def test_fused_pipe_raftdb_sql_stack(tmp_path, monkeypatch):
         rdb.close()
 
 
-def test_fused_native_payload_plane(tmp_path, monkeypatch):
-    """RAFTSQL_FUSED_NATIVE_PLOG=1: the C payload store + combined
-    walplog calls produce the same commit streams and survive restart
-    replay (the opt-in native plane must stay correct even while the
-    Python store is the measured default)."""
-    monkeypatch.setenv("RAFTSQL_FUSED_NATIVE_PLOG", "1")
-    cfg = mkcfg(groups=2)
-    node = FusedClusterNode(cfg, str(tmp_path))
-    if not hasattr(node.plogs[0], "handle"):
-        import pytest
-        pytest.skip("native library unavailable")
-    elect(node)
-    drain(node, 0)
-    for g in range(2):
-        node.propose_many(g, [f"SET k{i} g{g}".encode()
-                              for i in range(6)])
-    for _ in range(30):
-        node.tick()
-    live, _ = drain(node, 0)
-    assert len(live) == 12
-    node.stop()
-    node2 = FusedClusterNode(cfg, str(tmp_path))
-    rep, sent = drain(node2, 0)
-    assert sent == 1 and len(rep) == 12
-    node2.stop()
-
-
 def test_multistep_dispatch_equals_single_step_ticks(tmp_path):
     """RAFTSQL_FUSED_STEPS=S must be EXACTLY S single-step ticks: same
     consensus math (same seed), same durable bytes, same published

@@ -20,6 +20,7 @@ import pytest
 from raftsql_tpu.config import LEADER, RaftConfig
 from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
 from raftsql_tpu.runtime.db import NotLeaderError, RaftDB
+from raftsql_tpu.runtime.errors import ReadTimeout
 from raftsql_tpu.runtime.pipe import RaftPipe
 from raftsql_tpu.transport.loopback import (FaultPlan, LoopbackHub,
                                             LoopbackTransport)
@@ -129,16 +130,30 @@ def test_reads_do_not_wait_on_an_entry_that_carries_no_command(cluster):
     apply that could not happen (503 until the group's next write).
     The commit stream now delivers the no-op's index too."""
     dbs, _ = cluster
-    lead = leader_index(dbs)
-    node = dbs[lead].pipe.node
     deadline = time.monotonic() + TIMEOUT
-    while int(node._hard_np[0, 2]) < 1:         # the no-op committed
-        assert time.monotonic() < deadline
-        time.sleep(0.02)
-    assert dbs[lead].watermark(0) == 0          # nothing was applied
-    assert dbs[lead].query("SELECT 1", linear=True, timeout=5) == "|1|\n"
+    while True:
+        lead = leader_index(dbs)
+        node = dbs[lead].pipe.node
+        while int(node._hard_np[0, 2]) < 1:         # the no-op committed
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
+        assert dbs[lead].watermark(0) == 0          # nothing was applied
+        try:
+            assert dbs[lead].query("SELECT 1", linear=True,
+                                   timeout=5) == "|1|\n"
+            break
+        except (NotLeaderError, ReadTimeout) as e:
+            # The election timer is 50 ms: on a loaded host the node
+            # `leader_index` named can be deposed, or be a deposed
+            # leader that has not heard yet, and then it refuses or
+            # cannot re-confirm.  Ask again who leads.  The wait this
+            # test is about is the one for the APPLY: never retried.
+            if getattr(e, "phase", None) == "apply" \
+                    or time.monotonic() > deadline:
+                raise
     assert dbs[lead].query("SELECT 1", mode="follower",
-                           timeout=5) == "|1|\n"
+                           timeout=TIMEOUT) == "|1|\n"
+    assert dbs[lead].watermark(0) == 0      # served over no-ops alone
 
 
 def test_fused_reads_after_restart_do_not_wait_on_the_new_noop(

@@ -532,7 +532,7 @@ def test_mesh_counters_count_what_a_scripted_tick_wrote(tmp_path):
     assert 6 <= d["shard_syncs"] <= 2 * 2 * PEERS
     assert d["shard_syncs"] == d["fsyncs"]      # a shard is a WAL
     _mirror_counts_as_scripted(d, ticks)
-    # ShardedWAL has no native mirror: every row took the Python one.
+    # One mirror: the rows it wrote are the rows it was handed.
     assert d["mirror_fallback_rows"] == d["mirror_rows"]
     # The mesh's own phase and the publish workers' stamp.
     assert snap["mesh_put"]["n"] == snap["launch"]["n"] > 0
@@ -543,23 +543,11 @@ def test_mesh_counters_count_what_a_scripted_tick_wrote(tmp_path):
     assert stages["publish"]["queue"]["n"] % SHARDS == 0    # a worker each
 
 
-@pytest.mark.parametrize("native_plog", [False, True],
-                         ids=["python-plog", "native-plog"])
-def test_fused_counters_count_what_a_scripted_tick_wrote(
-        tmp_path, monkeypatch, native_plog):
-    """The fused runtime: no shard streams, no `mesh_put`.  With the
-    native payload log (RAFTSQL_FUSED_NATIVE_PLOG=1) `wal_mirror_all`
-    takes every row: fallback 0.  The SERVED `--fused` deployment keeps
-    its payloads in the Python log, which the native mirror cannot
-    read: every row falls back there too, as on the mesh."""
-    if native_plog:
-        monkeypatch.setenv("RAFTSQL_FUSED_NATIVE_PLOG", "1")
-    else:
-        monkeypatch.delenv("RAFTSQL_FUSED_NATIVE_PLOG", raising=False)
+def test_fused_counters_count_what_a_scripted_tick_wrote(tmp_path):
+    """The fused runtime: no shard streams, no `mesh_put`; the mirror
+    counts as on the mesh."""
     node = FusedClusterNode(cfg_for(), str(tmp_path))
     try:
-        if native_plog and not hasattr(node.plogs[0], "handle"):
-            pytest.skip("the native payload log did not build here")
         d, ticks = _scripted(node)
         snap = node.prof.snapshot()
     finally:
@@ -567,8 +555,7 @@ def test_fused_counters_count_what_a_scripted_tick_wrote(
     assert d["records"] == 6
     assert d["shard_syncs"] == 0 and d["fsyncs"] > 0
     _mirror_counts_as_scripted(d, ticks)
-    assert d["mirror_fallback_rows"] == (0 if native_plog
-                                         else d["mirror_rows"])
+    assert d["mirror_fallback_rows"] == d["mirror_rows"]
     assert "mesh_put" not in snap
     assert abs(snap["dispatch"]["total_ms"] - snap["launch"]["total_ms"]
                - snap["readback"]["total_ms"]) < 0.01
