@@ -7,7 +7,10 @@ transport.Send → publish, with the dispatch itself as the send barrier):
 
   messages composed at tick t are OBSERVED by their receivers only
   inside step t+1 — and the host does not dispatch step t+1 until every
-  peer's tick-t appends and hard states are fsynced.
+  peer's tick-t appends and hard states are fsynced.  Within a tick a
+  peer's entry records precede its hard states, which are found by one
+  compare for all peers of the step's (term, vote, commit) columns
+  against what the WALs hold, and written whatever the history.
 
 This module is the host half of that contract, factored out of the
 original ~1400-line runtime/fused.py so both runtimes share ONE codepath
@@ -77,6 +80,9 @@ from raftsql_tpu.storage.wal import WAL, split_uniform_runs, wal_exists
 from raftsql_tpu.utils.metrics import GroupTraffic, NodeMetrics
 
 _C = {n: i for i, n in enumerate(INFO_FIELDS)}
+# What a hard state is: these columns of the packed info, in the column
+# order of ClusterHostPlane._hard (and of a WAL hard-state record).
+_HARD_COLS = [_C["term"], _C["voted_for"], _C["commit"]]
 
 
 def _read_committed_epoch(path: str) -> int:
@@ -212,8 +218,15 @@ class ClusterHostPlane:
         self._commit_qs: List["queue.Queue"] = [queue.Queue()
                                                 for _ in range(P)]
         self._applied = np.zeros((P, G), np.int64)
-        self._hard = np.zeros((P, G, 3), np.int64)
+        # What every peer's WAL holds, [P, G, (term, vote, commit)], in
+        # the packed info's dtype (core/step.py pack_info: every column
+        # is int32) and kept column by column, as the TPU hands the
+        # packed info over (G is its minor axis there): the tick's
+        # compare then runs over contiguous columns and casts nothing.
+        self._hard_cols = np.zeros((P, 3, G), np.int32)
+        self._hard = self._hard_cols.transpose(0, 2, 1)
         self._hard[:, :, 1] = -1
+        self._hard_ne = np.zeros((P, 3, G), bool)   # the compare's flags
         # Per-(peer, group) proposal queues as plain lists: the tick
         # pops a whole batch with one C-level slice + del, vs a Python
         # popleft per entry on a deque.  _prop_lock covers _props and
@@ -1209,22 +1222,47 @@ class ClusterHostPlane:
             self._epoch_f.close()
             self._epoch_f = open(self._epoch_path, "ab")
 
-    def _save_hard(self, p: int, pinfo: np.ndarray) -> bool:
-        """Write peer p's changed hard states (term/vote/commit) to its
-        WAL, AFTER the tick's entry records (etcd wal.Save order: a
+    def _hard_changed(self, pinfo: np.ndarray):
+        """(peers, groups, rows): the (peer, group) whose hard state in
+        this step differs from what the WALs hold, peer-major and
+        group-ascending, and their (term, vote, commit) [n, 3].  ONE
+        exact compare for all peers in three numpy calls of [P, G] size
+        (the gather, the compare, the nonzero), each over contiguous
+        columns where the packed info comes column-major.  The count
+        matters more than the arithmetic: on a served engine every such
+        call lets go of the interpreter, and the tick thread then
+        stands in line for it behind the engine's other threads."""
+        G = pinfo.shape[1]
+        hs = pinfo.transpose(0, 2, 1)[:, _HARD_COLS]        # [P, 3, G]
+        ne = np.not_equal(hs, self._hard_cols, out=self._hard_ne)
+        at = np.flatnonzero(ne)             # (peer, column, group)
+        if at.size:
+            # A row is found once a changed column: few from here on.
+            at = np.unique(at // (3 * G) * G + at % G)
+        peers, groups = np.divmod(at, G)
+        return peers, groups, hs[peers, :, groups]
+
+    def _save_hard(self, pinfo: np.ndarray) -> bool:
+        """Write every peer's changed hard states (term/vote/commit) to
+        its WAL, AFTER the tick's entry records (etcd wal.Save order: a
         torn tail can then never leave a hard state referencing lost
-        entries).  True when anything changed."""
-        col = pinfo[p]
-        hs = np.stack([col[:, _C["term"]], col[:, _C["voted_for"]],
-                       col[:, _C["commit"]]], axis=1)
-        changed = np.nonzero((hs != self._hard[p]).any(axis=1))[0]
-        if not changed.size:
+        entries): one compare for all peers, then one set_hardstates a
+        peer that has any.  True when anything changed."""
+        peers, groups, rows = self._hard_changed(pinfo)
+        if not peers.size:
             return False
-        self._ensure_epoch_begin(p)
-        self.wals[p].set_hardstates(changed, hs[changed, 0],
-                                    hs[changed, 1], hs[changed, 2])
-        self._hard[p][changed] = hs[changed]
-        self._wal_hard[p] = changed         # -> wal.* (_count_wal)
+        ends = np.searchsorted(peers, np.arange(self.cfg.num_peers),
+                               side="right").tolist()
+        lo = 0
+        for p, hi in enumerate(ends):
+            if hi > lo:
+                changed, r = groups[lo:hi], rows[lo:hi]
+                self._ensure_epoch_begin(p)
+                self.wals[p].set_hardstates(changed, r[:, 0], r[:, 1],
+                                            r[:, 2])
+                self._hard[p][changed] = r
+                self._wal_hard[p] = changed     # -> wal.* (_count_wal)
+            lo = hi
         return True
 
     def tick(self) -> None:
@@ -1383,9 +1421,7 @@ class ClusterHostPlane:
                     tick_active = True
                     break
         if not tick_active:
-            hs = pinfo[:, :, [_C["term"], _C["voted_for"],
-                              _C["commit"]]]
-            tick_active = bool((hs != self._hard).any())
+            tick_active = bool(self._hard_changed(pinfo)[0].size)
         # Quiescence signal for the threaded loop: anything written,
         # any group leaderless, or any proposal backlog means "keep
         # ticking at full pace".
@@ -1733,10 +1769,12 @@ class ClusterHostPlane:
         Any future 2a change that is not a pure tail append breaks this
         argument and must move 2a after 2b's reads.
 
-        On the dispatch's FINAL step only, phase 2c (hard states) and
-        the per-peer fsync barrier run — a multi-step dispatch saves
-        every step's entries, then one hard state, then one fsync,
-        which is the etcd wal.Save order at dispatch granularity.
+        On the dispatch's FINAL step only, phase 2c (hard states: one
+        compare for all peers against what the WALs hold, then one
+        batched record write a peer that has a changed row) and the
+        per-peer fsync barrier run — a multi-step dispatch saves every
+        step's entries, then one hard state, then one fsync, which is
+        the etcd wal.Save order at dispatch granularity.
         Returns tick_active (entries or hard states written)."""
         P = self.cfg.num_peers
         import time as _t
@@ -1866,12 +1904,11 @@ class ClusterHostPlane:
         if final:
             # Phase 2c: hard states after every ENTRY record of the
             # dispatch (etcd wal.Save order: a torn tail can then never
-            # leave a hard state referencing lost entries), then the
-            # per-peer fsync that is the durable barrier before the
-            # next dispatch.
+            # leave a hard state referencing lost entries), found by
+            # one compare for all peers, then the per-peer fsync that
+            # is the durable barrier before the next dispatch.
             with span(ann, "tick.wal_hardstate", ptick):
-                for p in range(P):
-                    tick_active = self._save_hard(p, pinfo) or tick_active
+                tick_active = self._save_hard(pinfo) or tick_active
                 if self._ep_active:
                     for p in range(P):
                         if self._ep_begun[p]:

@@ -15,9 +15,18 @@ election makes once in a long while happen on demand:
   - a commit beyond what the payload log holds: `_publish_shard` raises,
     it never delivers a short batch.
 
-The last drives real ticks and holds the WAL's record order: within a
+The next drives real ticks and holds the WAL's record order: within a
 dispatch every entry record of a peer precedes its hard states, which
-precede its fsync, at one step a dispatch and at two.
+precede its fsync, at one step a dispatch and at two; a second node on
+the data dir then reads back the hard states the first one held.
+
+The last part is phase 2c alone, the hard states.  One compare for all
+peers finds them (`_hard_changed`, `_save_hard`); the function it
+replaced ran once a peer, and lives on here as the oracle: scripted
+dispatches go to both, over WALs that list what they are handed, and
+every call, `_hard`, `_wal_hard` and the return value must agree.  Then
+a restart reads back what dispatches that changed hard states ONLY
+wrote.
 """
 import numpy as np
 import pytest
@@ -34,19 +43,19 @@ GROUPS, PEERS, SHARDS = 4, 3, 2
 SCRIPTED = (0, 3)           # one group in each mesh shard
 
 
-def cfg_for():
-    return RaftConfig(num_groups=GROUPS, num_peers=PEERS, seed=7,
+def cfg_for(peers=PEERS, groups=GROUPS):
+    return RaftConfig(num_groups=groups, num_peers=peers, seed=7,
                       log_window=32, max_entries_per_msg=4,
                       tick_interval_s=0.0)
 
 
-def fused(data_dir):
-    return FusedClusterNode(cfg_for(), data_dir, seed=3)
+def fused(data_dir, **shape):
+    return FusedClusterNode(cfg_for(**shape), data_dir, seed=3)
 
 
-def mesh(data_dir):
+def mesh(data_dir, **shape):
     return MeshClusterNode(
-        cfg_for(), data_dir,
+        cfg_for(**shape), data_dir,
         MeshConfig(peer_shards=1, group_shards=SHARDS).build(), seed=3)
 
 
@@ -59,7 +68,7 @@ RUNTIMES = pytest.mark.parametrize("make", [fused, mesh],
 def blank(node):
     """A step in which nothing happened: every hard state as it stands,
     no append accepted."""
-    pinfo = np.zeros((PEERS, GROUPS, len(_C)), np.int32)
+    pinfo = np.zeros(node._hard.shape[:2] + (len(_C),), np.int32)
     pinfo[:, :, _C["term"]] = node._hard[:, :, 0]
     pinfo[:, :, _C["voted_for"]] = node._hard[:, :, 1]
     pinfo[:, :, _C["commit"]] = node._hard[:, :, 2]
@@ -74,8 +83,8 @@ def accept(pinfo, peer, g, src, start, n, new_len):
     row[_C["app_n"]], row[_C["new_log_len"]] = n, new_len
 
 
-def no_writes():
-    return [([], [], [], [], []) for _ in range(PEERS)]
+def no_writes(peers=PEERS):
+    return [([], [], [], [], []) for _ in range(peers)]
 
 
 def tail_append(staged, peer, g, start, term, datas):
@@ -285,8 +294,17 @@ def test_hard_states_follow_every_entry_record_of_the_dispatch(
         node.publish_flush()
         assert all(node.plogs[p].length(g) >= 7
                    for p in range(PEERS) for g in range(GROUPS))
+        hard = node._hard.copy()
     finally:
         node.stop()
+    # Elections (votes a tick before any entry), appends and commit
+    # advances: a second node reads back every hard state of them.
+    node2 = make(str(tmp_path))
+    try:
+        assert node2._hard.dtype == hard.dtype == np.int32
+        np.testing.assert_array_equal(node2._hard, hard)
+    finally:
+        node2.stop()
     both = 0
     for seq in events.values():
         dispatch = []
@@ -305,3 +323,273 @@ def test_hard_states_follow_every_entry_record_of_the_dispatch(
             both += "entries" in body and "hard" in body
             dispatch = []
     assert both >= GROUPS       # the schedule had such dispatches
+
+
+# -- phase 2c: one hard-state compare for all peers -----------------------
+
+class Listing:
+    """A WAL that lists the hard states and epoch marks it is handed
+    and, where it stands in front of a real one, hands them on."""
+
+    def __init__(self, real=None):
+        self.real, self.calls = real, []
+
+    def set_hardstates(self, groups, terms, votes, commits):
+        self.calls.append(("hard",) + tuple(
+            np.asarray(a).tolist()
+            for a in (groups, terms, votes, commits)))
+        if self.real is not None:
+            self.real.set_hardstates(groups, terms, votes, commits)
+
+    def epoch_mark(self, no, end):
+        self.calls.append(("end" if end else "begin", no))
+        if self.real is not None:
+            self.real.epoch_mark(no, end=end)
+
+    def __getattr__(self, name):
+        return getattr(self.real, name)
+
+
+class PerPeerOracle:
+    """Phase 2c as the parent commit ran it (hostplane.py at bcfac11):
+    `_save_hard(p, pinfo)` and `_ensure_epoch_begin(p)` statement for
+    statement, int64 state and all, with what `_finish_durable` and
+    `_durable_phases` did around them for a dispatch without entries."""
+
+    def __init__(self, node):
+        P = node.cfg.num_peers
+        self.P = P
+        self._hard = node._hard.astype(np.int64)
+        self._wal_hard = [None] * P
+        self.wals = [Listing() for _ in range(P)]
+        self._epoch_no = node._epoch_no
+        self._ep_active = False
+
+    def _ensure_epoch_begin(self, p):
+        if not self._ep_active or self._ep_begun[p]:
+            return
+        if self._ep_no_this is None:
+            self._ep_no_this = self._epoch_no + 1
+        self._ep_begun[p] = True
+        self.wals[p].epoch_mark(self._ep_no_this, end=False)
+
+    def _save_hard(self, p, pinfo):
+        col = pinfo[p]
+        hs = np.stack([col[:, _C["term"]], col[:, _C["voted_for"]],
+                       col[:, _C["commit"]]], axis=1)
+        changed = np.nonzero((hs != self._hard[p]).any(axis=1))[0]
+        if not changed.size:
+            return False
+        self._ensure_epoch_begin(p)
+        self.wals[p].set_hardstates(changed, hs[changed, 0],
+                                    hs[changed, 1], hs[changed, 2])
+        self._hard[p][changed] = hs[changed]
+        self._wal_hard[p] = changed
+        return True
+
+    def dispatch(self, step_infos):
+        pinfo = step_infos[-1]
+        self._ep_active = len(step_infos) > 1
+        if self._ep_active:
+            self._ep_begun = [False] * self.P
+            self._ep_no_this = None
+        tick_active = False
+        for p in range(self.P):
+            tick_active = self._save_hard(p, pinfo) or tick_active
+        if self._ep_active:
+            for p in range(self.P):
+                if self._ep_begun[p]:
+                    self.wals[p].epoch_mark(self._ep_no_this, end=True)
+            if self._ep_no_this is not None:
+                self._epoch_no = self._ep_no_this
+        self._ep_active = False
+        return tick_active
+
+
+def nothing_changed(node, rng):
+    yield [blank(node)]
+
+
+def one_row_of_one_peer(node, rng):
+    P, G = node._hard.shape[:2]
+    pinfo = blank(node)
+    pinfo[P - 1, G // 2, _C["commit"]] += 3
+    yield [pinfo]
+    yield [pinfo.copy()]                # and nothing the step after
+
+
+def every_row_of_every_peer(node, rng):
+    pinfo = blank(node)
+    pinfo[:, :, _C["term"]] += 1
+    yield [pinfo]
+    pinfo = pinfo.copy()                # each column on its own, too
+    pinfo[:, :, _C["voted_for"]] = 1
+    yield [pinfo]
+    pinfo = pinfo.copy()
+    pinfo[:, :, _C["commit"]] += 2
+    yield [pinfo]
+
+
+def a_vote_of_minus_one(node, rng):
+    P, G = node._hard.shape[:2]
+    pinfo = blank(node)
+    pinfo[:, :, _C["term"]] = 1
+    pinfo[:, :, _C["voted_for"]] = P - 1
+    yield [pinfo]
+    # A higher term is heard of before any vote in it: the vote of a
+    # peer goes back to -1, a value like any other.
+    pinfo = pinfo.copy()
+    pinfo[0, :, _C["term"]] = 2
+    pinfo[0, :, _C["voted_for"]] = -1
+    pinfo[1, G - 1, _C["voted_for"]] = -1
+    yield [pinfo]
+
+
+def a_change_of_role_alone(node, rng):
+    pinfo = blank(node)
+    pinfo[:, :, _C["role"]] = 2
+    pinfo[:, :, _C["leader_hint"]] = 1
+    pinfo[:, :, _C["lease"]] = 9
+    yield [pinfo]
+
+
+def two_steps_with_epochs(node, rng):
+    P, G = node._hard.shape[:2]
+    # Only the FINAL step's hard states are saved: what step one shows
+    # of peer 0 is gone by step two, where the last peer alone differs
+    # from what its WAL holds.  BEGIN for it, nothing for the others.
+    first, final = blank(node), blank(node)
+    first[0, :, _C["term"]] += 5
+    final[P - 1, G - 1, _C["term"]] += 1
+    final[P - 1, 0, _C["commit"]] += 1
+    yield [first, final]
+    # A second dispatch: every peer, so every peer is framed; then one
+    # in which nothing changed frames nobody and commits no epoch.
+    first, final = final.copy(), final.copy()
+    final[:, 0, _C["voted_for"]] = 0
+    yield [first, final]
+    yield [final.copy(), final.copy()]
+
+
+def a_seeded_run(node, rng):
+    P, G = node._hard.shape[:2]
+    pinfo = blank(node)
+    for rows in (1, 0, 5, max(G // 3, 1), 2, P * G):
+        pinfo = pinfo.copy()
+        pinfo[:, :, _C["role"]] = rng.integers(0, 3, (P, G))
+        at = rng.choice(P * G, size=min(rows, P * G), replace=False)
+        pp, gg = np.unravel_index(at, (P, G))
+        col = rng.choice([_C["term"], _C["voted_for"], _C["commit"]],
+                         size=at.size)
+        pinfo[pp, gg, col] += rng.integers(-1, 3, at.size).astype(
+            np.int32)                   # a 0 among them: no change
+        pinfo[:, :, _C["voted_for"]] = np.clip(
+            pinfo[:, :, _C["voted_for"]], -1, P - 1)
+        yield [pinfo]
+
+
+def shaped(runtime, name, peers, groups):
+    return pytest.param(
+        lambda data_dir: runtime(data_dir, peers=peers, groups=groups),
+        id=f"{name}-P{peers}-G{groups}")
+
+
+def as_the_tpu_hands_it_over(pinfo):
+    """The same packed info with G as its minor axis, [P][C][G] in
+    memory: what `jax.device_get` returns on the TPU (strides
+    640000, 4, 40000 at G=10,000), where the CPU backend's is row-major.
+    A view that reinterprets rows passes every test here and cannot
+    run there."""
+    return np.ascontiguousarray(pinfo.transpose(0, 2, 1)).transpose(0, 2, 1)
+
+
+@pytest.mark.parametrize("layout", [np.asarray, as_the_tpu_hands_it_over],
+                         ids=["row-major", "G-minor"])
+@pytest.mark.parametrize("script", [
+    nothing_changed, one_row_of_one_peer, every_row_of_every_peer,
+    a_vote_of_minus_one, a_change_of_role_alone, two_steps_with_epochs,
+    a_seeded_run], ids=lambda f: f.__name__)
+@pytest.mark.parametrize("make", [
+    shaped(fused, "fused", 3, 1), shaped(fused, "fused", 3, 7),
+    shaped(fused, "fused", 5, 7), shaped(fused, "fused", 3, 10_000),
+    shaped(mesh, "mesh2", 3, 4), shaped(mesh, "mesh2", 5, 4),
+    shaped(mesh, "mesh2", 3, 10_000)])
+def test_one_compare_for_all_peers_is_the_per_peer_save(make, script,
+                                                        layout, tmp_path):
+    node = make(str(tmp_path))
+    try:
+        P = node.cfg.num_peers
+        # With the telemetry plane off nothing takes `_wal_hard` away
+        # before it is read here (`_wal_counts` would).
+        node.prof = None
+        node.wals = [Listing(w) for w in node.wals]
+        oracle = PerPeerOracle(node)
+        wrote = 0
+        for step_infos in script(node, np.random.default_rng(31)):
+            node._wal_hard = [None] * P
+            oracle._wal_hard = [None] * P
+            was = [len(w.calls) for w in node.wals]
+            got = node._finish_durable(
+                [layout(pi) for pi in step_infos],
+                [no_writes(P) for _ in step_infos])
+            assert got == oracle.dispatch(step_infos)
+            for p in range(P):
+                # The same calls in the same order: groups, terms,
+                # votes, commits of every row, BEGIN before a peer's
+                # first record, no mark for a peer that wrote nothing.
+                assert node.wals[p].calls == oracle.wals[p].calls, p
+                wrote += len(node.wals[p].calls) - was[p]
+                if oracle._wal_hard[p] is None:
+                    assert node._wal_hard[p] is None, p
+                else:
+                    np.testing.assert_array_equal(node._wal_hard[p],
+                                                  oracle._wal_hard[p])
+            np.testing.assert_array_equal(node._hard, oracle._hard)
+            assert node._epoch_no == oracle._epoch_no
+        assert node._hard.dtype == np.int32     # the packed info's
+        assert node._hard.shape == oracle._hard.shape
+        if script in (nothing_changed, a_change_of_role_alone):
+            assert wrote == 0
+        else:
+            assert wrote
+    finally:
+        node.stop()
+
+
+@RUNTIMES
+def test_a_restart_reads_back_hard_states_written_without_entries(
+        make, tmp_path):
+    node = make(str(tmp_path))
+    try:
+        # One entry a scripted group at peer 0, so that a commit has
+        # something to stand on.
+        staged = no_writes()
+        for g in SCRIPTED:
+            tail_append(staged, 0, g, 1, 1, [b"a"])
+        pinfo = blank(node)
+        pinfo[:, :, _C["term"]] = 1
+        pinfo[:, :, _C["voted_for"]] = 0
+        assert node._finish_durable([pinfo], [staged])
+        # An election with no entry: terms and votes alone.
+        pinfo = pinfo.copy()
+        pinfo[:, :, _C["term"]] = 2
+        pinfo[:, :, _C["voted_for"]] = 1
+        pinfo[2, :, _C["voted_for"]] = -1
+        assert node._finish_durable([pinfo], [no_writes()])
+        # A commit advance with no append.
+        pinfo = pinfo.copy()
+        for g in SCRIPTED:
+            pinfo[0, g, _C["commit"]] = 1
+        assert node._finish_durable([pinfo], [no_writes()])
+        assert not node._finish_durable([pinfo.copy()], [no_writes()])
+        hard = node._hard.copy()
+        assert hard.dtype == pinfo.dtype == np.int32
+        assert (hard[:, :, 0] == 2).all() and hard[0, SCRIPTED[0], 2] == 1
+    finally:
+        node.stop()
+    node2 = make(str(tmp_path))
+    try:
+        assert node2._hard.dtype == hard.dtype
+        np.testing.assert_array_equal(node2._hard, hard)
+    finally:
+        node2.stop()

@@ -412,6 +412,7 @@ def test_multistep_uncommitted_dispatch_dropped_on_restart(tmp_path):
             for g in range(cfg.num_groups):
                 assert node2.plogs[p].length(g) == lens[p][g], (p, g)
         assert (node2._hard == hard).all()
+        assert node2._hard.dtype == hard.dtype == np.int32
         assert node2._epoch_no == committed_epoch
     finally:
         node2.stop()
