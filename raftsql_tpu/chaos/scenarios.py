@@ -490,10 +490,26 @@ class FusedChaosRunner:
                     self._resolve_reads()
                     self._observe(t)
                     if self.sched.compact_every and t \
-                            and t % self.sched.compact_every == 0 \
-                            and self.node.compact(
-                                keep=self.sched.compact_keep):
-                        self.report["compactions"] += 1
+                            and t % self.sched.compact_every == 0:
+                        # A sweep writes markers and fsyncs: the
+                        # storage faults can fire in it as in a tick,
+                        # with the same posture (the floors it moved in
+                        # memory die with the process; the restart
+                        # finds the markers that became durable).
+                        try:
+                            if self.node.compact(
+                                    keep=self.sched.compact_keep):
+                                self.report["compactions"] += 1
+                        except fsio.EnospcError:
+                            self.report["enospc_hits"] += 1
+                            self._crash_restart(t, power_loss=False)
+                        except fsio.FsyncFaultError:
+                            self.report["fsync_faults"] += 1
+                            self._crash_restart(t, power_loss=False)
+                        except fsio.CrashPointError as e:
+                            self.report["torn_write_faults"] += 1
+                            self._crash_restart(t, power_loss=True,
+                                                tear_peer=int(e.tag))
                 # Final deep checks + a restart pass so the run always
                 # ends with a full durability audit.
                 check_log_matching(self.sched.ticks,

@@ -37,7 +37,11 @@ class StateMachine(Protocol):
         Machines whose applied_index survives a process crash advertise it
         with a truthy `has_durable_snapshot` attribute; the engine treats
         everything else as floor 0 for WAL compaction (compacting on a
-        volatile index silently loses data on restart)."""
+        volatile index silently loses data on restart).  Such a machine
+        also has `checkpoint() -> int`: put what was applied on disk and
+        return the applied index that now survives a POWER loss (a
+        commit need not sync); the compaction sweep drops the raft log
+        only under an index this has returned (models/store.py)."""
         ...
 
     def close(self) -> None: ...

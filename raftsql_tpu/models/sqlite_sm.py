@@ -20,6 +20,8 @@ import sqlite3
 import threading
 from typing import Optional
 
+from raftsql_tpu.storage import fsio
+
 
 def is_select(query: str) -> bool:
     """First-token SELECT check, case-insensitive — the reference's naive
@@ -56,18 +58,27 @@ class SQLiteStateMachine:
         # WAL compaction may only trust applied_index() as a floor when it
         # survives a crash (models/base.py contract).
         self.has_durable_snapshot = resume and path != ":memory:"
+        # Descriptors this machine holds while its connection is open
+        # (models/store.py budgets by it): a WAL-journal database keeps
+        # the file, its -wal and its -shm; an in-memory one none, and
+        # can therefore never be released.
+        self.open_files = 0 if path == ":memory:" else (
+            3 if self.has_durable_snapshot else 1)
         self._conn = self._connect()
         self._lock = threading.Lock()
         self._applied = 0
+        self._dir_synced = False
         if resume:
             self._conn.execute(
                 "CREATE TABLE IF NOT EXISTS _raft_meta "
                 "(k TEXT PRIMARY KEY, v INTEGER)")
             self._conn.commit()
-            row = self._conn.execute(
-                "SELECT v FROM _raft_meta WHERE k='applied_index'"
-            ).fetchone()
-            self._applied = int(row[0]) if row else 0
+            self._applied = self._applied_on_file()
+
+    def _applied_on_file(self) -> int:
+        row = self._conn.execute(
+            "SELECT v FROM _raft_meta WHERE k='applied_index'").fetchone()
+        return int(row[0]) if row else 0
 
     def _connect(self) -> sqlite3.Connection:
         """Open self.path configured for this state machine: manual
@@ -84,7 +95,9 @@ class SQLiteStateMachine:
             lose a recent tail on power loss but always rolls the file
             back to a consistent point whose applied_index matches, and
             the raft log replays forward from there — exactly-once
-            preserved at a fraction of the fsync cost."""
+            preserved at a fraction of the fsync cost.  The log must
+            still BE there: a compaction sweep drops it only under an
+            index `checkpoint()` has put on disk."""
         conn = sqlite3.connect(self.path, check_same_thread=False)
         conn.isolation_level = None
         try:
@@ -100,6 +113,60 @@ class SQLiteStateMachine:
 
     def applied_index(self) -> int:
         return self._applied
+
+    def release(self) -> None:
+        """Close the connection and keep the machine (models/store.py:
+        the least recently used handle gives its descriptors back).
+        Closing the last connection of a WAL-journal database
+        checkpoints it; the file stays, in either mode, and `reopen`
+        finds it as it was left."""
+        with self._lock:
+            if self._conn is not None:
+                self._conn.close()
+                self._conn = None
+
+    def checkpoint(self) -> int:
+        """Make everything applied so far survive a power loss, and
+        return the applied index that now does (0 where this machine
+        keeps no durable snapshot, or the checkpoint could not run to
+        its end).  `synchronous=NORMAL` syncs nothing at a commit: the
+        newest transactions live in the `-wal` file's unsynced tail
+        until a checkpoint, which syncs the `-wal`, copies its pages
+        into the database and syncs that.  The compaction sweep drops
+        the raft log under a group's applied index, so it may only
+        trust an index this has returned (models/store.py
+        `durable`)."""
+        with self._lock:
+            if not self.has_durable_snapshot or self._conn is None:
+                return 0
+            try:
+                busy, in_log, moved = self._conn.execute(
+                    "PRAGMA wal_checkpoint(FULL)").fetchone()
+            except sqlite3.Error:
+                return 0                # disk full: the log stays
+            if busy or in_log != moved:
+                return 0
+            if not self._dir_synced:
+                # The file's own directory entry, once.
+                fsio.fsync_dir(os.path.dirname(self.path) or ".")
+                self._dir_synced = True
+            return self._applied
+
+    def reopen(self) -> None:
+        """Connect again to the file `release` left.  Never deletes:
+        parity mode's delete-at-boot is the constructor's.  In resume
+        mode the file's own `_raft_meta` must say what this machine
+        remembers, or the file is not the one that was released."""
+        with self._lock:
+            if self._conn is not None:
+                return
+            self._conn = self._connect()
+            if self.resume:
+                on_file = self._applied_on_file()
+                if on_file != self._applied:
+                    raise RuntimeError(
+                        f"{self.path}: applied index {on_file} on file, "
+                        f"{self._applied} remembered at release")
 
     def apply(self, command: str, index: int = 0) -> Optional[Exception]:
         return self.apply_batch([(command, index)])[0]
@@ -283,4 +350,6 @@ class SQLiteStateMachine:
 
     def close(self) -> None:
         with self._lock:
-            self._conn.close()
+            if self._conn is not None:
+                self._conn.close()
+                self._conn = None

@@ -213,6 +213,12 @@ def load_native_wal():
         lib.wal_set_compact.argtypes = [
             ctypes.c_void_p, ctypes.c_uint32, ctypes.c_uint64,
             ctypes.c_uint64]
+        lib.wal_set_compacts.restype = ctypes.c_int
+        lib.wal_set_compacts.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint32,
+            ctypes.POINTER(ctypes.c_uint32),
+            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_uint64)]
         lib.wal_sync.restype = ctypes.c_int
         lib.wal_sync.argtypes = [ctypes.c_void_p]
         lib.wal_close.restype = ctypes.c_int

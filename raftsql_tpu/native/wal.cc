@@ -221,6 +221,28 @@ int wal_set_compact(void* h, uint32_t group, uint64_t index,
   return 0;
 }
 
+// A sweep's floor markers in one call (storage/wal.py
+// WAL._write_compact_recs): a call from Python lets go of the interpreter and
+// has to win it back, which in a served engine costs far more than the
+// record; a sweep writes hundreds, the one that drops the first
+// segment tens of thousands.
+int wal_set_compacts(void* h, uint32_t n, const uint32_t* groups,
+                     const uint64_t* indexes, const uint64_t* terms) {
+  Wal* w = static_cast<Wal*>(h);
+  std::lock_guard<std::mutex> lk(w->mu);
+  std::vector<uint8_t> body;
+  for (uint32_t i = 0; i < n; ++i) {
+    body.clear();
+    body.reserve(21);
+    body.push_back(4);
+    put_u32(body, groups[i]);
+    put_u64(body, indexes[i]);
+    put_u64(body, terms[i]);
+    frame(w, body);
+  }
+  return 0;
+}
+
 int wal_set_hardstate(void* h, uint32_t group, uint64_t term, int64_t vote,
                       uint64_t commit) {
   Wal* w = static_cast<Wal*>(h);

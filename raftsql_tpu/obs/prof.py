@@ -40,7 +40,11 @@ a request (`stages.put.*`, `stages.get.*` on the engine;
 `worker_stages.*` in an HTTP worker, which folds its own into the
 document it relays) and of a tick's commits on their way to the apply
 plane (`stages.publish.queue`: handed to a publish worker -> taken up
-by it, one sample a worker a tick).  No ring, no percentile, no per-request object.
+by it, one sample a worker a tick), and of a compaction
+(`stages.compact.checkpoint`: the state machines written since the
+last sweep put on disk, on a thread of its own, runtime/db.py; then
+`stages.compact.sweep`: the tick thread's sweep, one sample each a
+sweep).  No ring, no percentile, no per-request object.
 
 COUNTERS — plain cumulative integers (`intake.*`: what the host plane's
 queues held, offered to the device and got accepted, per tick summed;
@@ -48,11 +52,20 @@ queues held, offered to the device and got accepted, per tick summed;
 phase, the shard streams a sharded WAL flushed, the follower ranges
 handed to the mirror, those of them that took the Python mirror, and
 `wal.mirror_skipped_rows`: the accepted appends that could change no
-log, empty heartbeat acks, and were dropped before any was listed).
+log, empty heartbeat acks, and were dropped before any was listed;
+`compact.sweeps`, `compact.floors_advanced` (groups x peers whose floor
+a sweep moved) and `wal.segments_unlinked`, one count() a sweep).
+
+GAUGES — values that are read where they live when a document is made
+(`gauge_fn`): `wal.disk_bytes` and `wal.segments_pinned` (the WALs keep
+both as they rotate and unlink), and the state-machine store's
+`sm.opens`, `sm.closes`, `sm.evictions`, `sm.open_handles`
+(models/store.py).  They sit beside the counters in the document.
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
-wal_plan, wal_append, wal_hardstate, fsync, publish) as a
+wal_plan, wal_append, wal_hardstate, fsync, publish, and compact where
+a sweep runs: `stages.compact.sweep` holds its time) as a
 `jax.profiler.TraceAnnotation` named `tick.<phase>` carrying `tick=<n>`
 (`annotation()` is the one flag test a tick makes; `span()` gives the
 shared no-op context when it says no), so a device trace names the
@@ -110,7 +123,7 @@ _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 # only after its first sample cannot be told from one that was lost).
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
                  "put.apply_batch", "get.queue", "get.wait", "get.sql",
-                 "publish.queue")
+                 "publish.queue", "compact.sweep", "compact.checkpoint")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
 ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
@@ -118,7 +131,12 @@ ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
                    "wal.hardstates", "wal.groups_written", "wal.fsyncs",
                    "wal.shard_syncs", "wal.mirror_rows",
                    "wal.mirror_fallback_rows", "wal.mirror_skipped_rows",
-                   "apply.runs", "apply.groups", "apply.fanout_runs")
+                   "apply.runs", "apply.groups", "apply.fanout_runs",
+                   "compact.sweeps", "compact.floors_advanced",
+                   "wal.segments_unlinked")
+# Read where they live, at export (gauge_fn); 0 until somebody says.
+ENGINE_GAUGES = ("wal.disk_bytes", "wal.segments_pinned", "sm.opens",
+                 "sm.closes", "sm.evictions", "sm.open_handles")
 
 
 # Appends a deque may hold before the appending thread folds them in
@@ -213,6 +231,7 @@ class TickPhaseProfiler(StageSet):
     def __init__(self, cap: int = 4096, annotate: bool = False):
         super().__init__(ENGINE_STAGES)
         self._counters: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
+        self._gauges: Dict[str, object] = {}    # name -> () -> number
         self._new_ticks: deque = deque()    # record_tick()s not folded in
         n = len(PROF_PHASES)
         self.cap = cap
@@ -274,6 +293,11 @@ class TickPhaseProfiler(StageSet):
         in one call."""
         self.record_tick(-1, (), counts)
 
+    def gauge_fn(self, name: str, fn) -> None:
+        """`fn()` is the value of gauge `name` whenever a document is
+        made; it runs on the scraping thread and must only read."""
+        self._gauges[name] = fn
+
     def _fold(self) -> None:
         super()._fold()
         new, index, cap, c = self._new_ticks, self._i, self.cap, \
@@ -300,6 +324,13 @@ class TickPhaseProfiler(StageSet):
         with self._mu:
             self._fold()
             flat = dict(self._counters)
+        for name in ENGINE_GAUGES:
+            flat[name] = 0
+        for name, fn in list(self._gauges.items()):
+            try:
+                flat[name] = int(fn())
+            except Exception:                           # noqa: BLE001
+                pass            # a gauge must never break the scrape
         return _nest(flat)
 
     def _window(self, rows: List[list]) -> List[list]:

@@ -96,6 +96,9 @@ class _NullShardWAL:
     def compact(self, floors, hard) -> int:
         return 0
 
+    def seed_floors(self, floors) -> None:
+        pass
+
     def close(self) -> None:
         pass
 

@@ -250,12 +250,13 @@ class ReshardPlane:
     # durability fence, same as the chaos runner's peer-0 stream.
 
     def _rows(self, group: int, sql: str) -> List[tuple]:
-        sm = self.db._sms[group]
-        fn = getattr(sm, "rows", None)
-        if fn is not None:
-            return fn(sql)
+        with self.db.store.use(group) as sm:
+            fn = getattr(sm, "rows", None)
+            if fn is not None:
+                return fn(sql)
+            text = sm.query(sql)
         out = []
-        for line in sm.query(sql).splitlines():
+        for line in text.splitlines():
             if line.startswith("|") and line.endswith("|"):
                 out.append(tuple(line[1:-1].split("|")))
         return out
@@ -384,8 +385,8 @@ class ReshardPlane:
         through the fault-injectable fsio plane (a failed fsync aborts
         the verb — the target never saw a partial image it could
         mistake for a shard)."""
-        sm = self.db._sms[int(group)]
-        index, image = sm.serialize_with_index()
+        with self.db.store.use(int(group)) as sm:
+            index, image = sm.serialize_with_index()
         os.makedirs(self.ship_dir, exist_ok=True)
         path = os.path.join(self.ship_dir,
                             f"g{int(group)}-p{int(target)}-"

@@ -37,7 +37,10 @@ from collections import defaultdict, deque
 from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Callable, Dict, Optional, Tuple
 
+import numpy as np
+
 from raftsql_tpu.models.base import StateMachine
+from raftsql_tpu.models.store import StateMachineStore
 from raftsql_tpu.models.sqlite_sm import is_select
 from raftsql_tpu.overload import (Overloaded, deadline_steps,
                                   zero_metrics_doc)
@@ -172,17 +175,21 @@ def _commit_item_tops(item):
         yield item[0], item[1]
 
 
-def _apply_group(sm: StateMachine, items: list) -> Tuple[list, float]:
+def _apply_group(store: StateMachineStore, group: int,
+                 items: list) -> Tuple[list, float]:
     """One group's batch of a run on its state machine: the error list
     (one Optional[Exception] per item) and the wall time it took,
-    measured inside the thread that ran it.  Runs on the reader thread
-    or on an apply worker, so it touches nothing but `sm`."""
+    measured inside the thread that ran it, the way to the group's
+    handle included (its first use opens it).  Runs on the reader
+    thread or on an apply worker, so it touches nothing but the
+    store."""
     t0 = time.monotonic()
-    batch_fn = getattr(sm, "apply_batch", None)
-    if batch_fn is not None:
-        errs = batch_fn(items)
-    else:
-        errs = [sm.apply(qy, ix) for (qy, ix) in items]
+    with store.use(group) as sm:
+        batch_fn = getattr(sm, "apply_batch", None)
+        if batch_fn is not None:
+            errs = batch_fn(items)
+        else:
+            errs = [sm.apply(qy, ix) for (qy, ix) in items]
     return errs, time.monotonic() - t0
 
 
@@ -227,7 +234,8 @@ class RaftDB:
     def __init__(self, sm_factory: Callable[[int], StateMachine],
                  pipe: RaftPipe, num_groups: int = 1,
                  listener=None, resume: bool = False,
-                 compact_every: int = 0, compact_keep: int = 1024):
+                 compact_every: int = 0, compact_keep: int = 1024,
+                 existing=()):
         """resume=True enables snapshot-resume (SURVEY.md §5.4
         improvement): state machines that persist applied_index (see
         SQLiteStateMachine resume mode) skip re-apply of already-applied
@@ -235,14 +243,25 @@ class RaftDB:
         covered by every group's snapshot is compacted away after every
         `compact_every` applies (retaining `compact_keep` entries for
         follower catch-up).  Default off: reference delete-and-replay
-        parity (db.go:27-29)."""
+        parity (db.go:27-29).  `existing`: with resume, the groups
+        whose state-machine file a former process left; their applied
+        indexes are read off the files here, before anything asks
+        (a group whose log was compacted away gets no replay that
+        would open it)."""
         self.pipe = pipe
         self.num_groups = num_groups
         self.listener = listener            # queue-like or None
         self.resume = resume
         self._compact_every = compact_every if resume else 0
+        if compact_every and not resume:
+            log.warning(
+                "compact_every=%d can never act without resume: in "
+                "parity mode the state machine is rebuilt from the "
+                "whole log at boot, so no prefix of it can go",
+                compact_every)
         self._compact_keep = compact_keep
         self._applies_since_compact = 0
+        self._compactor: Optional[threading.Thread] = None
         # Witness replica (config.py quorum geometry): this node votes,
         # appends and fsyncs — but owns no SQLite shard.  The real
         # sm_factory is never invoked, so no shard file or directory is
@@ -254,14 +273,19 @@ class RaftDB:
         if self.witness_self:
             from raftsql_tpu.models.witness import WitnessStateMachine
             sm_factory = WitnessStateMachine
-        self._sms: Dict[int, StateMachine] = {
-            g: sm_factory(g) for g in range(num_groups)}
-        if not any(getattr(sm, "has_durable_snapshot", False)
-                   for sm in self._sms.values()):
-            # All floors would be 0 (volatile applied indexes must not
-            # gate WAL compaction) — a guaranteed no-op; don't take
-            # _wal_lock for it every compact_every applies.
-            self._compact_every = 0
+        # The state machines, made on first use and held open within
+        # what RLIMIT_NOFILE allows (models/store.py): a group nobody
+        # writes or reads costs a slot in an array, not a database.
+        self.store = StateMachineStore(sm_factory, num_groups)
+        if resume:
+            self.store.seed(existing)
+        prof = self._prof()
+        if prof is not None:
+            store = self.store
+            prof.gauge_fn("sm.opens", lambda: store.opens)
+            prof.gauge_fn("sm.closes", lambda: store.closes)
+            prof.gauge_fn("sm.evictions", lambda: store.evictions)
+            prof.gauge_fn("sm.open_handles", store.open_handles)
         if resume:
             # Full state transfer for followers beyond the compaction
             # floor (InstallSnapshot) is only sound when re-apply is
@@ -278,7 +302,7 @@ class RaftDB:
         # entry is a fresh leader's no-op — every group, right after a
         # restart — waits for an apply that cannot happen.  Written by
         # the reader thread only; readers tolerate a stale (lower) value.
-        self._delivered = [0] * num_groups
+        self._delivered = np.zeros(num_groups, np.int64)
         self._q2cb: Dict[Tuple[int, str], deque] = defaultdict(deque)  # raftlint: guarded-by=_mu
         self._failed: Optional[Exception] = None
         self._closed = False
@@ -397,13 +421,13 @@ class RaftDB:
             per_g[group].append((query, index))
         fanout = len(per_g) > 1
         if fanout:
-            futs = [self._apply_pool.submit(_apply_group, self._sms[g],
+            futs = [self._apply_pool.submit(_apply_group, self.store, g,
                                             items)
                     for g, items in per_g.items()]
             wait(futs)                  # the barrier: all, then the rest
             done = [f.result() for f in futs]   # a worker's raise, here
         else:
-            done = [_apply_group(self._sms[g], items)
+            done = [_apply_group(self.store, g, items)
                     for g, items in per_g.items()]
         errs: Dict[int, list] = {
             g: d[0] for g, d in zip(per_g, done)}
@@ -515,15 +539,17 @@ class RaftDB:
     # ------------------------------------------------------------------
 
     def _snapshot_of(self, group: int):
-        sm = self._sms[group]
-        fn = getattr(sm, "serialize_with_index", None)
-        if fn is None or sm.applied_index() <= 0:
+        if self.store.applied_index(group) <= 0:
             # Nothing applied: there is no snapshot to hand out, so do
-            # not build one only to drop it (at boot that is every one
-            # of --groups databases, serialized under the shm plane's
+            # not open a database to find that out (at boot that is
+            # every one of --groups groups, asked under the shm plane's
             # start while the tick thread competes for the interpreter).
             return None
-        idx, blob = fn()
+        with self.store.use(group) as sm:
+            fn = getattr(sm, "serialize_with_index", None)
+            if fn is None:
+                return None
+            idx, blob = fn()
         return (idx, blob) if idx > 0 else None
 
     # Grace before failing acks orphaned by a snapshot install: commits
@@ -532,7 +558,8 @@ class RaftDB:
 
     def _install_snapshot(self, group: int, index: int,
                           blob: bytes) -> None:
-        self._sms[group].install(blob, index)
+        with self.store.use(group) as sm:
+            sm.install(blob, index)
         if self.shm is not None:
             # A state transfer skipped the delta stream: workers must
             # rebuild their replica from the installed image, so the
@@ -589,13 +616,77 @@ class RaftDB:
         if self._applies_since_compact < self._compact_every:
             return
         self._applies_since_compact = 0
-        # Volatile applied indexes (has_durable_snapshot unset/False) are
-        # floored at 0: compacting the WAL against state lost on restart
-        # would be silent data loss (models/base.py contract).
-        applied = {g: (sm.applied_index()
-                       if getattr(sm, "has_durable_snapshot", False) else 0)
-                   for g, sm in self._sms.items()}
-        self.pipe.node.compact(applied, keep=self._compact_keep)
+        store = self.store
+        if store.durable is None:
+            return                      # no state machine made yet
+        if not store.durable:
+            # Volatile applied indexes must not gate WAL compaction:
+            # compacting against state lost on restart would be silent
+            # data loss (models/base.py contract).  Every floor would
+            # be 0, for good: say so once and stop asking.
+            log.warning(
+                "compact_every=%d can never act: the state machines "
+                "keep no durable applied index (no snapshot to compact "
+                "the log under); compaction is off",
+                self._compact_every)
+            self._compact_every = 0
+            return
+        if self._closed or (self._compactor is not None
+                            and self._compactor.is_alive()):
+            return              # the last round is still on its way
+        self._compactor = threading.Thread(
+            target=self._compact_round, name="raftdb-compact",
+            daemon=True)
+        self._compactor.start()
+
+    def _compact_round(self) -> None:
+        """One compaction round, on a thread of its own (started by the
+        apply thread, one at a time): put on disk what the state
+        machines applied since the last round, then ask for the sweep.
+
+        A sweep unlinks the raft log under a group's applied index, and
+        a state machine commits without a sync: what a loss of power
+        leaves of a file is its last checkpoint.  So every group with
+        `applied > synced` is checkpointed first (models/store.py; its
+        own applies and reads wait for that one file, nobody else's:
+        the apply thread never waits for an fsync) and the sweep is
+        handed `synced`, the indexes no power loss takes back, never
+        `applied`."""
+        try:
+            store = self.store
+            t0 = time.monotonic()
+            for g in np.flatnonzero(store.applied > store.synced).tolist():
+                store.checkpoint(g)
+            prof = self._prof()
+            if prof is not None:
+                prof.stage("compact.checkpoint", time.monotonic() - t0)
+            # The apply thread moves on meanwhile.  `_delivered` is
+            # written after the applies it stands for: read it FIRST,
+            # so that where `applied` (read after) still equals
+            # `synced`, every statement at or below what was delivered
+            # is on disk, and what lies above `synced` there carries
+            # none.
+            delivered = self._delivered.copy()
+            applied = store.applied.copy()
+            synced = store.synced.copy()
+            node = self.pipe.node
+            ask = getattr(node, "request_compact", None)
+            if ask is not None:
+                # The co-located runtimes: the tick thread runs the
+                # sweep (runtime/hostplane.py compact) with these and,
+                # for what every peer already holds, how far the state
+                # machine covers the log.
+                covered = np.where(synced == applied,
+                                   np.maximum(synced, delivered), synced)
+                ask(synced, covered, self._compact_keep)
+            else:
+                written = np.flatnonzero(synced)
+                node.compact(dict(zip(written.tolist(),
+                                      synced[written].tolist())),
+                             keep=self._compact_keep)
+        except Exception:                               # noqa: BLE001
+            # Nothing was dropped: the floors stay where they were.
+            log.exception("compaction round failed; the log stays")
 
     def propose(self, query: str, group: int = 0,
                 token: Optional[int] = None,
@@ -681,14 +772,14 @@ class RaftDB:
         client that carries the largest watermark it has seen and
         presents it on `mode="session"` reads gets read-your-writes
         and monotonic reads from ANY replica."""
-        return int(self._sms[group].applied_index())
+        return self.store.applied_index(group)
 
     def _wait_applied(self, group: int, target: int, deadline: float,
                       tick: float, phase: str) -> None:
         """Block until the local apply reaches `target` (bounded):
         the state machine applied it, or the commit stream delivered
         past it (entries that carry no command — see _delivered)."""
-        while max(self._sms[group].applied_index(),
+        while max(self.store.applied_index(group),
                   self._delivered[group]) < target:
             if self._failed is not None:
                 raise self._failed
@@ -696,7 +787,7 @@ class RaftDB:
             if now > deadline:
                 raise ReadTimeout(
                     group, phase,
-                    f"apply (at {self._sms[group].applied_index()}) "
+                    f"apply (at {self.store.applied_index(group)}) "
                     f"did not reach read point {target} in time")
             time.sleep(min(tick, max(deadline - now, 0.0005)))
 
@@ -776,7 +867,8 @@ class RaftDB:
         # stages.get.wait: arrival -> this mode's freshness established;
         # stages.get.sql: the SELECT on SQLite (all modes together).
         t_fresh = time.monotonic()
-        rows = self._sms[group].query(query)
+        with self.store.use(group) as sm:
+            rows = sm.query(query)
         prof = self._prof()
         if prof is not None:
             prof.stage_many((("get.wait", t_fresh - t_in),
@@ -1063,7 +1155,7 @@ class RaftDB:
         for g in range(self.num_groups):
             row = groups.get(str(g))
             if row is not None:
-                row["applied"] = int(self._sms[g].applied_index())
+                row["applied"] = self.store.applied_index(g)
                 if lease_fn is not None:
                     row["lease_s"] = round(
                         max(lease_fn(g) - now, 0.0), 4)
@@ -1196,9 +1288,15 @@ class RaftDB:
             except Exception:                           # noqa: BLE001
                 pass
             self.replica_plane = None
+        if self._compactor is not None:
+            # A round in flight ends before the logs and the state
+            # machines it works on are closed (none starts after
+            # `_closed`).
+            self._compactor.join(timeout=30)
         err = self.pipe.close()
         self._reader.join(timeout=10)
         self._apply_pool.shutdown()
-        for sm in self._sms.values():
-            sm.close()
+        if self._compactor is not None:     # one the reader just started
+            self._compactor.join(timeout=30)
+        self.store.close()
         return err

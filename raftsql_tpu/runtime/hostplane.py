@@ -181,6 +181,11 @@ class ClusterHostPlane:
         # `benchmark` PR retires that reader (ROADMAP.md).
         self._wal_mirror = [0, 0, 0]
         self._wal_shard_syncs = 0       # last seen (sharded WALs only)
+        # A compaction sweep asked for by another thread (the apply
+        # thread, runtime/db.py) and not yet run: the newest
+        # (applied, covered, keep).  The TICK THREAD runs it, between
+        # two durable phases (see compact()).
+        self._compact_req: Optional[tuple] = None
         self._wal_hard: List[Optional[np.ndarray]] = [None] * P
         self._wal_groups: set = set()
         self._wal_wrote: Optional[Tuple[int, int]] = None   # last seen
@@ -450,6 +455,11 @@ class ClusterHostPlane:
             restored, seed)
         self._E = cfg.max_entries_per_msg
         self._gc_replay = None          # free the boot replay cache
+        if self.prof is not None:
+            self.prof.gauge_fn("wal.disk_bytes",
+                               lambda: self.wal_gauges()[0])
+            self.prof.gauge_fn("wal.segments_pinned",
+                               lambda: self.wal_gauges()[1])
 
     # -- subclass seams -------------------------------------------------
 
@@ -561,8 +571,16 @@ class ClusterHostPlane:
             # A witness replays its WAL for votes/terms/log only — it
             # has no apply plane, so nothing is re-published (the live
             # path in _publish_shard advances its cursor the same way).
-            if datas and g_peer_publishes:
+            # A group compacted up to its commit index has nothing to
+            # replay, but the consumer must still learn that the stream
+            # stands at its floor (runtime/db.py RaftDB._delivered), or
+            # a read at that index waits for the next election's no-op.
+            if (datas or gl.start) and g_peer_publishes:
                 self._commit_qs[p].put((RAW_PLAIN, g, gl.start, datas))
+        if starts:
+            # The WAL handle is told once what the replay found; the
+            # sweeps then tell it only what moves (storage/wal.py).
+            self.wals[p].seed_floors(starts)
         return restored_leaves(self.cfg, log_terms, hard,
                                starts=starts or None)
 
@@ -1343,6 +1361,11 @@ class ClusterHostPlane:
                 self._publish_inline(self._pending_pinfo,
                                      self._pending_tick)
             self._pending_pinfo = None
+        if self._compact_req is not None:
+            # Between two durable phases: the previous tick's is down
+            # (retired above, or finished inline last tick), this
+            # tick's starts after the readback below.
+            self._run_compact_request()
         t2 = _t.monotonic()
         if self.overlap_hook is not None:
             # Hook wall time is the caller's (apply-plane) cost, not a
@@ -1855,6 +1878,12 @@ class ClusterHostPlane:
                     self._wal_mirror[1] += len(m_peer)
                 for p in sorted(set(m_peer)):
                     self._ensure_epoch_begin(p)
+                floor_of = [pl.starts for pl in self.plogs]
+                if any(c and st <= floor_of[s][g]
+                       for (s, g, st, c) in zip(m_src, m_g, m_start,
+                                                m_count)):
+                    self._trim_mirror(m_peer, m_src, m_g, m_start,
+                                      m_count)
                 reads = [self.plogs[s].slice_columns(g, st, c)
                          if c else ([], [])
                          for (s, g, st, c) in zip(m_src, m_g, m_start,
@@ -2008,47 +2037,193 @@ class ClusterHostPlane:
 
     # -- log compaction (SURVEY §5.4) -----------------------------------
 
-    def compact(self, applied: Optional[Dict[int, int]] = None,
-                keep: int = 1024) -> bool:
-        """Advance every peer's compaction floor to (applied - keep):
-        payload-log prefixes drop, COMPACT markers land in the WALs, and
-        fully-superseded closed segments unlink (storage/wal.py compact)
-        — the memory-bound story for sustained load (the reference's
-        MemoryStorage grows forever, raft.go:129).
+    def compact(self, applied=None, keep: int = 1024,
+                covered=None) -> bool:
+        """One compaction sweep over every peer: payload-log prefixes
+        drop, COMPACT markers land in the WALs, and fully-superseded
+        closed segments unlink (storage/wal.py compact) — the
+        memory-bound story for sustained load (the reference's
+        MemoryStorage grows forever, raft.go:129).  True if a floor
+        moved.
 
-        `keep` is clamped to >= log_window so every index the device
-        ring can still reference stays servable (mirror reads and
-        in-window resends).  The publish cursor gates the floor: only
-        entries already delivered to the apply plane are dropped.
-        `applied` optionally tightens it further to the state machines'
-        DURABLY applied indexes — the calling convention RaftDB's
-        snapshot-driven compaction uses (runtime/db.py _maybe_compact),
-        so the --fused --resume --compact-every deployment works.
+        WHO RUNS IT.  The tick thread, between two durable phases: it
+        alone writes the WALs, the payload logs and `_hard`, so the
+        sweep takes no lock against it and needs none.  A caller that
+        drives `tick()` itself (tests, the chaos runners, the soak)
+        calls this between two ticks.  Any other thread (the apply
+        thread, runtime/db.py `_maybe_compact`) calls
+        `request_compact`, and the next tick runs the sweep inside its
+        dispatch window, where the previous tick's durable phase is
+        down and its own has not begun.  The publish workers and the
+        state machines move their cursors meanwhile: the sweep reads
+        each once, as arrays, and a value a moment old is only a lower
+        floor.
+
+        THE FLOORS, one pass over [P, G] arrays, no walk over groups:
+        a group's floor on peer p is `min(publish cursor, applied) -
+        keep`.  `keep` is clamped to >= log_window so every index the
+        device ring can still reference stays servable (mirror reads
+        and in-window resends).  The publish cursor gates the floor:
+        only entries already delivered to the apply plane are dropped.
+        `applied` ([G] array) tightens it to the indexes the state
+        machines have applied AND put on disk (models/store.py
+        `synced`: a commit alone is not synced, and what this sweep
+        unlinks no replay brings back) — the calling convention
+        RaftDB's snapshot-driven compaction uses, so the --fused
+        --resume --compact-every deployment works.
+
+        WHAT EVERY PEER HOLDS GOES, whatever `keep` says: a group's
+        floor is at least the highest index F that is in every peer's
+        durable log, at or below every peer's durable commit index and
+        publish cursor, and covered by the state machine (`covered`,
+        [G]: what the apply plane has on disk, or has been delivered
+        through where it has all it applied on disk; the publish
+        cursor where no state machine gates).
+        For a quiet group that is its last index.  Nothing is left
+        that a later step could ask this host for at or below F: every
+        peer holds the same committed prefix through F durably, so by
+        log matching no append with a previous index at or below F is
+        ever rejected, a reject's hint is never below the rejecting
+        peer's length, a leader's next index never walks below its
+        match index + 1, and a new leader starts at its own length + 1;
+        the one thing that can still name an index at or below F is a
+        batch re-sent before its ack was seen, which the receiver
+        already holds: the mirror trims such a read at the source's
+        floor (_trim_mirror).  A peer that lags holds F down to its
+        own length: nothing it lacks is ever dropped.  Without this
+        rule a node of mostly quiet groups never unlinks a segment: a
+        group with fewer than `keep` entries has no floor, the first
+        segment holds every group's election no-op, and the WAL stops
+        at the first segment it cannot delete.  CockroachDB truncates a
+        range's log to its committed index once every follower has it;
+        this is that rule.
         """
+        import time as _t
+        t0 = _t.monotonic()
+        P, G = self.cfg.num_peers, self.cfg.num_groups
         keep = max(keep, self.cfg.log_window)
+        starts = np.stack([pl.starts for pl in self.plogs])      # [P, G]
+        lens = np.stack([pl.lengths for pl in self.plogs])
+        floors, held = self._sweep_floors(
+            self._applied, starts, lens, self._hard[:, :, 2], applied,
+            covered, keep)
+        floors = np.maximum(floors, held[None, :])
+        pp, gg = np.nonzero(floors > starts)
+        moved = int(pp.size)
+        ff = floors[pp, gg]
+        by_peer = np.searchsorted(pp, np.arange(P + 1))
+        marks: List[Dict[int, Tuple[int, int]]] = []
+        for p in range(P):
+            lo, hi = by_peer[p], by_peer[p + 1]
+            gl, fl = gg[lo:hi].tolist(), ff[lo:hi].tolist()
+            terms = self.plogs[p].compact_many(gl, fl)
+            marks.append({g: (f, t) for g, f, t in zip(gl, fl, terms)})
+        # The WALs are asked every sweep, floors moved or not: a
+        # segment can have become deletable by CLOSING (the one that
+        # holds a sweep's re-asserted markers is, the moment it does),
+        # and a crash may have left doomed segments behind.
+        deleted = self._wal_compact(marks)
+        self.metrics.compactions += 1           # = compact.sweeps
+        if self.prof is not None:
+            self.prof.stage("compact.sweep", _t.monotonic() - t0)
+            self.prof.count((("compact.sweeps", 1),
+                             ("compact.floors_advanced", moved),
+                             ("wal.segments_unlinked", deleted)))
+        return bool(moved or deleted)
+
+    @staticmethod
+    def _sweep_floors(pub, starts, lens, commit, applied, covered,
+                      keep: int):
+        """([P, G] floors by the keep rule, [G] highest index every
+        peer holds, committed, published and covered) from the arrays
+        a sweep reads: the publish cursors, the payload logs' floors
+        and lengths, the durable commit indexes, all [P, G], and the
+        state machines' [G] `applied` / `covered` (None: no state
+        machine gates, the publish cursor does)."""
+        if applied is None:
+            gate = pub
+        else:
+            gate = np.minimum(pub, applied[None, :])
+            if covered is None:
+                covered = applied
+        held = np.minimum(np.minimum(lens, commit), pub).min(axis=0)
+        if covered is not None:
+            held = np.minimum(held, covered)
+        return gate - keep, held
+
+    def _wal_compact(self, marks: List[Dict[int, Tuple[int, int]]]
+                     ) -> int:
+        """Hand each peer's moved floors {group: (floor, term)} to the
+        WALs; returns the segments unlinked.  The hard states a WAL
+        must re-assert are looked up for the groups its doomed
+        segments name, nobody else's."""
         G = self.cfg.num_groups
-        any_changed = False
-        for p in range(self.cfg.num_peers):
-            plog = self.plogs[p]
-            floors: Dict[int, Tuple[int, int]] = {}
-            changed = False
-            for g in range(G):
-                floor = int(self._applied[p][g]) - keep
-                if applied is not None:
-                    floor = min(floor, applied.get(g, 0) - keep)
-                if floor > plog.start(g):
-                    plog.compact(g, floor, plog.term_of(g, floor))
-                    changed = True
-                s = plog.start(g)
-                if s > 0:
-                    floors[g] = (s, plog.term_of(g, s))
-            if changed:
-                hard = {g: tuple(int(x) for x in self._hard[p][g])
-                        for g in range(G)}
-                self.wals[p].compact(floors, hard)
-                self.metrics.compactions += 1
-                any_changed = True
-        return any_changed
+        if self._gcwal is not None:
+            # One shared log: every peer's floors in one call, by flat
+            # group id (peer * G + group).
+            flat = {p * G + g: v for p, m in enumerate(marks)
+                    for g, v in m.items()}
+            hard = self._hard
+
+            def hard_of(names):
+                flat_ids = np.asarray(names, np.int64)
+                rows = hard[flat_ids // G, flat_ids % G]
+                return rows[:, 0], rows[:, 1], rows[:, 2]
+            return self._gcwal.compact(flat, hard_of)
+        deleted = 0
+        for p, m in enumerate(marks):
+            hp = self._hard[p]
+
+            def hard_of(names, hp=hp):
+                rows = hp[np.asarray(names, np.int64)]
+                return rows[:, 0], rows[:, 1], rows[:, 2]
+            deleted += self.wals[p].compact(m, hard_of)
+        return deleted
+
+    def request_compact(self, applied, covered, keep: int) -> None:
+        """Ask the tick thread for a sweep (any thread; the newest
+        request wins).  `applied` and `covered` are the [G] arrays of
+        compact(); they are read when the sweep runs."""
+        self._compact_req = (applied, covered, keep)
+
+    def _run_compact_request(self) -> None:
+        applied, covered, keep = self._compact_req
+        self._compact_req = None
+        with span(self._ann, "tick.compact", self._tick_no):
+            self.compact(applied, keep, covered)
+
+    def _trim_mirror(self, m_peer, m_src, m_g, m_start, m_count) -> None:
+        """Cut the mirror rows that reach at or below their SOURCE's
+        compaction floor back to what lies above it (in place).  Only a
+        floor set to what every peer holds can be reached (compact():
+        the keep rule stays a ring window below any index the device
+        can name), and every peer held the log through that floor
+        before it was set: such a row is a batch re-sent before its
+        ack was seen, and its receiver has the entries.  A receiver
+        that has not is a fault: stop, as the slice's own floor check
+        did."""
+        for i, (p, s, g, st, c) in enumerate(zip(
+                m_peer, m_src, m_g, m_start, m_count)):
+            floor = self.plogs[s].start(g)
+            if not c or st > floor:
+                continue
+            have = self.plogs[p].length(g)
+            if have < min(st + c - 1, floor):
+                raise RuntimeError(
+                    f"peer {p} g{g}: append from peer {s} at {st} "
+                    f"reaches below the source's floor {floor} and "
+                    f"the receiver's log ends at {have}")
+            m_start[i] = floor + 1
+            m_count[i] = max(st + c - 1 - floor, 0)
+
+    def wal_gauges(self) -> Tuple[int, int]:
+        """(bytes of the WAL segments that exist, closed segments the
+        last sweep had to leave), over every WAL this plane writes;
+        kept by the WALs as they rotate and unlink (no directory is
+        listed for a scrape)."""
+        ws = [self._gcwal.base] if self._gcwal is not None else self.wals
+        return (sum(w.disk_bytes() for w in ws),
+                sum(w.segments_pinned for w in ws))
 
     # -- teardown -------------------------------------------------------
 

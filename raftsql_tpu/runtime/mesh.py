@@ -271,14 +271,30 @@ class ShardedWAL:
         return sum(b for b, _ in got), sum(n for _, n in got)
 
     def compact(self, floors, hard) -> int:
+        """`floors`: the floors that moved; a shard none of them lies
+        in is left alone.  `hard`: the function of group ids
+        WAL.compact takes, handed to each shard as it is."""
         deleted = 0
         for j, s in enumerate(self.shards):
             fj = {g: v for g, v in floors.items() if g // self._gl == j}
-            if not fj:
-                continue
-            hj = {g: v for g, v in hard.items() if g // self._gl == j}
-            deleted += s.compact(fj, hj)
+            if fj:
+                deleted += s.compact(fj, hard)
         return deleted
+
+    def seed_floors(self, floors) -> None:
+        for j, s in enumerate(self.shards):
+            fj = {g: v for g, v in floors.items() if g // self._gl == j}
+            if fj:
+                s.seed_floors(fj)
+
+    def disk_bytes(self) -> int:
+        return sum(s.disk_bytes() for s in self.shards
+                   if isinstance(s, WAL))
+
+    @property
+    def segments_pinned(self) -> int:
+        return sum(s.segments_pinned for s in self.shards
+                   if isinstance(s, WAL))
 
     def close(self) -> None:
         for s in self.shards:

@@ -1123,8 +1123,11 @@ class RaftNode:
                     floors[g] = (s, self.payload_log.term_of(g, s))
             if not changed:
                 return False
-            hard = {g: tuple(int(x) for x in self._hard_np[g])
-                    for g in range(self.cfg.num_groups)}
+            hard_np = self._hard_np
+
+            def hard(names):
+                rows = hard_np[np.asarray(names, np.int64)]
+                return rows[:, 0], rows[:, 1], rows[:, 2]
             self.wal.compact(floors, hard)
             self.metrics.compactions += 1
             return True

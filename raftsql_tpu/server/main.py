@@ -24,23 +24,39 @@ import threading
 import time
 
 
-def _raise_nofile_limit(need: int) -> None:
-    """Every group owns a SQLite handle (and the WALs, rings and sockets
-    come on top), so a many-group deployment outgrows the default soft
-    RLIMIT_NOFILE long before the machine's real limit.  Raise the soft
-    limit to the hard one; if even that cannot hold `need` descriptors,
-    stop with a sentence that names the limit instead of crashing in
-    `sqlite3.connect` halfway through boot."""
+def _raise_nofile_limit(resume: bool) -> None:
+    """The state-machine store (models/store.py) holds SQLite handles
+    for the groups in use and budgets them by what RLIMIT_NOFILE
+    leaves: so raise the soft limit to the hard one, which is the
+    largest budget this process can have, whatever --groups says.  A
+    hard limit under what the store needs to work at all (its least
+    number of handles, of three descriptors each in resume mode and
+    one in parity mode, beside the reserve for WALs, rings and
+    sockets) stops here, with a sentence that names the limit,
+    instead of in `sqlite3.connect` halfway through a run."""
     import resource
+
+    from raftsql_tpu.models.store import MIN_HANDLES, files_needed
+    need = files_needed(3 if resume else 1)
     soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
-    if soft != hard:
-        resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
     if hard != resource.RLIM_INFINITY and hard < need:
         raise SystemExit(
-            f"raftsql: this deployment needs about {need} open files "
-            f"(one SQLite database per group) but RLIMIT_NOFILE's hard "
-            f"limit is {hard}; raise it (ulimit -n / LimitNOFILE) or "
-            "serve fewer --groups")
+            f"raftsql: this deployment needs at least {need} open files "
+            f"({MIN_HANDLES} SQLite handles for the groups in use, "
+            f"besides the WALs, rings and sockets) but RLIMIT_NOFILE's "
+            f"hard limit is {hard}; raise it (ulimit -n / LimitNOFILE)")
+    if soft != hard:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
+
+
+def _groups_on_file(path_of, groups: int, resume: bool) -> list:
+    """The groups whose database a former process left, for RaftDB's
+    `existing` (under --resume their applied indexes are read off the
+    files at boot; without it every file is rebuilt and none counts)."""
+    import os
+    if not resume:
+        return []
+    return [g for g in range(groups) if os.path.exists(path_of(g))]
 
 
 from raftsql_tpu.api.http import SQLServer
@@ -102,13 +118,16 @@ def build_node(cluster: str, node_id: int, groups: int = 1,
     if trace:
         pipe.node.enable_tracing()
 
-    def sm_factory(g: int) -> SQLiteStateMachine:
-        path = (f"{data_prefix}-{node_id}.db" if g == 0
+    def path_of(g: int) -> str:
+        return (f"{data_prefix}-{node_id}.db" if g == 0
                 else f"{data_prefix}-{node_id}-g{g}.db")
-        return SQLiteStateMachine(path, resume=resume)
+
+    def sm_factory(g: int) -> SQLiteStateMachine:
+        return SQLiteStateMachine(path_of(g), resume=resume)
 
     return RaftDB(sm_factory, pipe, num_groups=groups, resume=resume,
-                  compact_every=compact_every, compact_keep=compact_keep)
+                  compact_every=compact_every, compact_keep=compact_keep,
+                  existing=_groups_on_file(path_of, groups, resume))
 
 
 def _start_ticking(node, tick: float) -> None:
@@ -178,13 +197,16 @@ def build_fused_node(groups: int = 1, peers: int = 3,
         node.enable_tracing()
     pipe = FusedPipe(node)
 
-    def sm_factory(g: int) -> SQLiteStateMachine:
-        path = (f"{data_prefix}-fused.db" if g == 0
+    def path_of(g: int) -> str:
+        return (f"{data_prefix}-fused.db" if g == 0
                 else f"{data_prefix}-fused-g{g}.db")
-        return SQLiteStateMachine(path, resume=resume)
+
+    def sm_factory(g: int) -> SQLiteStateMachine:
+        return SQLiteStateMachine(path_of(g), resume=resume)
 
     rdb = RaftDB(sm_factory, pipe, num_groups=groups, resume=resume,
-                 compact_every=compact_every, compact_keep=compact_keep)
+                 compact_every=compact_every, compact_keep=compact_keep,
+                 existing=_groups_on_file(path_of, groups, resume))
     _start_ticking(node, tick)
     return rdb
 
@@ -229,14 +251,16 @@ def build_mesh_node(groups: int = 8, peers: int = 3,
     pipe = FusedPipe(node)
     g_loc = groups // mc.group_shards
 
+    def path_of(g: int) -> str:
+        return f"{data_prefix}-mesh-db/s{g // g_loc}/g{g}.db"
+
     def sm_factory(g: int) -> SQLiteStateMachine:
-        d = f"{data_prefix}-mesh-db/s{g // g_loc}"
-        _os.makedirs(d, exist_ok=True)
-        return SQLiteStateMachine(_os.path.join(d, f"g{g}.db"),
-                                  resume=resume)
+        _os.makedirs(_os.path.dirname(path_of(g)), exist_ok=True)
+        return SQLiteStateMachine(path_of(g), resume=resume)
 
     rdb = RaftDB(sm_factory, pipe, num_groups=groups, resume=resume,
-                 compact_every=compact_every, compact_keep=compact_keep)
+                 compact_every=compact_every, compact_keep=compact_keep,
+                 existing=_groups_on_file(path_of, groups, resume))
     _start_ticking(node, tick)
     return rdb
 
@@ -346,12 +370,15 @@ def build_pod_node(groups: int = 8, peers: int = 3, tick: float = 0.01,
             # owner-served (PodRaftDB), so no file may exist here.
             return SQLiteStateMachine(":memory:", resume=False)
         _os.makedirs(db_dir, exist_ok=True)
-        return SQLiteStateMachine(_os.path.join(db_dir, f"g{g}.db"),
-                                  resume=resume)
+        return SQLiteStateMachine(path_of(g), resume=resume)
+
+    def path_of(g: int) -> str:
+        return _os.path.join(db_dir, f"g{g}.db")
 
     return PodRaftDB(sm_factory, pipe, num_groups=groups, resume=resume,
                      compact_every=compact_every,
-                     compact_keep=compact_keep)
+                     compact_keep=compact_keep,
+                     existing=_groups_on_file(path_of, groups, resume))
 
 
 # Exit code when the consensus engine dies of a fatal error (failed
@@ -443,15 +470,28 @@ def main(argv=None) -> None:
     ap.add_argument("--tick", type=float, default=0.01,
                     help="seconds per consensus tick")
     ap.add_argument("--resume", action="store_true",
-                    help="snapshot-resume: keep the SQLite file across "
+                    help="snapshot-resume: keep the SQLite files across "
                          "restarts and skip re-applying the replayed "
-                         "prefix (default: reference delete-and-replay)")
+                         "prefix (default: reference delete-and-replay)."
+                         "  A group's file is opened on first use; a "
+                         "handle holds three descriptors (one without "
+                         "--resume) and the open ones are kept within "
+                         "RLIMIT_NOFILE, least recently used closed first")
     ap.add_argument("--compact-every", type=int, default=0,
-                    help="with --resume: advance WAL compaction floors "
-                         "(and drop covered segments) every N applies")
+                    help="with --resume: one compaction sweep every N "
+                         "applied entries, node-wide: the SQLite files "
+                         "written since the last one are checkpointed "
+                         "and fsynced, floors move to what is on disk "
+                         "- --compact-keep (to its last index "
+                         "for a group every peer has caught up on), "
+                         "floor markers are appended, and closed WAL "
+                         "segments under every floor are unlinked, "
+                         "oldest first; without --resume it never acts "
+                         "and the log says so")
     ap.add_argument("--compact-keep", type=int, default=1024,
                     help="entries retained above the compaction floor "
-                         "for follower catch-up")
+                         "of a group with traffic (never less than the "
+                         "device ring, 256)")
     ap.add_argument("--wal-segment-bytes", type=int, default=4 << 20,
                     help="rotate WAL segments at this size; compaction "
                          "unlinks whole covered segments")
@@ -608,7 +648,7 @@ def main(argv=None) -> None:
     from raftsql_tpu.utils.device import select_device
     select_device()
     # SQLite keeps -wal/-shm sidecars open per database in resume mode.
-    _raise_nofile_limit(args.groups * (3 if args.resume else 1) + 512)
+    _raise_nofile_limit(args.resume)
     t_boot = time.monotonic()
 
     # RAFTSQL_PROFILE=<dir>: cProfile of the consensus tick thread,

@@ -160,6 +160,10 @@ class StorageFaultInjector:
         # path -> durable size at last REAL fsync (for unsynced-loss
         # crash simulation; a path absent here was never synced).
         self.synced_size: Dict[str, int] = {}
+        # Path substring whose next unlink is the crash point (WAL
+        # compaction: the re-asserted records are durable, the doomed
+        # segment still there), or None.  Consumed when it fires.
+        self.crash_unlink: Optional[str] = None
         self._lock = threading.Lock()
 
     def add_rule(self, substring: str, fail_at=(),
@@ -431,6 +435,19 @@ def fsync_file(f) -> None:
         if not inj.on_fsync(getattr(f, "name", ""), f.tell()):
             return                       # silent loss: report success
     os.fsync(f.fileno())
+
+
+def unlink(path: str) -> None:
+    """Remove a file (a WAL segment compaction has superseded) through
+    the fault layer: an injector whose `crash_unlink` matches raises
+    CrashPointError INSTEAD, once: the machine died before the
+    unlink."""
+    inj = _injector
+    if inj is not None and inj.crash_unlink is not None \
+            and inj.crash_unlink in path:
+        inj.crash_unlink = None
+        raise CrashPointError(f"crash before unlink of {path}")
+    os.unlink(path)
 
 
 def fsync_dir(path: str) -> None:

@@ -29,6 +29,8 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
+import numpy as np
+
 
 class PayloadLog:
     """1-based, truncate-on-conflict (term, bytes) log for G groups.
@@ -43,6 +45,14 @@ class PayloadLog:
         self._datas: List[List[bytes]] = [[] for _ in range(num_groups)]
         self._start: List[int] = [0] * num_groups
         self._start_term: List[int] = [0] * num_groups
+        # Every group's floor and length once more, as arrays: what the
+        # compaction sweep reads of ALL groups at once
+        # (runtime/hostplane.py compact), kept by the writers below so
+        # that nobody walks the lists for it.  A reader on another
+        # thread sees a value a moment old; both only grow, but for a
+        # conflict truncation, which never reaches a committed index.
+        self.starts = np.zeros(num_groups, np.int64)
+        self.lengths = np.zeros(num_groups, np.int64)
         # One lock: readers (publish, catch-up, send) race the compactor,
         # and a torn (_start, lists) read would mis-align indexes.
         self._mu = __import__("threading").RLock()
@@ -62,6 +72,7 @@ class PayloadLog:
             assert not self._datas[group]
             self._start[group] = start
             self._start_term[group] = start_term
+            self.starts[group] = self.lengths[group] = start
 
     def reset(self, group: int, start: int, start_term: int) -> None:
         """Discard the group's entire log and restart it at `start` (the
@@ -72,6 +83,7 @@ class PayloadLog:
             self._datas[group].clear()
             self._start[group] = start
             self._start_term[group] = start_term
+            self.starts[group] = self.lengths[group] = start
 
     def compact(self, group: int, upto: int, boundary_term: int) -> None:
         """Drop entries <= upto (must be <= length)."""
@@ -83,6 +95,29 @@ class PayloadLog:
             del self._datas[group][: upto - s]
             self._start[group] = upto
             self._start_term[group] = boundary_term
+            self.starts[group] = upto
+
+    def compact_many(self, groups: Sequence[int],
+                     floors: Sequence[int]) -> List[int]:
+        """`compact(g, floor, term_of(g, floor))` for each pair under
+        one lock hold; returns the boundary terms, in order (what the
+        WAL's floor markers carry).  A pair at or below its group's
+        floor changes nothing and returns the boundary term there."""
+        out = []
+        with self._mu:
+            for g, upto in zip(groups, floors):
+                s = self._start[g]
+                if upto <= s:
+                    out.append(self._start_term[g])
+                    continue
+                term = self._terms[g][upto - 1 - s]
+                del self._terms[g][: upto - s]
+                del self._datas[g][: upto - s]
+                self._start[g] = upto
+                self._start_term[g] = term
+                self.starts[g] = upto
+                out.append(term)
+        return out
 
     def get(self, group: int, index: int) -> bytes:
         with self._mu:
@@ -222,3 +257,4 @@ class PayloadLog:
         if new_len is not None and new_len - off < len(dl):
             del tl[max(new_len - off, 0):]
             del dl[max(new_len - off, 0):]
+        self.lengths[group] = off + len(dl)

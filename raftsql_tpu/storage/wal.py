@@ -176,6 +176,11 @@ class _SegStats:
     the set of groups with HARDSTATE records."""
     max_idx: Dict[int, int] = field(default_factory=dict)
     hs: Set[int] = field(default_factory=set)
+    # max_idx once more as (groups, indexes) arrays, made when a
+    # CLOSED segment is first asked whether it may go: compact() then
+    # answers with one gather and one compare, whatever the segment
+    # holds (the first one holds every group's election no-op).
+    arrays: Optional[tuple] = None
 
     def bump(self, group: int, index: int) -> None:
         if index > self.max_idx.get(group, -1):
@@ -183,6 +188,15 @@ class _SegStats:
 
     def groups(self) -> Set[int]:
         return set(self.max_idx) | self.hs
+
+    def as_arrays(self) -> tuple:
+        if self.arrays is None:
+            import numpy as np
+            n = len(self.max_idx)
+            self.arrays = (
+                np.fromiter(self.max_idx.keys(), np.int64, n),
+                np.fromiter(self.max_idx.values(), np.int64, n))
+        return self.arrays
 
 
 def split_uniform_runs(start: int, terms) -> List[Tuple[int, int, int]]:
@@ -257,6 +271,23 @@ class WAL:
         self._active_stats = _SegStats()
         self._closed_stats: Dict[str, _SegStats] = {}
         self._marker_floor: Dict[int, int] = {}
+        # Every group's compaction floor as this handle knows it: told
+        # by compact() as floors advance, seeded by the owning runtime
+        # from the replay at boot (seed_floors).  compact() is handed
+        # only the floors that MOVED; whether a closed segment may go
+        # is asked of this array ([group id] -> floor index, 0 = none;
+        # it grows with the highest group id seen).
+        self._floor_idx = None          # np.int64 array, made on demand
+        self._floors: Dict[int, Tuple[int, int]] = {}
+        # Bytes of the closed segments that exist, by path (the
+        # wal.disk_bytes gauge; what a restart has to read), and the
+        # closed segments the last compact() had to leave.
+        self._seg_bytes: Dict[str, int] = {
+            path: os.path.getsize(path) for _, path in segs
+            if path != self.path}
+        self._closed_bytes = sum(self._seg_bytes.values())
+        self.segments_pinned = 0
+        self.segments_unlinked = 0
         # Latest applied-membership baseline per group (set_conf),
         # re-asserted into the active segment when compaction unlinks
         # the segment that held it — same survival contract as hard
@@ -317,6 +348,11 @@ class WAL:
         grow, so a caller on the writing thread takes differences (the
         host plane's wal.bytes / wal.fsyncs counters)."""
         return self._rotated + self._bytes, self.syncs
+
+    def disk_bytes(self) -> int:
+        """Bytes of the segments that exist: kept as the log rotates
+        and compact() unlinks, so a scrape lists no directory."""
+        return self._closed_bytes + self._bytes
 
     # -- write path ------------------------------------------------------
 
@@ -607,6 +643,34 @@ class WAL:
             return
         self._write(_SNAP.pack(REC_COMPACT, group, index, term))
 
+    def _write_compact_recs(self, groups, indexes, terms) -> None:
+        """`_write_compact_rec` for parallel sequences, in ONE native
+        call (a sweep writes hundreds of markers, and every call from
+        Python hands the interpreter over)."""
+        n = len(groups)
+        if n == 0:
+            return
+        if self._lib is None:
+            for g, i, t in zip(groups, indexes, terms):
+                self._write_compact_rec(int(g), int(i), int(t))
+            return
+        import ctypes
+
+        import numpy as np
+        ga = np.ascontiguousarray(groups, np.uint32)
+        ia = np.ascontiguousarray(indexes, np.uint64)
+        ta = np.ascontiguousarray(terms, np.uint64)
+        bump = self._active_stats.bump
+        for g, i in zip(ga.tolist(), ia.tolist()):
+            bump(g, i)
+        self._lib.wal_set_compacts(
+            self._h, n,
+            ga.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+            ia.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+            ta.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
+        self._pending = True
+        self._bytes += n * (_HDR.size + _SNAP.size)
+
     def mark_compact(self, group: int, index: int, term: int) -> None:
         """Compaction floor marker: on replay, entries of `group` at or
         below `index` are dropped; the suffix survives.  Idempotent per
@@ -646,6 +710,8 @@ class WAL:
         durable record stream."""
         self._close_handle()
         self._closed_stats[self.path] = self._active_stats
+        self._seg_bytes[self.path] = self._bytes
+        self._closed_bytes += self._bytes
         self._active_stats = _SegStats()
         self._seq += 1
         self.path = os.path.join(self.dirname, f"wal-{self._seq}.log")
@@ -714,16 +780,57 @@ class WAL:
         self._closed_stats[path] = st
         return st
 
-    def compact(self, floors: Dict[int, Tuple[int, int]],
-                hard: Dict[int, Tuple[int, int, int]]) -> int:
+    def seed_floors(self, floors: Dict[int, Tuple[int, int]]) -> None:
+        """What the replay found: {group: (floor_index, floor_term)} of
+        every group whose log starts above 0.  The owning runtime hands
+        it over once, at boot, so that compact() can be told only what
+        moves afterwards.  The markers that carry these floors are in
+        the segments that exist (compact() re-asserts a marker before
+        it unlinks the segment that held it), so none is written."""
+        for g, (idx, term) in floors.items():
+            self._note_floor(g, idx, term)
+            if idx > self._marker_floor.get(g, 0):
+                self._marker_floor[g] = idx
+
+    def _note_floor(self, group: int, idx: int, term: int) -> None:
+        import numpy as np
+        fa = self._floor_idx
+        if fa is None or group >= fa.size:
+            grown = np.zeros(max(2 * group + 2, 1024), np.int64)
+            if fa is not None:
+                grown[:fa.size] = fa
+            fa = self._floor_idx = grown
+        if idx > fa[group]:
+            fa[group] = idx
+            self._floors[group] = (idx, term)
+
+    def _may_go(self, st: _SegStats) -> bool:
+        """Whether every entry and marker of a closed segment is at or
+        below its group's floor."""
+        groups, top = st.as_arrays()
+        if not groups.size:
+            return True
+        fa = self._floor_idx
+        if fa is None or int(groups.max()) >= fa.size:
+            return False                # a group no floor was ever told of
+        # A floor of 0 covers nothing, not even index 0.
+        f = fa[groups]
+        return bool(((f > 0) & (top <= f)).all())
+
+    def compact(self, floors: Dict[int, Tuple[int, int]], hard) -> int:
         """Advance compaction floors and drop fully-superseded segments.
 
         floors: {group: (floor_index, floor_term)} — the durable
-          snapshot-covered boundary per group (every group with a nonzero
-          payload-log start, not just newly compacted ones).
-        hard: {group: (term, vote, commit)} — current hard states, used
-          to re-assert state for groups whose only hardstate records live
-          in a segment being deleted.
+          snapshot-covered boundary of every group whose floor MOVED
+          (a caller may as well pass every floor it knows: this handle
+          remembers what it was told and what `seed_floors` gave it).
+        hard: the current hard states, used to re-assert state for
+          groups whose only hardstate records live in a segment being
+          deleted: a function that is called ONCE with the sorted list
+          of the groups the doomed segments name and returns their
+          (terms, votes, commits), three sequences in that order (the
+          owning runtime has every group's hard state and lists only
+          those asked for).
 
         Appends COMPACT markers for advanced floors, then walks closed
         segments oldest-first and unlinks each whose every entry/marker
@@ -734,12 +841,14 @@ class WAL:
 
         Returns the number of deleted segments.
         """
-        wrote = False
+        marks = []
         for g, (idx, term) in sorted(floors.items()):
+            self._note_floor(g, idx, term)
             if idx > self._marker_floor.get(g, 0):
-                self.mark_compact(g, idx, term)
-                wrote = True
-        if wrote:
+                self._marker_floor[g] = idx
+                marks.append((g, idx, term))
+        if marks:
+            self._write_compact_recs(*zip(*marks))
             self.sync()
 
         # Find the longest deletable prefix run first, then re-assert the
@@ -748,29 +857,29 @@ class WAL:
         # node's WAL lock across this).
         run: List[str] = []
         affected: Set[int] = set()
-        for seq, path in _segment_paths(self.dirname):
-            if path == self.path:
-                break                   # never delete the active segment
+        closed = [path for _, path in _segment_paths(self.dirname)
+                  if path != self.path]
+        for path in closed:
             st = self._stats_for(path)
-            ok = all(
-                g in floors and idx <= floors[g][0]
-                for g, idx in st.max_idx.items()
-            ) and all(g in hard for g in st.hs - set(st.max_idx))
-            if not ok:
+            if not self._may_go(st):
                 break
             run.append(path)
             affected |= st.groups()
+        self.segments_pinned = len(closed) - len(run)
         if not run:
             return 0
         # Re-assert everything the doomed segments contributed, into the
         # active segment, durably, BEFORE the unlinks: hard states
         # (last-wins, and `hard` is current so appending it last is
-        # correct) and floor markers (replay must re-learn start).
-        for g in sorted(affected):
-            if g in hard:
-                self.set_hardstate(g, *hard[g])
-            if g in floors:
-                self._write_compact_rec(g, *floors[g])
+        # correct) and floor markers (replay must re-learn start); one
+        # batched call a record type, not one a group.
+        names = sorted(affected)
+        self.set_hardstates(names, *hard(names))
+        marked = [(g,) + self._floors[g] for g in names
+                  if g in self._floors]
+        if marked:
+            self._write_compact_recs(*zip(*marked))
+        for g in names:
             conf = self._conf_latest.get(g)
             if conf is not None and self._lib is None:
                 # The membership baseline must survive the unlink too:
@@ -785,8 +894,11 @@ class WAL:
                 self._write(dd)
         self.sync()
         for path in run:
-            os.unlink(path)
+            fsio.unlink(path)
             self._closed_stats.pop(path, None)
+            self._closed_bytes -= self._seg_bytes.pop(
+                path, 0)
+        self.segments_unlinked += len(run)
         _fsync_dir(self.dirname)
         return len(run)
 
@@ -1000,8 +1112,6 @@ class GroupCommitWAL:
         self._dirty: Set[int] = set()
         self._open_views = 0
         self._epoch_last: Optional[Tuple[int, bool]] = None
-        self._floors: Dict[int, Tuple[int, int]] = {}
-        self._hard: Dict[int, Tuple[int, int, int]] = {}
         self.group_commits = 0
         self.batch_hist: Dict[int, int] = {}
         self._views = [WALGroupView(self, p) for p in range(num_peers)]
@@ -1065,17 +1175,20 @@ class GroupCommitWAL:
             self.group_commits += 1
             self.batch_hist[batch] = self.batch_hist.get(batch, 0) + 1
 
-    def compact_view(self, bias: int, floors, hard) -> int:
-        """Per-view compaction: floors/hard merge into the cluster-wide
-        flat dicts (segment deletability needs EVERY peer's floors —
-        one peer's view alone could never prove a shared segment
-        fully superseded)."""
+    def compact(self, floors, hard) -> int:
+        """The whole shared log at once: `floors` by FLAT group id
+        (the floors that moved, of every peer), `hard` a function of
+        flat ids (WAL.compact).  What the host plane's sweep calls:
+        one marker pass, one re-assert, one walk over the segments for
+        all P peers."""
         with self._mu:
-            self._floors.update(
-                {g + bias: v for g, v in floors.items()})
-            self._hard.update({g + bias: v for g, v in hard.items()})
-            return self.base.compact(dict(self._floors),
-                                     dict(self._hard))
+            return self.base.compact(floors, hard)
+
+    def seed_floors(self, bias: int, floors) -> None:
+        self.base.seed_floors({g + bias: v for g, v in floors.items()})
+
+    def disk_bytes(self) -> int:
+        return self.base.disk_bytes()
 
     def close_view(self) -> None:
         with self._mu:
@@ -1169,8 +1282,8 @@ class WALGroupView:
     def sync(self) -> None:
         self._owner.sync()
 
-    def compact(self, floors, hard) -> int:
-        return self._owner.compact_view(self.group_bias, floors, hard)
+    def seed_floors(self, floors) -> None:
+        self._owner.seed_floors(self.group_bias, floors)
 
     def close(self) -> None:
         self._owner.close_view()

@@ -284,7 +284,9 @@ def test_wal_conf_baseline_survives_compaction(tmp_path):
         for i in range(11, 41):
             w.append_entry(0, i, 1, b"x" * 24)
         w.sync()
-        w.compact({0: (30, 1)}, {0: (1, -1, 35)})
+        n = len  # one hard state for whatever the segments name
+        w.compact({0: (30, 1)}, lambda names: (
+            [1] * n(names), [-1] * n(names), [35] * n(names)))
         w.close()
     logs = WAL.replay(str(tmp_path / "w"))
     assert logs[0].start == 30

@@ -290,11 +290,11 @@ class TestInstallSnapshot:
             # its applied_index/data to come back from the FILE without
             # needing another transfer (sqlite3.deserialize detaches to
             # memory — install writes the image to the path instead).
-            installed_applied = dbs[1]._sms[0].applied_index()
+            installed_applied = dbs[1].store.applied_index(0)
             assert installed_applied >= 120
             dbs[1].close()
             dbs[1] = _boot(tmp_path, hub, cfg, 1, resume=True)
-            assert dbs[1]._sms[0].applied_index() >= installed_applied
+            assert dbs[1].store.applied_index(0) >= installed_applied
             assert "999" in dbs[1].query("SELECT v FROM t")
         finally:
             for db in dbs:

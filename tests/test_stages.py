@@ -146,7 +146,12 @@ def test_metrics_document_holds_the_new_keys(served):
     assert set(doc["wal"]) == {"records", "bytes", "hardstates",
                                "groups_written", "fsyncs", "shard_syncs",
                                "mirror_rows", "mirror_fallback_rows",
-                               "mirror_skipped_rows"}
+                               "mirror_skipped_rows", "segments_unlinked",
+                               "segments_pinned", "disk_bytes"}
+    assert set(doc["compact"]) == {"sweeps", "floors_advanced"}
+    assert set(doc["stages"]["compact"]) == {"sweep", "checkpoint"}
+    assert set(doc["sm"]) == {"opens", "closes", "evictions",
+                              "open_handles"}
     assert set(doc["stages"]["publish"]) == {"queue"}
     assert "mesh_put" not in doc["phase_profile"]   # the mesh's alone
     assert {"launch", "readback", "wal_plan", "wal_append",

@@ -10,6 +10,15 @@ from raftsql_tpu.transport.base import (AppendRec, ProposalRec, TickBatch,
 from raftsql_tpu.transport.codec import decode_batch, encode_batch
 
 
+def hard_of(states):
+    """{group: (term, vote, commit)} as the function WAL.compact asks
+    for the hard states of the groups a doomed segment names."""
+    def fn(names):
+        rows = [states[g] for g in names]
+        return tuple(zip(*rows)) if rows else ((), (), ())
+    return fn
+
+
 class TestWAL:
     def test_roundtrip(self, tmp_path):
         d = str(tmp_path / "w")
@@ -126,7 +135,7 @@ class TestSegmentation:
             w.sync()
         segs0 = sorted((tmp_path / "w").glob("wal-*.log"))
         assert len(segs0) > 3
-        deleted = w.compact({0: (30, 2)}, {0: (2, 0, 40)})
+        deleted = w.compact({0: (30, 2)}, hard_of({0: (2, 0, 40)}))
         assert deleted > 0
         segs1 = sorted((tmp_path / "w").glob("wal-*.log"))
         assert len(segs1) < len(segs0)
@@ -148,7 +157,7 @@ class TestSegmentation:
         for i in range(1, 41):
             w.append_entry(0, i, 1, f"e{i}".encode())
             w.sync()
-        deleted = w.compact({0: (5, 1)}, {0: (1, -1, 40)})
+        deleted = w.compact({0: (5, 1)}, hard_of({0: (1, -1, 40)}))
         w.close()
         gl = WAL.replay(d)[0]
         assert gl.start == 5
@@ -167,11 +176,10 @@ class TestSegmentation:
             w.append_entry(1, i, 1, f"b{i}".encode())
             w.sync()
         # Only group 0 has a floor; group 1 pins every segment.
-        assert w.compact({0: (15, 1)}, {0: (1, -1, 20),
-                                        1: (1, -1, 20)}) == 0
+        hard = hard_of({0: (1, -1, 20), 1: (1, -1, 20)})
+        assert w.compact({0: (15, 1)}, hard) == 0
         # Give group 1 a floor too: early segments can go.
-        assert w.compact({0: (15, 1), 1: (15, 1)},
-                         {0: (1, -1, 20), 1: (1, -1, 20)}) > 0
+        assert w.compact({0: (15, 1), 1: (15, 1)}, hard) > 0
         w.close()
         groups = WAL.replay(d)
         assert groups[0].start == 15 and groups[1].start == 15
@@ -224,7 +232,7 @@ class TestSegmentation:
             w.append_entry(0, i, 1, f"e{i}".encode())
             w.set_hardstate(0, 1, -1, i)
             w.sync()
-        assert w.compact({0: (30, 1)}, {0: (1, -1, 40)}) > 0
+        assert w.compact({0: (30, 1)}, hard_of({0: (1, -1, 40)})) > 0
         w.close()
         gl = WAL.replay(d)[0]
         assert gl.start == 30
@@ -689,10 +697,10 @@ class TestRangeRecords:
         # from bytes (the _stats_for parse under test).
         w._closed_stats.clear()
         # Floor at 2 does not cover the first segment's range 1-4.
-        removed = w.compact({0: (2, 1)}, {0: (1, -1, 0)})
+        removed = w.compact({0: (2, 1)}, hard_of({0: (1, -1, 0)}))
         assert removed == 0
         # Floor at 6 covers both closed ranges.
-        removed = w.compact({0: (6, 1)}, {0: (1, -1, 0)})
+        removed = w.compact({0: (6, 1)}, hard_of({0: (1, -1, 0)}))
         assert removed >= 1
         w.close()
         gl = WAL.replay(d)[0]
