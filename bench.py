@@ -1395,7 +1395,7 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
     from raftsql_tpu.models.kv_sm import KVStateMachine
     from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
     from raftsql_tpu.runtime.db import _expand_commit_item
-    from raftsql_tpu.runtime.fused import FusedClusterNode
+    from raftsql_tpu.runtime.fused import PIPELINE_STEPS, FusedClusterNode
 
     E = int(os.environ.get("BENCH_E", "8"))
     mesh_cfg = None
@@ -1482,10 +1482,13 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
         # WAL group commit (PR 7): one shared log + one fsync per tick
         # for all P peers — the durable rung's default; 0 restores the
         # per-peer-file layout for A/Bs.
+        # The dispatch the served node makes (server/main.py): the
+        # pipeline's depth in one launch.
         node = FusedClusterNode(
             cfg, tmp,
             group_commit=os.environ.get(
-                "BENCH_WAL_GROUP_COMMIT", "1") == "1")
+                "BENCH_WAL_GROUP_COMMIT", "1") == "1",
+            steps=PIPELINE_STEPS)
     node.publish_peers = {0}       # the drain consumes peer 0's stream
     try:
         for t in range(40 * cfg.election_ticks):
@@ -2089,16 +2092,7 @@ def main() -> int:
                        # plane): E=64 beats 32 (768k vs 525k commits/s)
                        # and 128 (590k — WAL bytes dominate past the
                        # framing amortization).
-                       "BENCH_E": os.environ.get("BENCH_E", "64"),
-                       # Multi-step dispatch: S consensus steps per
-                       # device program amortize the fixed dispatch +
-                       # readback cost S-fold at the price of S x
-                       # device compute.  CPU measurement: -13%
-                       # throughput, p99 220->143ms.  Not measured on
-                       # the chip at the present code.
-                       "RAFTSQL_FUSED_STEPS": os.environ.get(
-                           "RAFTSQL_FUSED_STEPS",
-                           os.environ.get("BENCH_TPU_STEPS", "8"))},
+                       "BENCH_E": os.environ.get("BENCH_E", "64")},
             label="durable-tpu-fused")
 
     # -- 3. durable-path child, host runtime with the step pinned to
@@ -2151,12 +2145,7 @@ def main() -> int:
             "cpu", min(timeout_s, remaining() - reserve),
             extra_env={"BENCH_CONFIG": "durable",
                        "BENCH_DURABLE_MODE": "fused",
-                       "BENCH_E": os.environ.get("BENCH_E", "64"),
-                       # Interleaved A/B at G=1000/E=64 on one core:
-                       # S=4 wins both pairs (625/681k vs 543/630k) —
-                       # bigger per-dispatch WAL batches.
-                       "RAFTSQL_FUSED_STEPS": os.environ.get(
-                           "RAFTSQL_FUSED_STEPS", "4")},
+                       "BENCH_E": os.environ.get("BENCH_E", "64")},
             label="durable-cpu-fused")
 
     # -- 3d. latency child on the device: ONE small shape (G=1024, E=16)

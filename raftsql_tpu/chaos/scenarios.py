@@ -231,12 +231,10 @@ class FusedChaosRunner:
         this with the mesh runtime (same host plane, sharded device
         step + sharded WAL dirs)."""
         return FusedClusterNode(self.cfg, self.data_dir,
-                                seed=self.sched.seed)
+                                seed=self.sched.seed, steps=self.steps)
 
     def _boot(self, first: bool) -> FusedClusterNode:
         node = self._make_node()
-        if self.steps > 1:
-            node._steps = self.steps
         node.publish_peers = self.PUBLISH_PEERS
         # Flight recorder feed (raftsql_tpu/obs/): device event ring +
         # host spans, dumped next to the seed on invariant failure.
@@ -591,8 +589,9 @@ class MeshChaosRunner(FusedChaosRunner):
     schedule."""
 
     def __init__(self, schedule: ChaosSchedule, data_dir: str,
-                 cfg: Optional[RaftConfig] = None, steps: int = 1):
-        super().__init__(schedule, data_dir, cfg=cfg, steps=steps)
+                 cfg: Optional[RaftConfig] = None):
+        # One step a dispatch: the sharded step carries no more.
+        super().__init__(schedule, data_dir, cfg=cfg)
         from raftsql_tpu.runtime.mesh import MeshConfig
         self.mesh_config = MeshConfig.for_groups(self.cfg)
         if self.mesh_config.group_shards < 2:
@@ -2633,7 +2632,7 @@ class OverloadChaosRunner(FusedChaosRunner):
     def _make_node(self) -> FusedClusterNode:
         from raftsql_tpu.overload import OverloadController
         node = FusedClusterNode(self.cfg, self.data_dir,
-                                seed=self.sched.seed)
+                                seed=self.sched.seed, steps=self.steps)
         if not self.plan.unsafe_no_admission:
             node.overload = OverloadController(
                 self.cfg.num_groups,

@@ -24,6 +24,9 @@ tick:
       wal_append    leader appends + follower mirror appends
       wal_hardstate changed hard states (+ epoch END marks)
     fsync      the per-peer fsync barrier
+    epoch_commit  a multi-step dispatch's commit record: the write and
+               fsync of data_dir/EPOCHS after the barrier (absent
+               where a dispatch is one step)
     publish    commit delivery to the apply plane
     ring_drain the serving plane's propose-ring drain batches
 
@@ -46,8 +49,11 @@ last sweep put on disk, on a thread of its own, runtime/db.py; then
 `stages.compact.sweep`: the tick thread's sweep, one sample each a
 sweep).  No ring, no percentile, no per-request object.
 
-COUNTERS — plain cumulative integers (`intake.*`: what the host plane's
-queues held, offered to the device and got accepted, per tick summed;
+COUNTERS — plain cumulative integers (`dispatch.steps`: the consensus
+steps the launches carried, one tick a launch; `intake.*`: what the
+host plane's queues held, offered to the device and got accepted, per
+tick summed, and `intake.committed_in_dispatch`: of the accepted, the
+entries the publishing peer saw committed before that dispatch ended;
 `wal.*`: records, bytes, hard states, groups and fsyncs of the durable
 phase, the shard streams a sharded WAL flushed, the follower ranges
 handed to the mirror, those of them that took the Python mirror, and
@@ -64,8 +70,8 @@ both as they rotate and unlink), and the state-machine store's
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
-wal_plan, wal_append, wal_hardstate, fsync, publish, and compact where
-a sweep runs: `stages.compact.sweep` holds its time) as a
+wal_plan, wal_append, wal_hardstate, fsync, epoch_commit, publish, and
+compact where a sweep runs: `stages.compact.sweep` holds its time) as a
 `jax.profiler.TraceAnnotation` named `tick.<phase>` carrying `tick=<n>`
 (`annotation()` is the one flag test a tick makes; `span()` gives the
 shared no-op context when it says no), so a device trace names the
@@ -113,10 +119,10 @@ import numpy as np
 # Phases that partition the tick thread's wall time; ring_drain runs on
 # the serving plane's drain threads and is reported but excluded from
 # the tick-share denominators, as are the finer phases that lie INSIDE
-# dispatch (launch, readback) and wal_write (wal_*).
+# dispatch (launch, readback) and wal_write (wal_*), and epoch_commit.
 PROF_PHASES = ("pop", "dispatch", "launch", "readback", "mesh_put",
                "wal_write", "wal_plan", "wal_append", "wal_hardstate",
-               "fsync", "publish", "ring_drain")
+               "fsync", "epoch_commit", "publish", "ring_drain")
 _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 
 # The names every document carries from boot (a series that appears
@@ -126,8 +132,10 @@ ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
                  "publish.queue", "compact.sweep", "compact.checkpoint")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
-ENGINE_COUNTERS = ("intake.backlog", "intake.offered", "intake.accepted",
-                   "intake.groups", "wal.records", "wal.bytes",
+ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
+                   "intake.accepted", "intake.groups",
+                   "intake.committed_in_dispatch", "wal.records",
+                   "wal.bytes",
                    "wal.hardstates", "wal.groups_written", "wal.fsyncs",
                    "wal.shard_syncs", "wal.mirror_rows",
                    "wal.mirror_fallback_rows", "wal.mirror_skipped_rows",

@@ -721,7 +721,7 @@ class RaftDB:
         if deadline_ms is not None \
                 and getattr(node, "overload", None) is not None:
             dstep = deadline_steps(node._device_steps, deadline_ms,
-                                   node.cfg.tick_interval_s)
+                                   node.step_interval_s)
         with self._mu:
             if self._failed is not None:
                 fut.set(self._failed)

@@ -54,15 +54,26 @@ from raftsql_tpu.runtime.hostplane import (_C,  # noqa: F401
                                            ClusterHostPlane)
 
 __all__ = ["FusedClusterNode", "FusedPipe", "ClusterHostPlane",
-           "_C", "_read_committed_epoch",
+           "PIPELINE_STEPS", "_C", "_read_committed_epoch",
            "MeshClusterNode"]
+
+# The protocol's own depth, in consensus steps, and what the deployment
+# that serves passes as `steps` (server/main.py): a proposal popped at
+# the head of a dispatch is (1) accepted by its group's leader, (2)
+# appended and acknowledged by the followers, (3) committed by the
+# leader, (4) learnt by the peer whose stream is applied (FusedPipe:
+# peer 0, which leads about a third of the groups).  The least S at
+# which such a proposal shows as committed in the column the publish
+# reads, `pinfo[-1][0][:, commit]`, whichever peer leads: at 3 only the
+# groups peer 0 leads get there, and the rest wait a launch more.
+PIPELINE_STEPS = 4
 
 
 class FusedClusterNode(ClusterHostPlane):
     """The single-device durable runtime: ClusterHostPlane with the
     fused cluster step (core/cluster.py) as its device program —
-    including the multi-step dispatch (RAFTSQL_FUSED_STEPS) and the
-    device busy bit that drives idle parking."""
+    including the multi-step dispatch (`steps`, PIPELINE_STEPS where
+    it serves) and the device busy bit that drives idle parking."""
 
     # Steady-state [P] i32 lockstep advance, built once: the None and
     # the skew branch must ship the SAME dtype/shape to the jitted step

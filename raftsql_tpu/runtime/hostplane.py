@@ -141,7 +141,8 @@ class ClusterHostPlane:
 
     def __init__(self, cfg: RaftConfig, data_dir: str,
                  seed: Optional[int] = None,
-                 group_commit: Optional[bool] = None):
+                 group_commit: Optional[bool] = None,
+                 steps: int = 1):
         P, G = cfg.num_peers, cfg.num_groups
         self.cfg = cfg
         self.metrics = NodeMetrics()
@@ -189,9 +190,10 @@ class ClusterHostPlane:
         self._wal_hard: List[Optional[np.ndarray]] = [None] * P
         self._wal_groups: set = set()
         self._wal_wrote: Optional[Tuple[int, int]] = None   # last seen
-        # This tick's [backlog, offered, groups, accepted] (intake.*),
-        # and whether a profiler session ran at the tick's start.
-        self._intake = [0, 0, 0, 0]
+        # This tick's [backlog, offered, groups, accepted, committed
+        # in the dispatch] (intake.*), and whether a profiler session
+        # ran at the tick's start.
+        self._intake = [0, 0, 0, 0, 0]
         self._ann = None
         self.dirs = [os.path.join(data_dir, f"p{i + 1}") for i in range(P)]
         # WAL group commit: multiplex all P peers' records into ONE
@@ -341,17 +343,19 @@ class ClusterHostPlane:
         # tick of ack latency.  Saturated ticks keep the deferral.
         self._inline_publish_max = int(os.environ.get(
             "RAFTSQL_PUBLISH_INLINE_MAX", "4096"))
-        # Steps per dispatch (RAFTSQL_FUSED_STEPS, default 1): run S
-        # consensus steps inside one device program and replay the
-        # durable phases per step on return (core/cluster.py
-        # cluster_multistep_host).  Amortizes the fixed dispatch +
-        # readback cost over S steps and lets a proposal commit within
-        # ONE dispatch (the 3-step pipeline completes before the
-        # durable barrier).  Election /
+        # Steps per dispatch: run S consensus steps inside one device
+        # program and replay the durable phases per step on return
+        # (core/cluster.py cluster_multistep_host).  The fixed cost of
+        # a dispatch (launch, readback, hard states, the barrier) is
+        # paid once for S steps, and at S = PIPELINE_STEPS a proposal
+        # commits within the dispatch that accepted it.  The bare
+        # default is one step a dispatch; the deployment that serves
+        # (server/main.py) passes the pipeline's depth.  Election /
         # heartbeat timers advance once per STEP, so election_ticks
         # continue to mean steps, not dispatches.
-        self._steps = max(1, int(os.environ.get(
-            "RAFTSQL_FUSED_STEPS", "1")))
+        if steps < 1:
+            raise ValueError(f"steps per dispatch must be >= 1: {steps}")
+        self._steps = steps
         # Publish workers (parallel hosts): delivering a tick's
         # (already durable) commits to the apply plane costs ~40% of a
         # saturated tick's wall time; ordered workers take it off the
@@ -1032,6 +1036,16 @@ class ClusterHostPlane:
     # hot-spins device steps faster than the wall interval.
     _LEASE_HORIZON_S = 0.05
 
+    @property
+    def step_interval_s(self) -> float:
+        """The wall time one consensus step is taken to last wherever a
+        count of steps becomes a time or a time a count of steps (the
+        published lease deadline below, the overload plane's
+        deadline_steps): the loop paces DISPATCHES at tick_interval_s
+        and a dispatch carries `_steps` steps at once.  An untimed
+        engine (tick_interval_s == 0) converts at 0.1 ms a step."""
+        return max(self.cfg.tick_interval_s / self._steps, 1e-4)
+
     def lease_deadline_s(self, group: int) -> float:
         """The time.monotonic() instant until which a lease read for
         `group` stays provably safe, 0.0 when no live lease — the
@@ -1052,8 +1066,7 @@ class ClusterHostPlane:
         remaining = until - (self._device_steps + cfg.max_clock_skew)
         if until <= 0 or remaining <= 0:
             return 0.0
-        interval = max(cfg.tick_interval_s, 1e-4)
-        return time.monotonic() + min(remaining * interval,
+        return time.monotonic() + min(remaining * self.step_interval_s,
                                       self._LEASE_HORIZON_S)
 
     # -- the tick -------------------------------------------------------
@@ -1068,8 +1081,11 @@ class ClusterHostPlane:
         from one backlog snapshot — so pops never outrun the queue and
         payloads stay aligned with the device's assigned indexes."""
         P, G = self.cfg.num_peers, self.cfg.num_groups
-        cap = self._E * steps
-        prop_n = np.zeros((P, G), np.int32)
+        E = self._E
+        cap = E * steps
+        # Filled entry by entry, a dozen of 30,000 a step: nothing of
+        # [P, G] size is computed to cut the offers into steps.
+        prop_n = np.zeros((steps, P, G), np.int32)
         dead = []
         ov = self.overload
         now_step = self._device_steps
@@ -1110,17 +1126,16 @@ class ClusterHostPlane:
                             dead.append((p, g))
                             continue
                 n = len(q)
-                prop_n[p, g] = min(n, cap)
+                offer = min(n, cap)
+                for s in range(-(-offer // E)):
+                    prop_n[s, p, g] = min(offer - s * E, E)
                 backlog += n
-                offered += min(n, cap)
+                offered += offer
                 groups += 1
             for k in dead:
                 self._queued.discard(k)
-        self._intake = [backlog, offered, groups, 0]
-        if steps <= 1:
-            return prop_n
-        return np.stack([np.clip(prop_n - s * self._E, 0, self._E)
-                         for s in range(steps)]).astype(np.int32)
+        self._intake = [backlog, offered, groups, 0, 0]
+        return prop_n if steps > 1 else prop_n[0]
 
     def _pub_run(self, q: "queue.Queue", shard: int) -> None:
         """Ordered publish worker (see __init__): per worker one queue,
@@ -1384,15 +1399,13 @@ class ClusterHostPlane:
                 dev_busy = True
         t3 = _t.monotonic()
 
-        # Multi-step dispatch (RAFTSQL_FUSED_STEPS > 1): packed info
-        # arrives stacked [S, P, G, C]; the host replays its durable
+        # The dispatch's packed info as ONE block [S, P, G, C], a
+        # single-step dispatch's as S = 1; the host replays its durable
         # phases in step order — every step's entries land before the
         # ONE hard-state save + fsync barrier of the dispatch, which
         # preserves the etcd wal.Save order (entries-then-hardstate)
         # at dispatch granularity.
-        step_infos = ([np.asarray(pinfo[s])
-                       for s in range(pinfo.shape[0])]
-                      if pinfo.ndim == 4 else [pinfo])
+        step_infos = pinfo if pinfo.ndim == 4 else pinfo[None]
         pinfo = step_infos[-1]
         self._hints = pinfo[0, :, _C["leader_hint"]]
         self._lease_col = pinfo[:, :, _C["lease"]]
@@ -1407,14 +1420,17 @@ class ClusterHostPlane:
         # bit-identical to the serialized one.
         ts0 = _t.monotonic()
         with span(ann, "tick.pop", tick_no):
-            staged = [self._stage_ranges(pi) for pi in step_infos]
+            staged = self._stage_ranges(step_infos)
         if prof is not None:
             # `dispatch` is launch + readback (the host blocks on the
             # device completing this tick's program): a sample each,
             # and each half under its own name too; on a mesh the
             # inputs' way to their shards is a third part, `mesh_put`.
+            # dispatch.steps: the consensus steps this launch carried.
             # intake.*: what _build_prop_n found queued and offered,
-            # what _stage_ranges popped as accepted.
+            # what _stage_ranges popped as accepted, and how much of
+            # that the publishing peer saw committed before the
+            # dispatch ended.
             ld, rd = t1 - tl, t3 - t2b
             it = self._intake
             samples = [("pop", t0, tb - t0), ("dispatch", tl, ld),
@@ -1424,11 +1440,14 @@ class ClusterHostPlane:
             if put is not None:
                 samples += (("dispatch", tb, tl - tb),
                             ("mesh_put", tb, tl - tb))
-            prof.record_tick(
-                tick_no, samples,
-                (("intake.backlog", it[0]), ("intake.offered", it[1]),
-                 ("intake.groups", it[2]), ("intake.accepted", it[3]))
-                if it[2] else ())
+            counts = (("dispatch.steps", len(step_infos)),)
+            if it[2]:
+                counts += (("intake.backlog", it[0]),
+                           ("intake.offered", it[1]),
+                           ("intake.groups", it[2]),
+                           ("intake.accepted", it[3]),
+                           ("intake.committed_in_dispatch", it[4]))
+            prof.record_tick(tick_no, samples, counts)
         if self.overload is not None:
             # Overload plane tick feed: drain-rate EWMA (Retry-After)
             # + queue-depth EWMA (the brownout governor's hysteresis).
@@ -1439,10 +1458,8 @@ class ClusterHostPlane:
         tick_active = any(
             bool(st_p[0]) for st in staged for st_p in st)
         if not tick_active:
-            for pi in step_infos:
-                if (pi[:, :, _C["app_from"]] >= 0).any():
-                    tick_active = True
-                    break
+            tick_active = bool(
+                (step_infos[..., _C["app_from"]] >= 0).any())
         if not tick_active:
             tick_active = bool(self._hard_changed(pinfo)[0].size)
         # Quiescence signal for the threaded loop: anything written,
@@ -1553,7 +1570,8 @@ class ClusterHostPlane:
             self._retire_stash()
 
     def _finish_durable(self, step_infos, staged) -> bool:
-        """The whole durable back half for one dispatch: per-step
+        """The whole durable back half for one dispatch, from its
+        packed info [S, P, G, C] and its S staged write plans: the
         durable phases (epoch-framed when multi-step), the epoch
         commit, and membership apply-at-commit.  Returns tick_active
         (anything written).  Attributed to `self._prof_tick` (set by
@@ -1577,16 +1595,18 @@ class ClusterHostPlane:
         if self._ep_active:
             self._ep_begun = [False] * self.cfg.num_peers
             self._ep_no_this = None
-        tick_active = False
-        for si, (pi, st) in enumerate(zip(step_infos, staged)):
-            tick_active = self._durable_phases(
-                pi, final=(si == len(step_infos) - 1),
-                staged=st) or tick_active
+        tick_active = self._durable_phases(step_infos, staged)
+        ep_span = None
         if self._ep_active and self._ep_no_this is not None:
             # Every peer's barrier is down; this fsync is the
             # dispatch's atomic commit point (before any publish).
+            # Clocked where it runs, apart from the WAL barrier: a
+            # leaf span and a phase of its own (epoch_commit).
             self._epoch_no = self._ep_no_this
-            self._commit_epoch(self._epoch_no)
+            te0 = _t.monotonic()
+            with span(self._ann, "tick.epoch_commit", ptick):
+                self._commit_epoch(self._epoch_no)
+            ep_span = (te0, _t.monotonic() - te0)
         self._ep_active = False
         if self.membership is not None:
             # Apply-at-commit for conf entries: patch each peer row
@@ -1599,17 +1619,22 @@ class ClusterHostPlane:
             samples: list = []
             if tick_active:
                 # wal_write = the durable back half minus the fsync
-                # barrier (clocked where it ran: _durable_phases fills
-                # _fsync_span); its parts are _durable_phases'.
+                # barrier and the epoch commit (each clocked where it
+                # ran: _durable_phases fills _fsync_span); its parts
+                # are _durable_phases'.
                 t_tot = _t.monotonic() - td0
                 fs = self._fsync_span
                 fdur = fs[1] if fs is not None else 0.0
-                samples = [("wal_write", td0, max(t_tot - fdur, 0.0)),
+                edur = ep_span[1] if ep_span is not None else 0.0
+                samples = [("wal_write", td0,
+                            max(t_tot - fdur - edur, 0.0)),
                            ("wal_plan", td0, split[0]),
                            ("wal_append", td0, split[1]),
                            ("wal_hardstate", td0, split[2])]
                 if fs is not None:
                     samples.append(("fsync", fs[0], fdur))
+                if ep_span is not None:
+                    samples.append(("epoch_commit",) + ep_span)
             prof.record_tick(ptick, samples, self._wal_counts())
         return tick_active
 
@@ -1658,128 +1683,159 @@ class ClusterHostPlane:
                 ("wal.mirror_fallback_rows", fell_back),
                 ("wal.mirror_skipped_rows", skipped))
 
-    def _stage_ranges(self, pinfo: np.ndarray) -> list:
-        """Build one step's phase-2a write plan — per peer the
-        (r_g, r_start, r_count, r_term, w_d) uniform-term ranges of
+    def _stage_ranges(self, infos: np.ndarray) -> list:
+        """Build a dispatch's phase-2a write plans — per step, per peer
+        the (r_g, r_start, r_count, r_term, w_d) uniform-term ranges of
         fresh-leader no-ops + accepted proposals — POPPING the accepted
-        payloads off the proposal queues.  Runs at stage time, in the
-        tick that read this pinfo: the pops must settle before the next
-        tick's _build_prop_n snapshot (offer counts and re-routes read
-        queue lengths), whether the heavy durable write runs inline or
+        payloads off the proposal queues.  `infos` is the dispatch's
+        packed info [S, P, G, C].  Runs at stage time, in the tick that
+        read it: the pops must settle before the next tick's
+        _build_prop_n snapshot (offer counts and re-routes read queue
+        lengths), whether the heavy durable write runs inline or
         stashed into the next dispatch window.  Side effects that ride
         the pop (conf-entry notes, tracer append stamps, the proposals
-        counter) happen here too, in step order."""
-        P = self.cfg.num_peers
-        out = []
-        for p in range(P):
-            col = pinfo[p]
-            noop = col[:, _C["noop"]]
-            acc = col[:, _C["prop_accepted"]]
-            base = col[:, _C["prop_base"]]
-            term = col[:, _C["term"]]
-            r_g: List[int] = []
-            r_start: List[int] = []
-            r_count: List[int] = []
-            r_term: List[int] = []
-            w_d: List[bytes] = []
-            ngs = np.nonzero(noop)[0]
-            if ngs.size:
+        counter) happen here too, in step order.
+
+        The (step, peer, group) with anything to stage are found in ONE
+        pass over the block, not one a step a peer: a dozen rows of
+        30,000 a step, and every [G]-sized numpy call costs the tick
+        thread of a served engine a turn at the interpreter (PERF.md,
+        PR 31), which S steps a dispatch would pay S times over."""
+        S, P = infos.shape[:2]
+        out = [[([], [], [], [], []) for _ in range(P)]
+               for _ in range(S)]
+        at = np.flatnonzero(infos[..., _C["noop"]]
+                            | infos[..., _C["prop_accepted"]])
+        if not at.size:
+            return out
+        ss, ps, gs = np.unravel_index(at, infos.shape[:3])
+        rows = infos[ss, ps, gs]                    # [n, C], few
+        bases = rows[:, _C["prop_base"]]
+        accs = rows[:, _C["prop_accepted"]]
+        took = accs > 0
+        # Of what this dispatch accepts, the entries the publishing
+        # peer's final commit index already covers: those commit inside
+        # the dispatch that accepted them (intake.committed_in_dispatch).
+        done = np.clip(infos[-1, 0, gs, _C["commit"]] - bases, 0, accs)
+        self._intake[4] += int(done[took].sum())
+        noops = rows[:, _C["noop"]] != 0
+        terms = rows[:, _C["term"]]
+        traced = [] if self.tracer is not None else None
+        confs = [] if self.membership is not None else None
+        ov = self.overload
+        strip = self._deadlines_live
+        # One step's rows of one peer at a time, as they come (groups
+        # ascending).
+        cuts = (np.flatnonzero(np.diff(ss * P + ps)) + 1).tolist()
+        for lo, hi in zip([0] + cuts, cuts + [at.size]):
+            p = int(ps[lo])
+            r_g, r_start, r_count, r_term, w_d = out[int(ss[lo])][p]
+            nz = noops[lo:hi]
+            if nz.any():
                 # One empty record at prop_base per fresh leader
                 # (ordered before any accepted proposals of the same
                 # group — base < base+1, both pure tail appends).
-                r_g.extend(ngs.tolist())
-                r_start.extend(base[ngs].tolist())
-                r_count.extend([1] * ngs.size)
-                r_term.extend(term[ngs].tolist())
-                w_d.extend([b""] * ngs.size)
-            ags = np.nonzero(acc > 0)[0]
-            if ags.size:
-                props_p = self._props[p]
-                traced = [] if self.tracer is not None else None
-                confs = [] if self.membership is not None else None
-                ov = self.overload
-                strip = self._deadlines_live
-                with self._prop_lock:   # pops race client-thread extends
-                    for g, n, b0, tm in zip(ags.tolist(),
-                                            acc[ags].tolist(),
-                                            (base[ags] + 1).tolist(),
-                                            term[ags].tolist()):
-                        q = props_p[g]
-                        batch = q[:n]
-                        del q[:n]
-                        if strip:
-                            # Deadline-carrying entries are (payload,
-                            # deadline_step) pairs — strip to plain
-                            # bytes before WAL/trace/conf consumers.
-                            batch = [e[0] if type(e) is tuple else e
-                                     for e in batch]
-                        if ov is not None:
-                            ov.drained(g, n)
-                        w_d.extend(batch)
-                        r_g.append(g)
-                        r_start.append(b0)
-                        r_count.append(n)
-                        r_term.append(tm)
-                        if traced is not None:
-                            traced.append((g, b0, batch))
-                        if confs is not None:
-                            # Conf entries entering the cluster log —
-                            # one leading-byte test per accepted
-                            # proposal, only with membership enabled.
-                            for off, d in enumerate(batch):
-                                if d[:1] == _CONF_PREFIX \
-                                        and is_conf_entry(d):
-                                    confs.append((g, b0 + off, d))
-                if confs:
-                    for (cg, cidx, cd) in confs:
-                        self._conf_note(cg, cidx, cd)
-                n_acc = int(acc[ags].sum())
-                self.metrics.proposals += n_acc
-                self._intake[3] += n_acc
-                # Per-group traffic: the accepted counts are already in
-                # hand per group — one vectorized add, no new walks.
-                self.traffic.add_propose(ags, acc[ags])
-                if traced:
-                    # Append stamp + index binding, outside the lock.
-                    for g, b0, batch in traced:
-                        self.tracer.note_append(
-                            g, b0, [d.decode("utf-8", "replace")
-                                    for d in batch])
-            out.append((r_g, r_start, r_count, r_term, w_d))
+                ngs = gs[lo:hi][nz].tolist()
+                r_g.extend(ngs)
+                r_start.extend(bases[lo:hi][nz].tolist())
+                r_count.extend([1] * len(ngs))
+                r_term.extend(terms[lo:hi][nz].tolist())
+                w_d.extend([b""] * len(ngs))
+            tk = took[lo:hi]
+            if not tk.any():
+                continue
+            props_p = self._props[p]
+            with self._prop_lock:   # pops race client-thread extends
+                for g, n, b0, tm in zip(gs[lo:hi][tk].tolist(),
+                                        accs[lo:hi][tk].tolist(),
+                                        (bases[lo:hi][tk] + 1).tolist(),
+                                        terms[lo:hi][tk].tolist()):
+                    q = props_p[g]
+                    batch = q[:n]
+                    del q[:n]
+                    if strip:
+                        # Deadline-carrying entries are (payload,
+                        # deadline_step) pairs — strip to plain bytes
+                        # before WAL/trace/conf consumers.
+                        batch = [e[0] if type(e) is tuple else e
+                                 for e in batch]
+                    if ov is not None:
+                        ov.drained(g, n)
+                    w_d.extend(batch)
+                    r_g.append(g)
+                    r_start.append(b0)
+                    r_count.append(n)
+                    r_term.append(tm)
+                    if traced is not None:
+                        traced.append((g, b0, batch))
+                    if confs is not None:
+                        # Conf entries entering the cluster log — one
+                        # leading-byte test per accepted proposal, only
+                        # with membership enabled.
+                        for off, d in enumerate(batch):
+                            if d[:1] == _CONF_PREFIX \
+                                    and is_conf_entry(d):
+                                confs.append((g, b0 + off, d))
+        if confs:
+            for (cg, cidx, cd) in confs:
+                self._conf_note(cg, cidx, cd)
+        if took.any():
+            n_acc = int(accs[took].sum())
+            self.metrics.proposals += n_acc
+            self._intake[3] += n_acc
+            # Per-group traffic: the accepted counts are already in
+            # hand per group — one add, no new walks.
+            self.traffic.add_propose(gs[took], accs[took])
+        if traced:
+            # Append stamp + index binding, outside the lock.
+            for g, b0, batch in traced:
+                self.tracer.note_append(
+                    g, b0, [d.decode("utf-8", "replace")
+                            for d in batch])
         return out
 
-    def _mirror_keep(self, pinfo: np.ndarray
-                     ) -> Tuple[int, Tuple[np.ndarray, np.ndarray]]:
-        """One step's accepted appends: how many `(peer, group)` took
-        one, and the (peers, groups) index arrays of those that go to
-        the mirror, peer-major, groups ascending: the ones that carry
-        an entry.  An EMPTY accepted append (a heartbeat's ack: every
-        follower of a steady group, every tick) can change no log.  On
-        the device (core/step.py Phase 4) `a_n == 0` gives no overlap,
-        so no conflict, and `log_len = max(log_len, prev)` with
-        `prev <= log_len` by `prev_ok`: its `new_log_len` is the length
-        the log had, which is the payload log's (the mirror of the
-        device's log).  On the host such a row writes no record, puts
-        nothing and truncates to the length that is there.
-        tests/test_mirror_rows.py holds that, row by dropped row.
-        `app_n` is 0 wherever no append was accepted.  Nothing more
-        of [P, G] size than this: what a numpy call on a column costs
-        the tick thread of a served engine is in PERF.md (PR 29)."""
-        n_took = int(np.count_nonzero(pinfo[:, :, _C["app_from"]] >= 0))
-        return n_took, np.nonzero(pinfo[:, :, _C["app_n"]])
+    def _mirror_keep(self, infos: np.ndarray
+                     ) -> Tuple[np.ndarray, Tuple[np.ndarray, ...]]:
+        """A dispatch's accepted appends, from its packed info
+        [S, P, G, C]: how many `(peer, group)` took one at each step
+        ([S]), and the (steps, peers, groups) index arrays of those
+        that go to the mirror, step-major, then peer-major, groups
+        ascending: the ones that carry an entry.  An EMPTY accepted
+        append (a heartbeat's ack: every follower of a steady group,
+        every step) can change no log.  On the device (core/step.py
+        Phase 4) `a_n == 0` gives no overlap, so no conflict, and
+        `log_len = max(log_len, prev)` with `prev <= log_len` by
+        `prev_ok`: its `new_log_len` is the length the log had, which
+        is the payload log's (the mirror of the device's log).  On the
+        host such a row writes no record, puts nothing and truncates
+        to the length that is there.  tests/test_mirror_rows.py holds
+        that, row by dropped row, step by step.  `app_n` is 0 wherever
+        no append was accepted.  Nothing more of [P, G] size than
+        this, and ONE pass over the block for all its steps: what a
+        numpy call on a column costs the tick thread of a served
+        engine is in PERF.md (PR 29), and a dispatch of S steps would
+        pay a pass a step S times (found flat: a 2-D `np.nonzero` over
+        [P, G] costs five times a flat one)."""
+        shape = infos.shape[:3]
+        n_took = np.count_nonzero(
+            (infos[..., _C["app_from"]] >= 0).reshape(shape[0], -1),
+            axis=1)
+        at = np.flatnonzero(infos[..., _C["app_n"]])
+        return n_took, np.unravel_index(at, shape)
 
-    def _durable_phases(self, pinfo: np.ndarray, final: bool,
-                        staged: list) -> bool:
-        """The durable host phases for ONE step's packed info [P,G,C],
-        one straight run.  Phase 1 (plan) collects mirror METADATA
-        (peer, src, group, start, count, new_len) with no reads, of the
-        accepted appends that can change a log (_mirror_keep).  Phase
-        2a writes leader appends (fresh-leader no-ops + accepted
-        proposals, pre-popped into `staged` by _stage_ranges) as
-        uniform-term RANGES into each leader's WAL and payload log.
-        Phase 2b mirrors follower appends in two passes: ALL source
-        reads, then one batched payload-log write and one WAL append
-        per destination peer.
+    def _durable_phases(self, step_infos, staged: list) -> bool:
+        """The durable host phases of ONE dispatch, from its packed
+        info [S, P, G, C] (or a sequence of S [P, G, C]) and the S
+        write plans `_stage_ranges` made, one straight run.  Phase 1
+        (plan), once for all the steps, collects mirror METADATA
+        (step, peer, src, group, start, count, new_len) with no reads,
+        of the accepted appends that can change a log (_mirror_keep).
+        Then step by step: phase 2a writes leader appends
+        (fresh-leader no-ops + accepted proposals, pre-popped into
+        `staged` by _stage_ranges) as uniform-term RANGES into each
+        leader's WAL and payload log, and phase 2b mirrors follower
+        appends in two passes: ALL source reads, then one batched
+        payload-log write and one WAL append per destination peer.
 
         The source reads happen inside 2b AFTER 2a's appends — safe
         because 2a writes are pure TAIL appends strictly above any
@@ -1792,12 +1848,13 @@ class ClusterHostPlane:
         Any future 2a change that is not a pure tail append breaks this
         argument and must move 2a after 2b's reads.
 
-        On the dispatch's FINAL step only, phase 2c (hard states: one
-        compare for all peers against what the WALs hold, then one
-        batched record write a peer that has a changed row) and the
-        per-peer fsync barrier run — a multi-step dispatch saves every
-        step's entries, then one hard state, then one fsync, which is
-        the etcd wal.Save order at dispatch granularity.
+        After the last step's entries, phase 2c (hard states: one
+        compare for all peers of the FINAL step's info against what
+        the WALs hold, then one batched record write a peer that has a
+        changed row) and the per-peer fsync barrier run — a multi-step
+        dispatch saves every step's entries, then one hard state, then
+        one fsync, which is the etcd wal.Save order at dispatch
+        granularity.
         Returns tick_active (entries or hard states written)."""
         P = self.cfg.num_peers
         import time as _t
@@ -1813,148 +1870,163 @@ class ClusterHostPlane:
         with span(ann, "tick.wal_plan", ptick):
             # Only the accepted appends that can change a log become
             # rows, picked out BEFORE anything is listed.
-            n_took, kept = self._mirror_keep(pinfo)
-            m_peer, m_g = kept[0].tolist(), kept[1].tolist()
-            sub = pinfo[kept]                           # [rows, C]
-            m_src: List[int] = sub[:, _C["app_from"]].tolist()
-            m_start: List[int] = sub[:, _C["app_start"]].tolist()
-            m_count: List[int] = sub[:, _C["app_n"]].tolist()
-            m_newlen: List[int] = sub[:, _C["new_log_len"]].tolist()
-            if counting and n_took:
+            infos = np.asarray(step_infos)
+            n_took, (k_step, k_peer, k_g) = self._mirror_keep(infos)
+            a_peer, a_g = k_peer.tolist(), k_g.tolist()
+            sub = infos[k_step, k_peer, k_g]            # [rows, C]
+            a_src: List[int] = sub[:, _C["app_from"]].tolist()
+            a_start: List[int] = sub[:, _C["app_start"]].tolist()
+            a_count: List[int] = sub[:, _C["app_n"]].tolist()
+            a_newlen: List[int] = sub[:, _C["new_log_len"]].tolist()
+            # The rows of step s: [step_at[s], step_at[s + 1]).
+            step_at = np.searchsorted(
+                k_step, np.arange(len(infos) + 1)).tolist()
+            took = int(n_took.sum())
+            if counting and took:
                 # wal.records / wal.groups_written: mirrored entries
                 # (an empty heartbeat ack mirrors none); the ranges the
                 # mirror is handed, and the appends that made none.
-                self._wal_mirror[0] += len(m_peer)
-                self._wal_mirror[2] += n_took - len(m_peer)
-                self._wal_records += sum(m_count)
+                self._wal_mirror[0] += len(a_peer)
+                self._wal_mirror[2] += took - len(a_peer)
+                self._wal_records += sum(a_count)
                 self._wal_groups.update(
-                    [g for g, c in zip(m_g, m_count) if c])
+                    [g for g, c in zip(a_g, a_count) if c])
 
-            if self.tracer is not None and m_peer:
-                # Replicate stamp: the mirrored range is landing in a
-                # follower's log this step (first stamp wins per index).
-                for g, st, c in zip(m_g, m_start, m_count):
+            if self.tracer is not None and a_peer:
+                # Replicate stamp: the mirrored range lands in a
+                # follower's log this dispatch (first stamp wins per
+                # index).
+                for g, st, c in zip(a_g, a_start, a_count):
                     if c:
                         self.tracer.note_replicate(g, st + c - 1)
 
-            if self.witness_peers and m_peer:
+            if self.witness_peers and a_peer:
                 # Witnesses never lead, so every entry they persist
                 # arrives here as a mirrored follower append.
                 self.metrics.witness_appends += sum(
-                    c for p, c in zip(m_peer, m_count)
+                    c for p, c in zip(a_peer, a_count)
                     if c and p in self.witness_peers)
 
         # Any accepted append, empty or not, keeps the tick active.
-        tick_active = bool(n_took)
+        tick_active = bool(took)
         tb = _t.monotonic()
         split[0] += tb - ta
         with span(ann, "tick.wal_append", ptick):
-            # Phase 2a: leader appends as uniform-term RANGE records —
-            # one framed record per (group, start, term) run, not one
-            # per entry.  The write plan was staged (and the payloads
-            # popped) by _stage_ranges.
-            for p in range(P):
-                r_g, r_start, r_count, r_term, w_d = staged[p]
-                if not r_g:
-                    continue
-                tick_active = True
-                if counting:
-                    self._wal_records += sum(r_count)
-                    self._wal_groups.update(r_g)
-                self._ensure_epoch_begin(p)
-                self.wals[p].append_ranges(r_g, r_start, r_count,
-                                           r_term, w_d)
-                puts = []
-                pos = 0
-                for g, s, c, tm in zip(r_g, r_start, r_count, r_term):
-                    puts.append((g, s, w_d[pos: pos + c], [tm] * c,
-                                 None))
-                    pos += c
-                self.plogs[p].put_ranges(puts)
-            # Phase 2b: the mirror.  ALL source reads first (the
-            # staging contract), then one batched write per peer.
-            if m_peer:
-                if counting:
-                    self._wal_mirror[1] += len(m_peer)
-                for p in sorted(set(m_peer)):
-                    self._ensure_epoch_begin(p)
-                floor_of = [pl.starts for pl in self.plogs]
-                if any(c and st <= floor_of[s][g]
-                       for (s, g, st, c) in zip(m_src, m_g, m_start,
-                                                m_count)):
-                    self._trim_mirror(m_peer, m_src, m_g, m_start,
-                                      m_count)
-                reads = [self.plogs[s].slice_columns(g, st, c)
-                         if c else ([], [])
-                         for (s, g, st, c) in zip(m_src, m_g, m_start,
-                                                  m_count)]
+            for si, st_plan in enumerate(staged):
+                # Phase 2a: leader appends as uniform-term RANGE
+                # records — one framed record per (group, start, term)
+                # run, not one per entry.  The write plan was staged
+                # (and the payloads popped) by _stage_ranges.
                 for p in range(P):
-                    b_g: List[int] = []
-                    b_start: List[int] = []
-                    b_count: List[int] = []
-                    b_terms: List[int] = []
-                    b_d: List[bytes] = []
+                    r_g, r_start, r_count, r_term, w_d = st_plan[p]
+                    if not r_g:
+                        continue
+                    tick_active = True
+                    if counting:
+                        self._wal_records += sum(r_count)
+                        self._wal_groups.update(r_g)
+                    self._ensure_epoch_begin(p)
+                    self.wals[p].append_ranges(r_g, r_start, r_count,
+                                               r_term, w_d)
                     puts = []
-                    for (mp, g, st, c, nl), (terms, datas) in zip(
-                            zip(m_peer, m_g, m_start, m_count,
-                                m_newlen), reads):
-                        if mp != p:
-                            continue
-                        puts.append((g, st, datas, terms, nl))
-                        if c:
-                            b_g.append(g)
-                            b_start.append(st)
-                            b_count.append(c)
-                            b_terms.extend(terms)
-                            b_d.extend(datas)
-                    if puts:
-                        self.plogs[p].put_ranges(puts)
-                    if b_g:
-                        # Mirrored batches may cross term boundaries;
-                        # RANGE records are uniform-term, so split each
-                        # mirror at its term changes (rare: elections).
-                        s_g: List[int] = []
-                        s_start: List[int] = []
-                        s_count: List[int] = []
-                        s_term: List[int] = []
-                        pos = 0
-                        for g, st0, c in zip(b_g, b_start, b_count):
-                            for (rs, rc, rt) in split_uniform_runs(
-                                    st0, b_terms[pos: pos + c]):
-                                s_g.append(g)
-                                s_start.append(rs)
-                                s_count.append(rc)
-                                s_term.append(rt)
-                            pos += c
-                        self.wals[p].append_ranges(s_g, s_start, s_count,
-                                                   s_term, b_d)
+                    pos = 0
+                    for g, s, c, tm in zip(r_g, r_start, r_count,
+                                           r_term):
+                        puts.append((g, s, w_d[pos: pos + c], [tm] * c,
+                                     None))
+                        pos += c
+                    self.plogs[p].put_ranges(puts)
+                # Phase 2b: the step's mirror.  ALL source reads first
+                # (the staging contract), then one batched write per
+                # peer.
+                lo, hi = step_at[si], step_at[si + 1]
+                if hi > lo:
+                    self._mirror(a_peer[lo:hi], a_src[lo:hi],
+                                 a_g[lo:hi], a_start[lo:hi],
+                                 a_count[lo:hi], a_newlen[lo:hi])
         tc = _t.monotonic()
         split[1] += tc - tb
-        if final:
-            # Phase 2c: hard states after every ENTRY record of the
-            # dispatch (etcd wal.Save order: a torn tail can then never
-            # leave a hard state referencing lost entries), found by
-            # one compare for all peers, then the per-peer fsync that
-            # is the durable barrier before the next dispatch.
-            with span(ann, "tick.wal_hardstate", ptick):
-                tick_active = self._save_hard(pinfo) or tick_active
-                if self._ep_active:
-                    for p in range(P):
-                        if self._ep_begun[p]:
-                            self.wals[p].epoch_mark(self._ep_no_this,
-                                                    end=True)
-            # The durable barrier: every peer fsynced before this
-            # tick's messages can be observed (the next dispatch).  The
-            # P fsyncs are independent files — run them concurrently
-            # (os.fsync and the native wal_sync both release the GIL),
-            # so the barrier costs one fsync wall-time, not P.  A peer
-            # with nothing pending returns immediately.
-            tf0 = _t.monotonic()
-            split[2] += tf0 - tc
-            with span(ann, "tick.fsync", ptick):
-                list(self._sync_pool.map(lambda w: w.sync(), self.wals))
-            self._fsync_span = (tf0, _t.monotonic() - tf0)
+        # Phase 2c: hard states after every ENTRY record of the
+        # dispatch (etcd wal.Save order: a torn tail can then never
+        # leave a hard state referencing lost entries), found by one
+        # compare for all peers, then the per-peer fsync that is the
+        # durable barrier before the next dispatch.
+        with span(ann, "tick.wal_hardstate", ptick):
+            tick_active = self._save_hard(step_infos[-1]) or tick_active
+            if self._ep_active:
+                for p in range(P):
+                    if self._ep_begun[p]:
+                        self.wals[p].epoch_mark(self._ep_no_this,
+                                                end=True)
+        # The durable barrier: every peer fsynced before this tick's
+        # messages can be observed (the next dispatch).  The P fsyncs
+        # are independent files — run them concurrently (os.fsync and
+        # the native wal_sync both release the GIL), so the barrier
+        # costs one fsync wall-time, not P.  A peer with nothing
+        # pending returns immediately.
+        tf0 = _t.monotonic()
+        split[2] += tf0 - tc
+        with span(ann, "tick.fsync", ptick):
+            list(self._sync_pool.map(lambda w: w.sync(), self.wals))
+        self._fsync_span = (tf0, _t.monotonic() - tf0)
         return tick_active
+
+    def _mirror(self, m_peer, m_src, m_g, m_start, m_count,
+                m_newlen) -> None:
+        """Phase 2b for one step's mirror rows (parallel lists, peer-
+        major): every source range read before any destination is
+        written, then one batched payload-log write and one WAL append
+        per destination peer."""
+        if self.prof is not None:
+            self._wal_mirror[1] += len(m_peer)
+        for p in sorted(set(m_peer)):
+            self._ensure_epoch_begin(p)
+        floor_of = [pl.starts for pl in self.plogs]
+        if any(c and st <= floor_of[s][g]
+               for (s, g, st, c) in zip(m_src, m_g, m_start, m_count)):
+            self._trim_mirror(m_peer, m_src, m_g, m_start, m_count)
+        reads = [self.plogs[s].slice_columns(g, st, c)
+                 if c else ([], [])
+                 for (s, g, st, c) in zip(m_src, m_g, m_start, m_count)]
+        for p in range(self.cfg.num_peers):
+            b_g: List[int] = []
+            b_start: List[int] = []
+            b_count: List[int] = []
+            b_terms: List[int] = []
+            b_d: List[bytes] = []
+            puts = []
+            for (mp, g, st, c, nl), (terms, datas) in zip(
+                    zip(m_peer, m_g, m_start, m_count, m_newlen), reads):
+                if mp != p:
+                    continue
+                puts.append((g, st, datas, terms, nl))
+                if c:
+                    b_g.append(g)
+                    b_start.append(st)
+                    b_count.append(c)
+                    b_terms.extend(terms)
+                    b_d.extend(datas)
+            if puts:
+                self.plogs[p].put_ranges(puts)
+            if b_g:
+                # Mirrored batches may cross term boundaries; RANGE
+                # records are uniform-term, so split each mirror at its
+                # term changes (rare: elections).
+                s_g: List[int] = []
+                s_start: List[int] = []
+                s_count: List[int] = []
+                s_term: List[int] = []
+                pos = 0
+                for g, st0, c in zip(b_g, b_start, b_count):
+                    for (rs, rc, rt) in split_uniform_runs(
+                            st0, b_terms[pos: pos + c]):
+                        s_g.append(g)
+                        s_start.append(rs)
+                        s_count.append(rc)
+                        s_term.append(rt)
+                    pos += c
+                self.wals[p].append_ranges(s_g, s_start, s_count,
+                                           s_term, b_d)
 
     def _scrub_conf(self, g: int, base: int, datas: list) -> list:
         """Blank conf entries out of a publish batch (entries at

@@ -326,12 +326,10 @@ class MeshClusterNode(ClusterHostPlane):
         self._gg = gg
         self._g_loc = cfg.num_groups // gg
         self._check_mesh_meta(data_dir, gg)
+        # The sharded step dispatches exactly one consensus step, so
+        # this runtime (and the pod's, built on it) takes no `steps`:
+        # the host plane's bare default, one step a dispatch.
         super().__init__(cfg, data_dir, seed)
-        # The sharded step dispatches exactly one consensus step: pin
-        # steps-per-dispatch so a RAFTSQL_FUSED_STEPS env meant for the
-        # single-chip runtime cannot silently misreport the mesh's
-        # dispatch granularity.
-        self._steps = 1
         self._sharded_step = make_sharded_cluster_step_host(cfg, mesh)
         self._ti_spec = NamedSharding(mesh, timer_spec())
         # Host inputs go straight to their shards: jnp.asarray would

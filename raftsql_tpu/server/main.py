@@ -163,7 +163,8 @@ def build_fused_node(groups: int = 1, peers: int = 3,
     to the reference's 3-process Procfile cluster: same durability
     (fsync-per-peer between dispatches = save-before-send), no
     cross-process hops on the propose→commit path."""
-    from raftsql_tpu.runtime.fused import FusedClusterNode, FusedPipe
+    from raftsql_tpu.runtime.fused import (PIPELINE_STEPS,
+                                           FusedClusterNode, FusedPipe)
 
     # Leader leases on the fused plane: same safety clamp as
     # build_node — an operator-supplied lease can never exceed what
@@ -191,8 +192,12 @@ def build_fused_node(groups: int = 1, peers: int = 3,
     # for all P peers (storage/wal.py GroupCommitWAL).  An existing
     # per-peer data dir keeps its layout (the host plane refuses to
     # mix them); --wal-group-commit=off restores per-peer files.
+    # A dispatch carries the whole propose -> replicate -> commit ->
+    # learn pipeline: a write commits inside the launch that accepted
+    # it instead of waiting four of them.
     node = FusedClusterNode(cfg, f"{data_prefix}-fused",
-                            group_commit=wal_group_commit)
+                            group_commit=wal_group_commit,
+                            steps=PIPELINE_STEPS)
     if trace:
         node.enable_tracing()
     pipe = FusedPipe(node)

@@ -28,6 +28,7 @@ from raftsql_tpu.chaos import (ChaosSchedule, FsyncFault, FusedChaosRunner,
                                generate_stall, generate_tcp_plan)
 from raftsql_tpu.config import RaftConfig
 from raftsql_tpu.core.cluster import empty_cluster_inbox
+from raftsql_tpu.runtime.fused import PIPELINE_STEPS
 from raftsql_tpu.storage import fsio
 from raftsql_tpu.transport.faults import hold_messages, release_messages
 
@@ -164,13 +165,14 @@ def test_fsync_fault_is_fatal_and_recovers(tmp_path):
     assert r["committed_entries"] > 0
 
 
-def test_fused_scenario_multistep_epoch_framing(tmp_path):
-    """The same chaos under RAFTSQL_FUSED_STEPS-style multi-step
-    dispatch: crashes now interact with epoch framing (repair_epochs
-    drops uncommitted dispatch frames on restart)."""
+@pytest.mark.parametrize("steps", [2, PIPELINE_STEPS])
+def test_fused_scenario_multistep_epoch_framing(tmp_path, steps):
+    """The same chaos under multi-step dispatch (the served node's
+    depth among them): crashes now interact with epoch framing
+    (repair_epochs drops uncommitted dispatch frames on restart)."""
     sched = ChaosSchedule(seed=6, ticks=100,
                           torn_writes=(TornWriteFault(0, 50),))
-    r = FusedChaosRunner(sched, str(tmp_path), steps=2).run()
+    r = FusedChaosRunner(sched, str(tmp_path), steps=steps).run()
     assert r["committed_entries"] > 0
     assert r["crashes"] >= 1
 

@@ -100,13 +100,13 @@ class Recording(SQLiteStateMachine):
         return super().apply_batch(items)
 
 
-def deployment(data_dir, executed):
+def deployment(data_dir, executed, steps=1):
     """RaftDB over a fused node as `server.main --fused --resume
     --compact-every 32 --compact-keep 16` builds it, ticking on its own
     thread; small WAL segments so that some close."""
     node = FusedClusterNode(cfg_for(GROUPS, wal_segment_bytes=8192),
                             os.path.join(data_dir, "fused"),
-                            group_commit=True)
+                            group_commit=True, steps=steps)
 
     def factory(g):
         sm = Recording(os.path.join(data_dir, f"g{g}.db"), resume=True)
@@ -264,6 +264,87 @@ def test_power_loss_after_a_sweep_loses_no_acked_write(tmp_path,
             for mode in ("linear", "follower"):
                 assert rdb.query(SELECT, g, mode=mode) \
                     == ref.query(g, SELECT), (g, mode)
+    finally:
+        rdb.close()
+        ref.close()
+
+
+def test_power_loss_inside_a_served_dispatch_loses_no_acked_write(
+        tmp_path, monkeypatch):
+    """The same loss of power under the served node's dispatch
+    (PIPELINE_STEPS steps a launch, epoch-framed): the machine dies
+    after sweeps ran AND between a dispatch's WAL barrier and its
+    `EPOCHS` record, so every peer's log ends in a frame that no
+    commit record covers.  The restart erases that dispatch on every
+    peer (nothing of it was acknowledged) and reads every acknowledged
+    statement back."""
+    import shutil
+    from raftsql_tpu.runtime.fused import (PIPELINE_STEPS,
+                                           _read_committed_epoch)
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmarks"))
+    reference = importlib.import_module("lib.reference")
+    ref = reference.Reference()
+    history = ycsb_history(20261004, ops=300)
+    live, lost = str(tmp_path / "live"), str(tmp_path / "lost")
+    os.mkdir(live)
+    node, rdb = deployment(live, {}, steps=PIPELINE_STEPS)
+    try:
+        assert node._steps == PIPELINE_STEPS
+        for g, sql in history:
+            put(rdb, g, sql)                    # acknowledged
+            ref.apply(g, sql)
+        rdb._compactor.join()           # no round in flight
+        ticks = node.metrics.ticks
+        doc = rdb.metrics()
+        assert doc["compact"]["sweeps"] >= 3
+        assert doc["wal"]["segments_unlinked"] > 0
+        assert doc["dispatch"]["steps"] >= PIPELINE_STEPS * ticks
+        assert doc["dispatch"]["steps"] % PIPELINE_STEPS == 0
+        applied = rdb.store.applied.copy()
+        committed = node._epoch_no
+        assert committed > 0            # the dispatches were framed
+        # The power goes inside the next dispatch that writes: its
+        # frames reach every peer's WAL and are fsynced, its commit
+        # record never reaches EPOCHS.
+        died = threading.Event()
+
+        def power_off(no):
+            died.no = no
+            died.set()
+            raise OSError("power lost before the EPOCHS record")
+        monkeypatch.setattr(node, "_commit_epoch", power_off)
+        in_flight = [rdb.propose(
+            f"UPDATE usertable SET field0 = 'never acked' "
+            f"WHERE ycsb_key = 'user{g}x0'", g) for g in WRITTEN]
+        assert died.wait(30)
+        node._thread.join(30)
+        assert isinstance(node.error, OSError)
+        assert died.no == committed + 1
+        for fut in in_flight:           # refused, never acknowledged
+            assert fut.wait(10) is not None
+        shutil.copytree(live, lost, ignore=shutil.ignore_patterns(
+            "*.db-wal", "*.db-shm"))
+    finally:
+        rdb.close()
+    epochs = os.path.join(lost, "fused", "EPOCHS")
+    assert _read_committed_epoch(epochs) == committed
+    assert any(applied[g] > 0 for g in WRITTEN)
+    # The test bites: the disk holds a frame above the commit record.
+    probe = str(tmp_path / "probe")
+    shutil.copytree(os.path.join(lost, "fused", "gc"), probe)
+    assert GroupCommitWAL.repair_epochs(probe, committed)
+    node, rdb = deployment(lost, {}, steps=PIPELINE_STEPS)
+    try:
+        # The frame was on disk and is gone: the boot dropped it.
+        assert node._epoch_no == committed
+        for g in WRITTEN:
+            for mode in ("linear", "follower"):
+                assert rdb.query(SELECT, g, mode=mode) \
+                    == ref.query(g, SELECT), (g, mode)
+        # The node serves on: the next dispatch takes the number the
+        # lost one had.
+        put(rdb, WRITTEN[0], history[-1][1])
+        assert node._epoch_no > committed
     finally:
         rdb.close()
         ref.close()
@@ -929,7 +1010,7 @@ def test_a_batch_resent_below_the_floor_is_trimmed(tmp_path):
     row[_C["app_n"]], row[_C["new_log_len"]] = 3, last
     staged = [([], [], [], [], []) for _ in range(3)]
     wrote = node.wals[dest]._owner.base.written()[0]
-    node._durable_phases(pinfo, final=True, staged=staged)
+    node._durable_phases(pinfo[None], [staged])
     assert node.plogs[dest].length(g) == last
     assert node.plogs[dest].start(g) == last
     # Nothing of the re-sent batch was written again.
@@ -938,7 +1019,7 @@ def test_a_batch_resent_below_the_floor_is_trimmed(tmp_path):
     node.plogs[dest].lengths[g] = 0
     node.plogs[dest]._start[g] = 0
     with pytest.raises(RuntimeError, match="below the source's floor"):
-        node._durable_phases(pinfo, final=True, staged=staged)
+        node._durable_phases(pinfo[None], [staged])
     node.error = RuntimeError("scripted")       # stop() must not flush
     node.stop()
 

@@ -28,12 +28,14 @@ every call, `_hard`, `_wal_hard` and the return value must agree.  Then
 a restart reads back what dispatches that changed hard states ONLY
 wrote.
 """
+import functools
+
 import numpy as np
 import pytest
 
 from raftsql_tpu.config import RaftConfig
 from raftsql_tpu.runtime.db import _expand_commit_item
-from raftsql_tpu.runtime.fused import FusedClusterNode
+from raftsql_tpu.runtime.fused import PIPELINE_STEPS, FusedClusterNode
 from raftsql_tpu.runtime.hostplane import _C
 from raftsql_tpu.runtime.mesh import MeshClusterNode, MeshConfig
 from raftsql_tpu.storage.wal import (_HDR, _RANGE, REC_RANGE, WAL,
@@ -49,8 +51,9 @@ def cfg_for(peers=PEERS, groups=GROUPS):
                       tick_interval_s=0.0)
 
 
-def fused(data_dir, **shape):
-    return FusedClusterNode(cfg_for(**shape), data_dir, seed=3)
+def fused(data_dir, steps=1, **shape):
+    return FusedClusterNode(cfg_for(**shape), data_dir, seed=3,
+                            steps=steps)
 
 
 def mesh(data_dir, **shape):
@@ -256,11 +259,14 @@ def test_publish_refuses_a_commit_beyond_the_payload_log(make, tmp_path):
 
 # -- record order, on real ticks ------------------------------------------
 
-@pytest.mark.parametrize("make,steps", [(fused, 1), (fused, 2), (mesh, 1)],
-                         ids=["fused-1step", "fused-2steps", "mesh2-1step"])
+@pytest.mark.parametrize(
+    "make,steps",
+    [(fused, 1), (functools.partial(fused, steps=2), 2),
+     (functools.partial(fused, steps=PIPELINE_STEPS), PIPELINE_STEPS),
+     (mesh, 1)],
+    ids=["fused-1step", "fused-2steps", "fused-4steps", "mesh2-1step"])
 def test_hard_states_follow_every_entry_record_of_the_dispatch(
         make, steps, tmp_path, monkeypatch):
-    monkeypatch.setenv("RAFTSQL_FUSED_STEPS", str(steps))
     events = {}
 
     def logged(kind, real):

@@ -9,6 +9,7 @@ commit stream.
 import os
 
 import numpy as np
+import pytest
 
 import raftsql_tpu.runtime.fused as fused_mod
 from raftsql_tpu.config import RaftConfig
@@ -294,22 +295,169 @@ def test_fused_pipe_raftdb_sql_stack(tmp_path, monkeypatch):
         rdb.close()
 
 
-def test_multistep_dispatch_equals_single_step_ticks(tmp_path):
-    """RAFTSQL_FUSED_STEPS=S must be EXACTLY S single-step ticks: same
+def test_served_depth_acks_a_put_after_the_dispatch_that_accepted_it(
+        tmp_path):
+    """RaftDB over a fused node of the served depth (server/main.py
+    passes PIPELINE_STEPS), ticked by hand: a PUT proposed before a
+    dispatch is acknowledged after THAT dispatch's barrier, with no
+    other launch in between, whichever peer leads its group, and a
+    linear read after the ack sees it.  At one step a dispatch the
+    same PUT needs three to four launches."""
+    from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
+    from raftsql_tpu.runtime.db import RaftDB
+    from raftsql_tpu.runtime.fused import PIPELINE_STEPS, FusedPipe
+
+    G = 6
+    launches = {}
+    for steps in (1, PIPELINE_STEPS):
+        d = tmp_path / f"s{steps}"
+        d.mkdir()
+        node = FusedClusterNode(mkcfg(G), str(d / "raft"), seed=7,
+                                steps=steps)
+        rdb = RaftDB(lambda g, d=d: SQLiteStateMachine(str(d / f"g{g}.db")),
+                     FusedPipe(node), num_groups=G)
+        try:
+            elect(node)
+            # Peer 0, whose stream is applied, leads some groups and
+            # not others: both kinds are held to the one launch.
+            leaders = {int(h) for h in node._hints}
+            assert 0 in leaders and leaders - {0}, node._hints
+            futs = [rdb.propose("CREATE TABLE t (v text)", g)
+                    for g in range(G)]
+            for _ in range(8):
+                node.tick()
+            node.publish_flush()
+            assert [f.wait(10) for f in futs] == [None] * G
+            for _ in range(4):          # nothing in flight, no stash
+                node.tick()
+            node.publish_flush()
+            futs = [rdb.propose(f"INSERT INTO t (v) VALUES ('g{g}')", g)
+                    for g in range(G)]
+            t0 = node.metrics.ticks
+            acked: set = set()
+            while len(acked) < G:
+                node.tick()                 # one launch ...
+                node.publish_flush()        # ... its barrier, its publish
+                for g, f in enumerate(futs):
+                    if g not in acked:
+                        try:
+                            assert f.wait(2.0 if steps > 1 else 0.05) \
+                                is None
+                            acked.add(g)
+                        except TimeoutError:
+                            pass
+                assert node.metrics.ticks - t0 <= 8
+            launches[steps] = node.metrics.ticks - t0
+            for g in range(G):
+                assert rdb.query("SELECT v FROM t", g, linear=True,
+                                 timeout=10) == f"|g{g}|\n"
+        finally:
+            rdb.close()
+    assert launches[PIPELINE_STEPS] == 1, launches
+    assert launches[1] >= 3, launches
+
+
+@pytest.mark.parametrize("steps,share", [(1, 0.0), (4, 100.0)],
+                         ids=["1step", "4steps"])
+def test_dispatch_readers_on_a_real_nodes_counters(tmp_path, monkeypatch,
+                                                   steps, share):
+    """benchmarks/layers/steps_per_dispatch.py and
+    commit_in_dispatch_pct.py over the counters a node keeps
+    (`dispatch.steps`, `intake.committed_in_dispatch`): one proposal a
+    group a dispatch commits inside it at the pipeline's depth and
+    never at one step; a parent's document makes both silent, and both
+    are in the manifest for every cell."""
+    import importlib
+    import json
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(os.path.join(repo, "benchmarks"))
+    depth = importlib.import_module("layers.steps_per_dispatch")
+    inside = importlib.import_module("layers.commit_in_dispatch_pct")
+    node = FusedClusterNode(mkcfg(), str(tmp_path), seed=7, steps=steps)
+
+    def scrape():
+        node.publish_flush()
+        doc = dict(node.prof.counters_doc(), ticks=node.metrics.ticks)
+        return {"t": 0.0, "engine": doc, "workers": []}
+    try:
+        elect(node)
+        before = scrape()
+        for r in range(5):
+            for g in range(4):
+                node.propose_many(g, [f"SET r{r} g{g}".encode()])
+            node.tick()
+        for _ in range(4):
+            node.tick()
+        after = scrape()
+    finally:
+        node.stop()
+    assert depth.read(before, after, {}, None) == steps
+    assert after["engine"]["intake"]["accepted"] \
+        - before["engine"]["intake"]["accepted"] == 20
+    assert inside.read(before, after, {}, None) == share
+    # Nothing accepted in the window, or a program without the counters.
+    assert inside.read(after, after, {}, None) is None
+    old = {"t": 0.0, "engine": {"ticks": 9, "intake": {"accepted": 7}},
+           "workers": []}
+    new = {"t": 1.0, "engine": {"ticks": 19, "intake": {"accepted": 27}},
+           "workers": []}
+    assert depth.read(old, new, {}, None) is None
+    assert inside.read(old, new, {}, None) is None
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cells = [w["name"] for w in manifest["workloads"]]
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for name in ("steps_per_dispatch", "commit_in_dispatch_pct"):
+        m = by_name[name]
+        assert set(cells[:5]) <= set(m["workloads"])
+        assert m["moves"] == "write_p50_ms"
+        assert m["layer"] == "host plane tick (runtime/hostplane.py)"
+
+
+def test_served_ticks_compile_nothing_after_warm_up(tmp_path,
+                                                    monkeypatch):
+    """The served fused node runs ONE program: after the boot, the
+    elections and a first write, 50 more served ticks under writes add
+    no entry to any jit entry point's cache (the benchmark's
+    `window_compiles` guard)."""
+    from raftsql_tpu.analysis.tripwire import JitTripwire
+    from raftsql_tpu.runtime.fused import PIPELINE_STEPS
+    from raftsql_tpu.server.main import build_fused_node
+
+    monkeypatch.chdir(tmp_path)
+    rdb = build_fused_node(groups=3, peers=3, tick=0.001)
+    node = rdb.pipe.node
+    try:
+        assert node._steps == PIPELINE_STEPS
+        assert rdb.propose("CREATE TABLE t (v text)", 0).wait(60) is None
+        tw = JitTripwire()
+        t0 = node.metrics.ticks
+        n = 0
+        while node.metrics.ticks - t0 < 50:
+            assert rdb.propose(f"INSERT INTO t (v) VALUES ('{n}')",
+                               0).wait(30) is None
+            n += 1
+        assert tw.compiles()["cluster_multistep_host"] == 0
+        assert not any(tw.compiles().values()), tw.compiles()
+    finally:
+        rdb.close()
+
+
+@pytest.mark.parametrize("S", [2, 3, 4])
+def test_multistep_dispatch_equals_single_step_ticks(tmp_path, S):
+    """A dispatch of S steps must be EXACTLY S single-step ticks: same
     consensus math (same seed), same durable bytes, same published
     commits — only the dispatch/barrier granularity changes.  Drives
     two clusters through the identical step sequence (proposals enter
     at dispatch boundaries in both) and compares hard states, payload
     logs, applied KV state, and a restart replay of the multi-step
     node's WALs."""
-    S = 4
     cfg = mkcfg()
     a = FusedClusterNode(cfg, str(tmp_path / "single"), seed=11)
-    b = FusedClusterNode(cfg, str(tmp_path / "multi"), seed=11)
-    b._steps = S
+    b = FusedClusterNode(cfg, str(tmp_path / "multi"), seed=11, steps=S)
     try:
         # Same total warmup steps for both (b ticks S steps at a time).
-        warm = 40 * cfg.election_ticks
+        warm = 40 * cfg.election_ticks // S * S
         for _ in range(warm):
             a.tick()
         for _ in range(warm // S):
@@ -354,7 +502,7 @@ def test_multistep_dispatch_equals_single_step_ticks(tmp_path):
         b.stop()
 
     # The multi-step node's WALs replay to the same state.
-    c = FusedClusterNode(cfg, str(tmp_path / "multi"), seed=11)
+    c = FusedClusterNode(cfg, str(tmp_path / "multi"), seed=11, steps=S)
     try:
         assert (c._hard == b._hard).all()
     finally:
@@ -370,8 +518,7 @@ def test_multistep_uncommitted_dispatch_dropped_on_restart(tmp_path):
     S = 4
     cfg = mkcfg()
     d = str(tmp_path / "n")
-    node = FusedClusterNode(cfg, d, seed=5)
-    node._steps = S
+    node = FusedClusterNode(cfg, d, seed=5, steps=S)
     try:
         elect(node)
         for g in range(cfg.num_groups):
@@ -476,8 +623,7 @@ def test_epoch_file_creation_fsyncs_directory(tmp_path):
     d = str(tmp_path / "n")
     inj = fsio.StorageFaultInjector()
     with fsio.installed(inj):
-        node = FusedClusterNode(cfg, d, seed=2)
-        node._steps = 2
+        node = FusedClusterNode(cfg, d, seed=2, steps=2)
         try:
             elect(node)
             node.propose_many(0, [b"SET a 1"])

@@ -10,7 +10,7 @@ epoch-framed dispatch), and once on a MeshClusterNode over the forced
 host devices.  Held:
 
   (i)   every row the mask drops has no entry and a `new_log_len`
-        equal to the length of its payload log at that moment (the
+        equal to the length of its payload log at that step (the
         proof that an empty append never truncates is core/step.py's;
         this is what holds the host to it);
   (ii)  with the mask swapped for "keep every accepted append" (what
@@ -66,8 +66,8 @@ def mesh(data_dir):
 
 # -- the masks a run can take -------------------------------------------
 
-def took_of(pinfo):
-    return pinfo[:, :, _C["app_from"]] >= 0
+def took_of(infos):
+    return infos[..., _C["app_from"]] >= 0
 
 
 def lengths_of(plogs):
@@ -75,41 +75,54 @@ def lengths_of(plogs):
                      for pl in plogs])
 
 
-def keep_all(self, pinfo):
+def keep_all(self, infos):
     """What phase 1 listed before: every accepted append."""
-    took = took_of(pinfo)
-    return int(took.sum()), np.nonzero(took)
+    took = took_of(infos)
+    return took.reshape(len(infos), -1).sum(axis=1), np.nonzero(took)
 
 
-def drops_truncations(self, pinfo):
+def drops_truncations(self, infos):
     """A WRONG mask: it also drops every row whose `new_log_len` lies
     below its payload log's length, the conflict truncations.  (Every
     one: a leader sends an entry again until its ack arrives, and the
     second copy of a truncating append would repair the log.)"""
-    n_took, kept = MASK(self, pinfo)
-    ok = pinfo[kept][:, _C["new_log_len"]] >= lengths_of(self.plogs)[kept]
-    return n_took, (kept[0][ok], kept[1][ok])
+    n_took, kept = MASK(self, infos)
+    ok = infos[kept][:, _C["new_log_len"]] \
+        >= lengths_of(self.plogs)[kept[1:]]
+    return n_took, tuple(k[ok] for k in kept)
 
 
 def checked(mask, seen):
-    """`mask` with invariant (i) held at every call, and what the
-    schedule contained counted into `seen`."""
-    def _keep(self, pinfo):
+    """`mask` with invariant (i) held at every call, step by step of
+    the dispatch it is handed, and what the schedule contained counted
+    into `seen`."""
+    def _keep(self, infos):
+        # The payload logs' lengths as each step of the dispatch finds
+        # them: a step's accepted appends leave `new_log_len`, its
+        # leaders' own appends (a no-op at prop_base, proposals above
+        # it) end at prop_base + prop_accepted.
         lengths = lengths_of(self.plogs)
-        took = took_of(pinfo)
-        n_took, kept = mask(self, pinfo)
-        keep = np.zeros_like(took)
+        n_took, kept = mask(self, infos)
+        keep = np.zeros(infos.shape[:3], bool)
         keep[kept] = True
-        assert n_took == took.sum() and not (keep & ~took).any()
-        assert (np.diff(kept[0] * GROUPS + kept[1]) > 0).all()  # the order
-        new_len = pinfo[:, :, _C["new_log_len"]]
-        dropped = took & ~keep
-        assert (new_len[dropped] == lengths[dropped]).all(), \
-            "a dropped row would have changed its payload log"
-        assert (pinfo[:, :, _C["app_n"]][dropped] == 0).all()
-        seen["dropped"] += int(dropped.sum())
-        seen["kept"] += int(keep.sum())
-        seen["truncating"] += int((took & (new_len < lengths)).sum())
+        flat = (kept[0] * PEERS + kept[1]) * GROUPS + kept[2]
+        assert (np.diff(flat) > 0).all()                    # the order
+        for s, pinfo in enumerate(infos):
+            took = took_of(pinfo)
+            assert n_took[s] == took.sum() and not (keep[s] & ~took).any()
+            new_len = pinfo[:, :, _C["new_log_len"]]
+            dropped = took & ~keep[s]
+            assert (new_len[dropped] == lengths[dropped]).all(), \
+                "a dropped row would have changed its payload log"
+            assert (pinfo[:, :, _C["app_n"]][dropped] == 0).all()
+            seen["dropped"] += int(dropped.sum())
+            seen["kept"] += int(keep[s].sum())
+            seen["truncating"] += int((took & (new_len < lengths)).sum())
+            acc = pinfo[:, :, _C["prop_accepted"]]
+            led = (pinfo[:, :, _C["noop"]] != 0) | (acc > 0)
+            lengths = np.where(took, new_len, lengths)
+            lengths = np.where(led, pinfo[:, :, _C["prop_base"]] + acc,
+                               lengths)
         return n_took, kept
     return _keep
 
@@ -163,8 +176,8 @@ def run_schedule(monkeypatch, make, data_dir, mask, steps=1):
     monkeypatch.setattr(ClusterHostPlane, "_mirror_keep", mask)
     returns, trace, idle = [], [], {}
 
-    def _durable(self, pinfo, final, staged):
-        got = DURABLE(self, pinfo, final, staged)
+    def _durable(self, step_infos, staged):
+        got = DURABLE(self, step_infos, staged)
         returns.append(got)
         return got
     monkeypatch.setattr(ClusterHostPlane, "_durable_phases", _durable)
@@ -265,8 +278,9 @@ def assert_same(a, b, path=""):
 
 # -- the cases ------------------------------------------------------------
 
-@pytest.mark.parametrize("make,steps", [(fused, 1), (fused, 2), (mesh, 1)],
-                         ids=["fused", "fused-2-steps", "mesh4"])
+@pytest.mark.parametrize(
+    "make,steps", [(fused, 1), (fused, 2), (fused, 4), (mesh, 1)],
+    ids=["fused", "fused-2-steps", "fused-4-steps", "mesh4"])
 def test_mask_leaves_what_keeping_every_row_leaves(
         tmp_path, monkeypatch, make, steps):
     seen = {"dropped": 0, "kept": 0, "truncating": 0}
@@ -324,16 +338,17 @@ def test_mirror_keep_on_hand_made_rows(tmp_path, app_from, app_n, took,
                                        keep):
     node = fused(str(tmp_path))
     try:
-        pinfo = np.zeros((PEERS, GROUPS, len(_C)), np.int32)
-        pinfo[:, :, _C["app_from"]] = -1
-        pinfo[1, 3, [_C["app_from"], _C["app_n"]]] = 2, 1   # a row before
-        pinfo[2, 5, [_C["app_from"], _C["app_n"]]] = app_from, app_n
-        n_took, (peers, groups) = node._mirror_keep(pinfo)
+        # Two steps: a row in the first, the case in the second.
+        infos = np.zeros((2, PEERS, GROUPS, len(_C)), np.int32)
+        infos[..., _C["app_from"]] = -1
+        infos[0, 1, 3, [_C["app_from"], _C["app_n"]]] = 2, 1
+        infos[1, 2, 5, [_C["app_from"], _C["app_n"]]] = app_from, app_n
+        n_took, (steps, peers, groups) = node._mirror_keep(infos)
     finally:
         node.stop()
-    assert n_took == 1 + int(took)
-    assert list(zip(peers.tolist(), groups.tolist())) \
-        == [(1, 3)] + [(2, 5)] * int(keep)
+    assert n_took.tolist() == [1, int(took)]
+    assert list(zip(steps.tolist(), peers.tolist(), groups.tolist())) \
+        == [(0, 1, 3)] + [(1, 2, 5)] * int(keep)
 
 
 # -- the readers ----------------------------------------------------------

@@ -125,8 +125,7 @@ def test_crash_before_epoch_commit_erases_dispatch(tmp_path):
     inj = fsio.StorageFaultInjector()
     with fsio.installed(inj):
         cfg = mkcfg()
-        node = FusedClusterNode(cfg, str(tmp_path))
-        node._steps = 2
+        node = FusedClusterNode(cfg, str(tmp_path), steps=2)
         elect(node)
         node.propose_many(0, [b"SET a 1"])
         for _ in range(12):

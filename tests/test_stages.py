@@ -142,7 +142,8 @@ def test_metrics_document_holds_the_new_keys(served):
     assert set(doc["stages"]["get"]) == {"queue", "wait", "sql"}
     assert set(doc["apply"]) == {"runs", "groups", "fanout_runs"}
     assert set(doc["intake"]) == {"backlog", "offered", "accepted",
-                                  "groups"}
+                                  "groups", "committed_in_dispatch"}
+    assert set(doc["dispatch"]) == {"steps"}
     assert set(doc["wal"]) == {"records", "bytes", "hardstates",
                                "groups_written", "fsyncs", "shard_syncs",
                                "mirror_rows", "mirror_fallback_rows",
@@ -155,7 +156,7 @@ def test_metrics_document_holds_the_new_keys(served):
     assert set(doc["stages"]["publish"]) == {"queue"}
     assert "mesh_put" not in doc["phase_profile"]   # the mesh's alone
     assert {"launch", "readback", "wal_plan", "wal_append",
-            "wal_hardstate", "dispatch", "wal_write"} \
+            "wal_hardstate", "dispatch", "wal_write", "epoch_commit"} \
         <= set(doc["phase_profile"])
     assert set(doc["worker_stages"]["put"]) == {"edge_in", "ring_rtt",
                                                 "edge_out"}
@@ -189,6 +190,12 @@ def test_intake_counts_every_accepted_entry(served):
     assert intake["accepted"] <= intake["offered"] <= intake["backlog"]
     assert 0 < intake["groups"] <= intake["offered"]
     assert doc["proposals"] == intake["accepted"]
+    # The served node dispatches the pipeline's depth, so what a
+    # dispatch accepts at its first step commits inside it.
+    assert doc["dispatch"]["steps"] in (4 * doc["ticks"],
+                                        4 * doc["ticks"] + 4)
+    assert 0 < intake["committed_in_dispatch"] <= intake["accepted"]
+    assert doc["phase_profile"]["epoch_commit"]["n"] > 0
 
 
 def _wrote(wal):
