@@ -11,7 +11,10 @@ where the work happens, all cumulative, all on `GET /metrics`:
 PHASES — monotonic-clock stamps around each phase of the host plane's
 tick:
 
-    pop        proposal pop/stage (_build_prop_n + _stage_ranges)
+    pop        proposal pop/stage (_build_prop_n + _stage_ranges), of
+               which the second is also recorded apart as
+      pop_stage  _stage_ranges alone: the accepted payloads popped,
+                 group by group, into the durable phase's write plans
     dispatch   device dispatch + packed-info readback, which are also
                recorded apart as
       launch     the dispatch half: stage inputs, launch the program
@@ -59,6 +62,8 @@ phase, the shard streams a sharded WAL flushed, the follower ranges
 handed to the mirror, those of them that took the Python mirror, and
 `wal.mirror_skipped_rows`: the accepted appends that could change no
 log, empty heartbeat acks, and were dropped before any was listed;
+`publish.groups`: the groups in which a publish worker found commits of
+the client-facing peer to deliver, one count a dispatch a worker;
 `compact.sweeps`, `compact.floors_advanced` (groups x peers whose floor
 a sweep moved) and `wal.segments_unlinked`, one count() a sweep).
 
@@ -119,8 +124,10 @@ import numpy as np
 # Phases that partition the tick thread's wall time; ring_drain runs on
 # the serving plane's drain threads and is reported but excluded from
 # the tick-share denominators, as are the finer phases that lie INSIDE
-# dispatch (launch, readback) and wal_write (wal_*), and epoch_commit.
-PROF_PHASES = ("pop", "dispatch", "launch", "readback", "mesh_put",
+# pop (pop_stage), dispatch (launch, readback) and wal_write (wal_*),
+# and epoch_commit.
+PROF_PHASES = ("pop", "pop_stage", "dispatch", "launch", "readback",
+               "mesh_put",
                "wal_write", "wal_plan", "wal_append", "wal_hardstate",
                "fsync", "epoch_commit", "publish", "ring_drain")
 _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
@@ -139,7 +146,8 @@ ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
                    "wal.hardstates", "wal.groups_written", "wal.fsyncs",
                    "wal.shard_syncs", "wal.mirror_rows",
                    "wal.mirror_fallback_rows", "wal.mirror_skipped_rows",
-                   "apply.runs", "apply.groups", "apply.fanout_runs",
+                   "publish.groups", "apply.runs", "apply.groups",
+                   "apply.fanout_runs",
                    "compact.sweeps", "compact.floors_advanced",
                    "wal.segments_unlinked")
 # Read where they live, at export (gauge_fn); 0 until somebody says.

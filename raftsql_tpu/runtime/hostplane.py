@@ -1166,12 +1166,16 @@ class ClusterHostPlane:
                         # included): inside a write's propose_commit.
                         prof.stage("publish.queue", t0 - t_enq)
                     with span(ann, "tick.publish", ptick):
-                        self._publish_shard(pinfo, shard)
+                        n_groups = self._publish_shard(pinfo, shard)
                     dur = _t.monotonic() - t0
                     with self._metrics_mu:
                         self.metrics.t_publish_ms += dur * 1e3
                     if prof is not None:
-                        prof.record("publish", ptick, t0, dur, tid=shard)
+                        # publish.groups: once a dispatch a worker,
+                        # with the phase, never once a group.
+                        prof.record_tick(
+                            ptick, (("publish", t0, dur),),
+                            (("publish.groups", n_groups),), tid=shard)
             except Exception as e:
                 self.error = e
                 for cq in self._commit_qs:
@@ -1427,16 +1431,19 @@ class ClusterHostPlane:
             # and each half under its own name too; on a mesh the
             # inputs' way to their shards is a third part, `mesh_put`.
             # dispatch.steps: the consensus steps this launch carried.
+            # pop's second sample, the staging, also under a name of
+            # its own (pop_stage).
             # intake.*: what _build_prop_n found queued and offered,
             # what _stage_ranges popped as accepted, and how much of
             # that the publishing peer saw committed before the
             # dispatch ended.
             ld, rd = t1 - tl, t3 - t2b
+            sd = _t.monotonic() - ts0
             it = self._intake
             samples = [("pop", t0, tb - t0), ("dispatch", tl, ld),
                        ("launch", tl, ld), ("dispatch", t2b, rd),
                        ("readback", t2b, rd),
-                       ("pop", ts0, _t.monotonic() - ts0)]
+                       ("pop", ts0, sd), ("pop_stage", ts0, sd)]
             if put is not None:
                 samples += (("dispatch", tb, tl - tb),
                             ("mesh_put", tb, tl - tb))
@@ -1556,11 +1563,12 @@ class ClusterHostPlane:
         import time as _t
         tp = _t.monotonic()
         with span(self._ann, "tick.publish", tick_no):
-            self._publish(pinfo)
+            n_groups = self._publish(pinfo)
         pdur = _t.monotonic() - tp
         self.metrics.t_publish_ms += pdur * 1e3
         if self.prof is not None:
-            self.prof.record("publish", tick_no, tp, pdur)
+            self.prof.record_tick(tick_no, (("publish", tp, pdur),),
+                                  (("publish.groups", n_groups),))
 
     def _drain_pipeline(self) -> None:
         """Retire any stashed durable phase (manual-tick callers: the
@@ -2042,19 +2050,23 @@ class ClusterHostPlane:
                     datas[idx - base - 1] = b""
         return datas
 
-    def _publish(self, pinfo: np.ndarray) -> None:
+    def _publish(self, pinfo: np.ndarray) -> int:
         """Deliver a saved tick's newly committed entries to every
         peer's commit stream, across ALL group shards (the inline /
         serial-host path; the async path fans the same pinfo out to the
-        per-shard workers instead)."""
-        for shard in range(len(self._shard_groups)):
-            self._publish_shard(pinfo, shard)
+        per-shard workers instead).  Returns what the shards return,
+        summed."""
+        return sum(self._publish_shard(pinfo, shard)
+                   for shard in range(len(self._shard_groups)))
 
-    def _publish_shard(self, pinfo: np.ndarray, shard: int) -> None:
+    def _publish_shard(self, pinfo: np.ndarray, shard: int) -> int:
         """Deliver one group shard's newly committed entries to each
         peer's commit stream (they were fsynced before this runs) — the
-        whole tick's block as ONE RAW_MANY queue item per peer."""
+        whole tick's block as ONE RAW_MANY queue item per peer.
+        Returns the groups of the shard in which peer 0, the
+        client-facing stream, had commits to deliver (publish.groups)."""
         gsel = self._shard_groups[shard]
+        n_groups = 0
         for p in range(self.cfg.num_peers):
             col = pinfo[p]
             commit = col[:, _C["commit"]]
@@ -2064,10 +2076,13 @@ class ClusterHostPlane:
                 ready = gsel[commit[gsel] > self._applied[p][gsel]]
             if not ready.size:
                 continue
-            if p == 0 and self.tracer is not None:
-                # Quorum/commit stamp on the client-facing stream.
-                for g, c in zip(ready.tolist(), commit[ready].tolist()):
-                    self.tracer.note_commit(g, int(c))
+            if p == 0:
+                n_groups = ready.size
+                if self.tracer is not None:
+                    # Quorum/commit stamp on the client-facing stream.
+                    for g, c in zip(ready.tolist(),
+                                    commit[ready].tolist()):
+                        self.tracer.note_commit(g, int(c))
             if (self.publish_peers is not None
                     and p not in self.publish_peers) \
                     or p in self.witness_peers:
@@ -2106,6 +2121,7 @@ class ClusterHostPlane:
                 deltas = commit[ready] - np.asarray(al)
                 self.traffic.add_commit(ready, deltas)
                 self._note_commits(int(deltas.sum()))
+        return n_groups
 
     # -- log compaction (SURVEY §5.4) -----------------------------------
 

@@ -1130,7 +1130,7 @@ def test_compaction_readers_are_in_the_manifest_for_their_cells():
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     cell = "ycsb-a-10kgroups-resume"
     every = [w["name"] for w in manifest["workloads"]]
-    assert every[-1] == cell and len(every) == 5
+    assert cell in every        # later cells are appended after it
     for name in ("compact_sweep_ms", "compact_floors_per_sweep",
                  "wal_segments_unlinked_per_sweep", "wal_segments_pinned",
                  "wal_disk_mb", "compact_checkpoint_ms"):
@@ -1140,10 +1140,10 @@ def test_compaction_readers_are_in_the_manifest_for_their_cells():
     for name in ("read_p50_ms", "read_queue_ms", "read_wait_ms",
                  "read_sql_ms", "read_edge_wait_ms",
                  "wal_mirror_rows_per_tick", "wal_mirror_skipped_pct"):
-        assert by_name[name]["workloads"][-1] == cell, name
+        assert cell in by_name[name]["workloads"], name
     p95 = [m for m in manifest["end_to_end"]
            if m["name"] == "write_p95_ms"][0]
-    assert p95["workloads"][-1] == cell
+    assert cell in p95["workloads"]
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "multiraft-10k-resume.json")) as f:
         config = json.load(f)
