@@ -17,9 +17,10 @@ MESH_ENV = JAX_PLATFORMS=cpu \
 all: native
 
 native:
-	$(PY) -c "from raftsql_tpu.native.build import load_native_wal; \
-	          lib = load_native_wal(); \
-	          print('native wal:', 'ok' if lib else 'UNAVAILABLE')"
+	$(PY) -c "from raftsql_tpu.native.build import load_native_wal, \
+	              load_native_apply; \
+	          print('native wal:', 'ok' if load_native_wal() else 'UNAVAILABLE'); \
+	          print('native apply:', 'ok' if load_native_apply() else 'UNAVAILABLE')"
 
 # Build-check the native GROUP-COMMIT path (the views' group bias over
 # wal.cc): write through per-peer views of one shared native WAL,

@@ -490,6 +490,7 @@ def smoke(shape: Shape, chips: int, seed: int, dev: dict,
         server.say_log()
         check_engine(health, platform, chips)
         say("native WAL loaded", health["native_wal"])
+        say("native apply loaded", health["native_apply"])
         if chips > 1:
             say("mesh placement", json.dumps(health["mesh"]))
         # -- load (the first statement of the first connection is the
@@ -592,6 +593,7 @@ def smoke(shape: Shape, chips: int, seed: int, dev: dict,
         "devices": dev["count"], "jax": dev["jax"],
         "groups": shape.groups, "peers": shape.peers,
         "native_wal": health["native_wal"],
+        "native_apply": health["native_apply"],
         "first_204_cold_s": round(cold_204_s, 2),
         "first_204_restart_s": round(warm_204_s, 2),
         "requests": {"attempted": acked.attempted, "acked": acked.acked,

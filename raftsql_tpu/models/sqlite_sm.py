@@ -12,14 +12,22 @@ Mirrors the reference's raftdb SQL handling (reference db.go):
 
 SQLite is C reached through CPython's `sqlite3` binding — the same
 library the reference reaches through cgo (db.go:6), per SURVEY.md §2b V5.
+
+A file-backed machine applies a batch in ONE call into that library
+(native/apply.cc, on the connection's own handle) where it can; the
+Python loop over `sqlite3` calls is the same transaction, the arm for
+everything else and the only source of a failure's outcome.
 """
 from __future__ import annotations
 
+import ctypes
+import functools
 import os
 import sqlite3
 import threading
 from typing import Optional
 
+from raftsql_tpu.native.build import load_native_apply
 from raftsql_tpu.storage import fsio
 
 
@@ -28,6 +36,42 @@ def is_select(query: str) -> bool:
     write/read split (db.go:98-104), preserved deliberately."""
     tokens = query.strip(" ").split(" ")
     return len(tokens) > 0 and tokens[0].upper() == "SELECT"
+
+
+def _borrow(conn: sqlite3.Connection, path: str):
+    """native/apply.cc's `apply_txn` bound to the `sqlite3*` inside
+    `conn`, so that a batch's transaction runs on the connection's OWN
+    handle (a second connection would double the descriptors
+    models/store.py budgets by, and make every query re-read the pages
+    a write changed); None where the handle cannot be had or does not
+    prove itself, and the machine then stays on the Python loop.
+
+    CPython's connection object is `PyObject_HEAD` followed by
+    `sqlite3 *db` (Modules/_sqlite/connection.h), and the module has no
+    accessor for it.  The word is read only on an interpreter whose
+    layout was looked at (the library loads on no other: native/build.py
+    `APPLY_TESTED_ON`), because asking the library about a word that is
+    no handle is a wild dereference: a crash, not a counted fallback.
+    What is read there is then believed only if the library, asked
+    through it for the main database's file, names this machine's file,
+    outside a transaction."""
+    if path == ":memory:" or type(conn) is not sqlite3.Connection:
+        return None
+    lib = load_native_apply()
+    if lib is None:
+        return None
+    db = ctypes.c_void_p.from_address(
+        id(conn) + object.__basicsize__).value
+    if not db:
+        return None
+    try:
+        name = lib.apply_db_filename(db)
+        if name and os.path.samefile(name, path) \
+                and not conn.in_transaction:
+            return functools.partial(lib.apply_txn, db)
+    except OSError:
+        pass
+    return None
 
 
 def _cell(v) -> str:
@@ -64,7 +108,11 @@ class SQLiteStateMachine:
         # can therefore never be released.
         self.open_files = 0 if path == ":memory:" else (
             3 if self.has_durable_snapshot else 1)
-        self._conn = self._connect()
+        # Whether the one native call committed the last batch (one
+        # that fell back to the Python loop counts as that loop's):
+        # runtime/db.py reads it after its call.
+        self.last_native = False
+        self._connect()
         self._lock = threading.Lock()
         self._applied = 0
         self._dir_synced = False
@@ -80,8 +128,9 @@ class SQLiteStateMachine:
             "SELECT v FROM _raft_meta WHERE k='applied_index'").fetchone()
         return int(row[0]) if row else 0
 
-    def _connect(self) -> sqlite3.Connection:
-        """Open self.path configured for this state machine: manual
+    def _connect(self) -> None:
+        """Open self.path configured for this state machine, as
+        `self._conn`, and borrow its handle as `self._txn`: manual
         transaction control (apply_batch brackets its own BEGIN/COMMIT
         group commit — the module's implicit-BEGIN machinery would fight
         the explicit statements) and journaling matched to the upstream
@@ -109,7 +158,14 @@ class SQLiteStateMachine:
                 conn.execute("PRAGMA synchronous=OFF")
         except sqlite3.Error:          # pragma: no cover - pragma support
             pass
-        return conn
+        self._conn = conn
+        self._txn = _borrow(conn, self.path)
+
+    def _disconnect(self) -> None:
+        """Close the connection; the borrowed handle dies with it."""
+        self._txn = None
+        self._conn.close()
+        self._conn = None
 
     def applied_index(self) -> int:
         return self._applied
@@ -122,8 +178,7 @@ class SQLiteStateMachine:
         finds it as it was left."""
         with self._lock:
             if self._conn is not None:
-                self._conn.close()
-                self._conn = None
+                self._disconnect()
 
     def checkpoint(self) -> int:
         """Make everything applied so far survive a power loss, and
@@ -160,7 +215,7 @@ class SQLiteStateMachine:
         with self._lock:
             if self._conn is not None:
                 return
-            self._conn = self._connect()
+            self._connect()
             if self.resume:
                 on_file = self._applied_on_file()
                 if on_file != self._applied:
@@ -184,69 +239,104 @@ class SQLiteStateMachine:
         _applied before this runs, so a stale queued entry can never
         re-apply over the installed image."""
         with self._lock:
-            errs: list = []
-            attempted: list = []     # False = skipped as already applied
-            last = 0
+            errs = self._apply_native(items) if self._txn else None
+            self.last_native = errs is not None
+            if errs is None:
+                errs = self._apply_python(items)
+            return errs
+
+    def _skip(self, index: int) -> bool:
+        """Already applied before a restart or an install (resume mode):
+        the entry is consumed, its statement not run again."""
+        return bool(self.resume and index and index <= self._applied)
+
+    def _apply_native(self, items) -> Optional[list]:
+        """The batch's transaction as one call on the borrowed handle
+        (native/apply.cc; lock held).  None where it did not commit:
+        nothing of it landed, and `_apply_python` runs the same items."""
+        todo = [(cmd, ix) for cmd, ix in items if not self._skip(ix)]
+        last = max((ix for _, ix in todo), default=0)
+        try:
+            cmds = [cmd.encode("utf-8") for cmd, _ in todo]
+        except UnicodeEncodeError:      # a lone surrogate
+            return None
+        n = len(cmds)
+        if self._txn(n, (ctypes.c_char_p * n)(*cmds),
+                     (ctypes.c_int * n)(*map(len, cmds)),
+                     last if self.resume else 0):
+            return None
+        if last:
+            self._applied = last
+        return [None] * len(items)
+
+    def _apply_python(self, items) -> list:
+        """The batch's transaction statement by statement through the
+        `sqlite3` module (lock held): the arm of an in-memory machine,
+        of a process without the native library, and of every batch the
+        native call gave back."""
+        errs: list = []
+        attempted: list = []     # False = skipped as already applied
+        last = 0
+        try:
+            self._conn.execute("BEGIN")
+        except sqlite3.Error:       # already in a transaction
+            pass
+        for command, index in items:
+            if self._skip(index):
+                errs.append(None)
+                attempted.append(False)
+                continue
+            attempted.append(True)
             try:
-                self._conn.execute("BEGIN")
-            except sqlite3.Error:       # already in a transaction
-                pass
-            for command, index in items:
-                if self.resume and index and index <= self._applied:
-                    errs.append(None)
-                    attempted.append(False)
-                    continue
-                attempted.append(True)
-                try:
-                    self._conn.execute("SAVEPOINT _apply")
-                    self._conn.execute(command)
-                    self._conn.execute("RELEASE _apply")
-                    errs.append(None)
-                except sqlite3.Error as e:
-                    # A failed command still consumes its entry (the
-                    # error is its outcome, reference db.go:55-80): undo
-                    # only ITS effects, keep the batch.
-                    try:
-                        self._conn.execute("ROLLBACK TO _apply")
-                        self._conn.execute("RELEASE _apply")
-                    except sqlite3.Error:
-                        pass
-                    errs.append(e)
-                if index:
-                    last = max(last, index)
-            meta = ("INSERT INTO _raft_meta (k, v) VALUES "
-                    "('applied_index', ?) ON CONFLICT(k) DO UPDATE "
-                    "SET v=excluded.v")
-            try:
-                if self.resume and last:
-                    self._conn.execute(meta, (last,))
-                self._conn.commit()
-                if last:
-                    self._applied = last
+                self._conn.execute("SAVEPOINT _apply")
+                self._conn.execute(command)
+                self._conn.execute("RELEASE _apply")
+                errs.append(None)
             except sqlite3.Error as e:
-                # Commit failure (disk full): nothing of the batch
-                # landed.  Report it on every entry attempted in THIS
-                # transaction (skipped duplicates keep their None — they
-                # are durable from an earlier boot), then try to advance
-                # the durable floor alone so the entries stay consumed
-                # ("the error is their outcome") — the applied floor may
-                # only move when it is durable, because WAL compaction
-                # and snapshot labeling trust it (models/base.py).
+                # A failed command still consumes its entry (the
+                # error is its outcome, reference db.go:55-80): undo
+                # only ITS effects, keep the batch.
                 try:
-                    self._conn.rollback()
+                    self._conn.execute("ROLLBACK TO _apply")
+                    self._conn.execute("RELEASE _apply")
                 except sqlite3.Error:
                     pass
-                errs = [err if (err is not None or not att) else e
-                        for err, att in zip(errs, attempted)]
-                if last:
-                    try:
-                        if self.resume:
-                            self._conn.execute(meta, (last,))
-                            self._conn.commit()
-                        self._applied = last
-                    except sqlite3.Error:
-                        pass            # floor stays; log re-delivers
-            return errs
+                errs.append(e)
+            if index:
+                last = max(last, index)
+        meta = ("INSERT INTO _raft_meta (k, v) VALUES "
+                "('applied_index', ?) ON CONFLICT(k) DO UPDATE "
+                "SET v=excluded.v")
+        try:
+            if self.resume and last:
+                self._conn.execute(meta, (last,))
+            self._conn.commit()
+            if last:
+                self._applied = last
+        except sqlite3.Error as e:
+            # Commit failure (disk full): nothing of the batch
+            # landed.  Report it on every entry attempted in THIS
+            # transaction (skipped duplicates keep their None — they
+            # are durable from an earlier boot), then try to advance
+            # the durable floor alone so the entries stay consumed
+            # ("the error is their outcome") — the applied floor may
+            # only move when it is durable, because WAL compaction
+            # and snapshot labeling trust it (models/base.py).
+            try:
+                self._conn.rollback()
+            except sqlite3.Error:
+                pass
+            errs = [err if (err is not None or not att) else e
+                    for err, att in zip(errs, attempted)]
+            if last:
+                try:
+                    if self.resume:
+                        self._conn.execute(meta, (last,))
+                        self._conn.commit()
+                    self._applied = last
+                except sqlite3.Error:
+                    pass            # floor stays; log re-delivers
+        return errs
 
     def _image(self) -> bytes:
         """Serialize in DELETE journal mode: a WAL-mode image cannot be
@@ -307,7 +397,7 @@ class SQLiteStateMachine:
                     f.write(blob)
                     f.flush()
                     os.fsync(f.fileno())
-                self._conn.close()
+                self._disconnect()
                 try:
                     os.replace(tmp, self.path)
                     for suffix in ("-wal", "-shm"):
@@ -316,7 +406,7 @@ class SQLiteStateMachine:
                         except OSError:
                             pass
                 finally:
-                    self._conn = self._connect()
+                    self._connect()
             else:
                 self._conn.deserialize(blob)
             if self.resume:
@@ -351,5 +441,4 @@ class SQLiteStateMachine:
     def close(self) -> None:
         with self._lock:
             if self._conn is not None:
-                self._conn.close()
-                self._conn = None
+                self._disconnect()

@@ -1,0 +1,128 @@
+// Native apply — one group's apply transaction as ONE call into SQLite.
+//
+// models/sqlite_sm.py applies a drained run's batch for a group as one
+// transaction: BEGIN; per command SAVEPOINT _apply, the command, RELEASE
+// _apply; the _raft_meta upsert in resume mode; COMMIT.  Through
+// CPython's sqlite3 module that is 2 + 3n calls, and the module gives
+// the interpreter up around every prepare, step and reset inside each;
+// in a served engine of ~30 threads every hand-over that meets a holder
+// waits about a millisecond to get the interpreter back.  apply_txn()
+// runs the same transaction on the SAME handle (the sqlite3* of the
+// state machine's own sqlite3.Connection, borrowed and verified by
+// sqlite_sm.py); reached through ctypes.CDLL, the interpreter is given
+// up once, for the whole call.
+//
+// It commits the plain case and nothing else.  Whatever is not a
+// data-changing statement that runs straight to SQLITE_DONE -- an
+// error of any kind, a second statement in a command, a statement that
+// returns rows or only controls the transaction (COMMIT, ROLLBACK,
+// SAVEPOINT: they would change the bracket this function holds), a
+// statement with a parameter to bind (`?`, `:a`: the module refuses it,
+// "Incorrect number of bindings supplied", where a bare step would run
+// it with NULLs) -- ends in ROLLBACK and a non-zero return, and the
+// caller runs the SAME items through its Python loop, whose outcomes
+// (an error's class and text, per-statement isolation, the disk-full
+// branch) are thereby the only ones there are.
+//
+// This machine has no sqlite3.h: the prototypes below are the C API's,
+// unchanged since 3.7.  Linked with -l:libsqlite3.so.0, the object
+// _sqlite3 has already loaded: a handle may only be used with the
+// library that made it, and apply_sqlite_id() lets the loader check
+// that it is (native/build.py).
+//
+// ABI: plain C, consumed via ctypes (no pybind11 in this environment).
+
+#include <cstdio>
+#include <cstring>
+
+extern "C" {
+
+typedef struct sqlite3 sqlite3;
+typedef struct sqlite3_stmt sqlite3_stmt;
+
+int sqlite3_exec(sqlite3*, const char*,
+                 int (*)(void*, int, char**, char**), void*, char**);
+int sqlite3_prepare_v2(sqlite3*, const char*, int, sqlite3_stmt**,
+                       const char**);
+int sqlite3_step(sqlite3_stmt*);
+int sqlite3_finalize(sqlite3_stmt*);
+int sqlite3_stmt_readonly(sqlite3_stmt*);
+int sqlite3_bind_parameter_count(sqlite3_stmt*);
+int sqlite3_get_autocommit(sqlite3*);
+const char* sqlite3_db_filename(sqlite3*, const char*);
+const char* sqlite3_libversion(void);
+
+}  // extern "C"
+
+namespace {
+
+constexpr int kOk = 0;      // SQLITE_OK
+constexpr int kDone = 101;  // SQLITE_DONE
+
+bool run(sqlite3* db, const char* sql) {
+  return sqlite3_exec(db, sql, nullptr, nullptr, nullptr) == kOk;
+}
+
+// One replicated command inside its savepoint; true when it is applied.
+bool apply_one(sqlite3* db, const char* sql, int len) {
+  sqlite3_stmt* stmt = nullptr;
+  const char* tail = nullptr;
+  // len + 1: the caller's strings are NUL-terminated (ctypes c_char_p);
+  // a NUL inside one is the Python loop's to refuse.
+  if (std::memchr(sql, 0, len) != nullptr ||
+      sqlite3_prepare_v2(db, sql, len + 1, &stmt, &tail) != kOk ||
+      stmt == nullptr)
+    return false;
+  bool ok = !sqlite3_stmt_readonly(stmt) &&
+            sqlite3_bind_parameter_count(stmt) == 0;
+  for (const char* p = tail; ok && p < sql + len; ++p)
+    ok = *p == ' ' || *p == '\t' || *p == '\n' || *p == '\r' || *p == '\f';
+  ok = ok && sqlite3_step(stmt) == kDone;
+  return (sqlite3_finalize(stmt) == kOk) && ok;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The address of a symbol of the SQLite this object is bound to.
+const void* apply_sqlite_id() {
+  return reinterpret_cast<const void*>(&sqlite3_libversion);
+}
+
+// The main database's file name as the handle knows it (NULL or "" for
+// an in-memory or temporary one), for the borrower's check.
+const char* apply_db_filename(sqlite3* db) {
+  return sqlite3_db_filename(db, "main");
+}
+
+// Apply `n` commands (UTF-8, `lens` bytes each) and, where `meta_index`
+// is not 0, the applied index, as one transaction on `db`.  0: committed.
+// Non-zero: nothing of it landed and the handle is outside a transaction
+// again, except -1: a transaction was already open at the call and was
+// not touched.
+int apply_txn(sqlite3* db, int n, const char* const* cmds, const int* lens,
+              long long meta_index) {
+  if (!sqlite3_get_autocommit(db)) return -1;
+  bool ok = run(db, "BEGIN");
+  int at = 0;
+  for (; ok && at < n; ++at) {
+    ok = run(db, "SAVEPOINT _apply") && apply_one(db, cmds[at], lens[at]) &&
+         run(db, "RELEASE _apply");
+  }
+  if (ok && meta_index) {
+    char meta[160];
+    std::snprintf(meta, sizeof meta,
+                  "INSERT INTO _raft_meta (k, v) VALUES "
+                  "('applied_index', %lld) ON CONFLICT(k) DO UPDATE "
+                  "SET v=excluded.v",
+                  meta_index);
+    ok = run(db, meta);
+  }
+  ok = ok && run(db, "COMMIT") && sqlite3_get_autocommit(db);
+  if (ok) return 0;
+  if (!sqlite3_get_autocommit(db)) run(db, "ROLLBACK");
+  return at + 1;
+}
+
+}  // extern "C"

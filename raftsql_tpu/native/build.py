@@ -8,7 +8,8 @@ only if it was built from the source beside it.  Modification times
 prove nothing — a fresh checkout gives every file the same one, and a
 copied working tree carries ignored artifacts along.  Failures of any
 kind (no compiler, read-only checkout) degrade to the pure-Python
-implementations; `/healthz` says which one is serving (`native_wal`).
+implementations; `/healthz` says which one is serving (`native_wal`,
+`native_apply`).
 
 Set RAFTSQL_TPU_NATIVE=0 to force the Python fallbacks.
 """
@@ -20,6 +21,7 @@ import hashlib
 import logging
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 
@@ -30,14 +32,17 @@ _lock = threading.Lock()
 _cache: dict = {}
 
 
-def _compile(src: str, dest: str, link_args: tuple) -> bool:
+def _compile(src: str, dest: str, link_args: tuple,
+             libs: tuple = ()) -> bool:
     """Compile `src` to `dest` atomically (tmp + rename, so concurrent
     processes never open a half-written artifact); True on success,
-    warning + False on any failure, temp never leaked."""
+    warning + False on any failure, temp never leaked.  `libs` follow
+    the source on the command line, where the linker wants them."""
     fd, tmp = tempfile.mkstemp(dir=_DIR)
     os.close(fd)
     try:
-        cmd = ["g++", "-O2", "-std=c++17", *link_args, "-o", tmp, src]
+        cmd = ["g++", "-O2", "-std=c++17", *link_args, "-o", tmp, src,
+               *libs]
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True,
                                   timeout=120)
@@ -57,21 +62,23 @@ def _compile(src: str, dest: str, link_args: tuple) -> bool:
             os.unlink(tmp)
 
 
-def _artifact(stem: str, srcs: list, flags: tuple, suffix: str = ""):
+def _artifact(stem: str, srcs: list, flags: tuple, suffix: str = "",
+              libs: tuple = ()):
     """Path of `stem`'s artifact built from `srcs` (first is the
-    translation unit handed to g++) with `flags`, compiling it when no
-    object of exactly these sources exists; None when the build is
-    unavailable.  The name carries a hash of the sources and flags, so
-    a stale object beside changed source is never opened; superseded
-    objects of the same stem are unlinked after a successful build."""
-    h = hashlib.sha256(repr(flags).encode())
+    translation unit handed to g++) with `flags` and `libs`, compiling
+    it when no object of exactly these sources exists; None when the
+    build is unavailable.  The name carries a hash of the sources and
+    flags, so a stale object beside changed source is never opened;
+    superseded objects of the same stem are unlinked after a successful
+    build."""
+    h = hashlib.sha256(repr(flags + libs).encode())
     for src in srcs:
         with open(src, "rb") as f:
             h.update(f.read())
     path = os.path.join(_DIR, f"{stem}.{h.hexdigest()[:16]}{suffix}")
     if os.path.isfile(path):
         return path
-    if not _compile(srcs[0], path, flags):
+    if not _compile(srcs[0], path, flags, libs):
         return None
     for old in glob.glob(os.path.join(_DIR, f"{stem}.*{suffix}")):
         if old != path:
@@ -82,7 +89,7 @@ def _artifact(stem: str, srcs: list, flags: tuple, suffix: str = ""):
     return path
 
 
-def _load(name: str):
+def _load(name: str, libs: tuple = ()):
     """Compile (if no object of this source exists) and dlopen
     native/<name>.cc -> CDLL or None."""
     if os.environ.get("RAFTSQL_TPU_NATIVE", "1") == "0":
@@ -94,7 +101,7 @@ def _load(name: str):
         try:
             so = _artifact(f"_native_{name}",
                            [os.path.join(_DIR, f"{name}.cc")],
-                           ("-shared", "-fPIC"), suffix=".so")
+                           ("-shared", "-fPIC"), suffix=".so", libs=libs)
             if so is not None:
                 lib = ctypes.CDLL(so)
         except OSError as e:
@@ -227,3 +234,57 @@ def load_native_wal():
         log.warning("native wal ABI mismatch (%s); Python fallback", e)
         return None
     return lib
+
+
+# The interpreters on which taking the `sqlite3*` out of a
+# `sqlite3.Connection` by its layout (models/sqlite_sm.py `_borrow`) was
+# tested: (implementation, major, minor, size of a Connection object).
+APPLY_TESTED_ON = frozenset({("cpython", 3, 12, 224)})
+
+
+def _connection_layout() -> tuple:
+    import sqlite3
+    return (sys.implementation.name, *sys.version_info[:2],
+            sqlite3.Connection.__basicsize__)
+
+
+def load_native_apply():
+    """ctypes handle to the one-call apply transaction (apply.cc), or
+    None.  The object works on handles that CPython's `_sqlite3` made,
+    so it is only of use bound to the SAME loaded SQLite: linked by
+    soname against the library `_sqlite3` depends on, and checked here
+    by the address of one of its symbols as both see it (a `_sqlite3`
+    with a SQLite of its own inside, or none to look into, gives
+    None).  And only on an interpreter in `APPLY_TESTED_ON`: the handle
+    is a word read out of the connection object, and asking the library
+    about a word that is no handle is a crash, not a fallback."""
+    lib = _load("apply", libs=("-l:libsqlite3.so.0",))
+    if lib is None:
+        return None
+    with _lock:
+        if "apply_checked" in _cache:
+            return _cache["apply_checked"]
+        try:
+            if _connection_layout() not in APPLY_TESTED_ON:
+                raise OSError("a sqlite3.Connection's layout was not "
+                              f"tested on {_connection_layout()}")
+            import _sqlite3
+            theirs = ctypes.cast(
+                ctypes.CDLL(_sqlite3.__file__).sqlite3_libversion,
+                ctypes.c_void_p).value
+            lib.apply_sqlite_id.restype = ctypes.c_void_p
+            lib.apply_sqlite_id.argtypes = []
+            lib.apply_db_filename.restype = ctypes.c_char_p
+            lib.apply_db_filename.argtypes = [ctypes.c_void_p]
+            lib.apply_txn.restype = ctypes.c_int
+            lib.apply_txn.argtypes = [
+                ctypes.c_void_p, ctypes.c_int,
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_int), ctypes.c_longlong]
+            if lib.apply_sqlite_id() != theirs:
+                raise OSError("bound to another SQLite than _sqlite3's")
+        except (ImportError, AttributeError, OSError) as e:
+            log.warning("native apply unusable (%s); Python fallback", e)
+            lib = None
+        _cache["apply_checked"] = lib
+        return lib

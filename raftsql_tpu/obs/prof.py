@@ -147,7 +147,8 @@ ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
                    "wal.shard_syncs", "wal.mirror_rows",
                    "wal.mirror_fallback_rows", "wal.mirror_skipped_rows",
                    "publish.groups", "apply.runs", "apply.groups",
-                   "apply.fanout_runs",
+                   "apply.fanout_runs", "apply.native_txns",
+                   "apply.python_txns",
                    "compact.sweeps", "compact.floors_advanced",
                    "wal.segments_unlinked")
 # Read where they live, at export (gauge_fn); 0 until somebody says.

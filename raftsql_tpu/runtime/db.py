@@ -42,6 +42,7 @@ import numpy as np
 from raftsql_tpu.models.base import StateMachine
 from raftsql_tpu.models.store import StateMachineStore
 from raftsql_tpu.models.sqlite_sm import is_select
+from raftsql_tpu.native.build import load_native_apply
 from raftsql_tpu.overload import (Overloaded, deadline_steps,
                                   zero_metrics_doc)
 from raftsql_tpu.runtime.envelope import unwrap
@@ -57,12 +58,13 @@ from raftsql_tpu.utils.metrics import LatencyTimer
 log = logging.getLogger("raftsql_tpu.db")
 
 # Width of the apply pool: the groups of one drained run apply side by
-# side on this many threads (RaftDB._apply_run).  A SQLite transaction
-# is a handful of short calls that each drop the interpreter, wait for
-# the database file and must win the interpreter back from the tick,
-# WAL and ring threads: 34 ms a one-statement transaction in a served
-# 10,000-group engine, nearly all of it waiting (PERF.md, PR 27).  The
-# waits of different groups overlap; the statements' own work is small.
+# side on this many threads (RaftDB._apply_run).  A file-backed SQLite
+# machine's transaction is one native call that drops the interpreter
+# once (models/sqlite_sm.py): 3.8-5.3 ms in a served engine, the file
+# system's system calls and one wait to win the interpreter back from
+# the tick, WAL and ring threads (PERF.md, PR 35; 30-48 ms while it was
+# a dozen `sqlite3` calls, PR 27).  The waits of different groups
+# overlap; the statements' own work is small.
 APPLY_WORKERS = min(8, os.cpu_count() or 1)
 
 
@@ -176,13 +178,14 @@ def _commit_item_tops(item):
 
 
 def _apply_group(store: StateMachineStore, group: int,
-                 items: list) -> Tuple[list, float]:
+                 items: list) -> Tuple[list, float, bool]:
     """One group's batch of a run on its state machine: the error list
-    (one Optional[Exception] per item) and the wall time it took,
-    measured inside the thread that ran it, the way to the group's
-    handle included (its first use opens it).  Runs on the reader
-    thread or on an apply worker, so it touches nothing but the
-    store."""
+    (one Optional[Exception] per item), the wall time it took, measured
+    inside the thread that ran it, the way to the group's handle
+    included (its first use opens it), and whether the machine's one
+    native call committed it (models/sqlite_sm.py `last_native`).  Runs
+    on the reader thread or on an apply worker, so it touches nothing
+    but the store."""
     t0 = time.monotonic()
     with store.use(group) as sm:
         batch_fn = getattr(sm, "apply_batch", None)
@@ -190,7 +193,8 @@ def _apply_group(store: StateMachineStore, group: int,
             errs = batch_fn(items)
         else:
             errs = [sm.apply(qy, ix) for (qy, ix) in items]
-    return errs, time.monotonic() - t0
+        native = getattr(sm, "last_native", False)
+    return errs, time.monotonic() - t0, native
 
 
 class AckFuture:
@@ -457,8 +461,11 @@ class RaftDB:
             self._ack_one(group, query, err, commit_ts, acked)
         if acked:
             prof.stage_many(acked)
+            native = sum(d[2] for d in done)
             prof.count((("apply.runs", 1), ("apply.groups", len(per_g)),
-                        ("apply.fanout_runs", int(fanout))))
+                        ("apply.fanout_runs", int(fanout)),
+                        ("apply.native_txns", native),
+                        ("apply.python_txns", len(done) - native)))
         for _ in run:
             self._maybe_compact()
 
@@ -634,10 +641,14 @@ class RaftDB:
         if self._closed or (self._compactor is not None
                             and self._compactor.is_alive()):
             return              # the last round is still on its way
-        self._compactor = threading.Thread(
+        # Started before it is published: close(), on another thread,
+        # joins whatever it finds here, and a thread can only be joined
+        # once it has been started.
+        round_ = threading.Thread(
             target=self._compact_round, name="raftdb-compact",
             daemon=True)
-        self._compactor.start()
+        round_.start()
+        self._compactor = round_
 
     def _compact_round(self) -> None:
         """One compaction round, on a thread of its own (started by the
@@ -1164,12 +1175,15 @@ class RaftDB:
         # Where this engine runs: the device as JAX reports it plus the
         # JAX version and compile-cache traffic (utils/device.py), and
         # whether the WAL writes through the native fast path or fell
-        # back to Python — so nothing outside the process has to guess
-        # whether it measured the chip and the native plane.
+        # back to Python, and whether a SQLite file's transaction has
+        # its one native call to take — so nothing outside the process
+        # has to guess whether it measured the chip and the native
+        # plane.
         doc["device"] = device_doc()
         wals = getattr(node, "wals", None) or [getattr(node, "wal", None)]
         doc["native_wal"] = all(getattr(w, "is_native", False)
                                 for w in wals)
+        doc["native_apply"] = load_native_apply() is not None
         # Mesh deployment: the observed shard placement of the step's
         # carry and output (runtime/mesh.py mesh_doc).
         mesh_fn = getattr(node, "mesh_doc", None)

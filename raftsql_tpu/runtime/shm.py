@@ -72,6 +72,7 @@ import secrets
 import struct
 import threading
 import time
+from array import array
 from typing import Callable, Dict, List, Optional, Tuple
 
 # Header: magic, version, flags, num_groups, epoch, seq, log_head,
@@ -410,7 +411,7 @@ class _GroupReplica:
         from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
         self.sm = SQLiteStateMachine(":memory:", resume=True)
         self.group = group
-        self.consumed = 0        # log bytes already fed
+        self.consumed = 0        # records of this group already fed
 
 
 # Why try_read() declined, one name per `return None` (last_miss()):
@@ -465,6 +466,10 @@ class ShmSnapshotReader:
         self._table_off = _HDR_SIZE
         self._log_off = _HDR_SIZE + self.num_groups * _ROW_SIZE
         self._replicas: Dict[int, _GroupReplica] = {}
+        # Where each group's records lie in the log (offsets from its
+        # start, in log order), for the bytes walked so far.
+        self._records: Dict[int, array] = {}
+        self._indexed = 0
 
     # -- mapping access -------------------------------------------------
 
@@ -502,27 +507,46 @@ class ShmSnapshotReader:
             return h1, rows
         return None
 
+    def _index_log(self, log_head: int) -> None:
+        """Walk the log from where the last walk stopped up to
+        `log_head` and note, group by group, where each record lies.
+        The log is one stream for all groups: a replica that looked for
+        its own records by walking it from the start paid for every
+        other group's (10,000 groups read back after 100,000 writes
+        walked 10^9 records).  Caller holds the lock; bytes below
+        log_head are immutable, so no seqlock is needed."""
+        pos = self._indexed
+        while pos + _REC.size <= log_head:
+            ln, _kind, group, _index = _REC.unpack_from(
+                self._mm, self._log_off + pos)
+            if pos + _REC.size + ln > log_head:
+                break
+            where = self._records.get(group)
+            if where is None:
+                where = self._records[group] = array("Q")
+            where.append(pos)
+            pos += _REC.size + ln
+        self._indexed = pos
+
     # raftlint: fail-closed
     def _catch_up(self, rep: _GroupReplica, target: int,
                   log_head: int) -> bool:
-        """Feed the replica from the append-only log until its applied
-        index reaches `target`.  Log bytes below log_head are immutable
-        — no seqlock needed here.  False when the log ran out before
-        the target (publisher hasn't written it yet — fall back)."""
-        g = rep.group
+        """Feed the replica its group's records, in log order, until
+        its applied index reaches `target`.  False when the log ran out
+        before the target (publisher hasn't written it yet — fall
+        back)."""
+        if rep.sm.applied_index() >= target:
+            return True
+        self._index_log(log_head)
+        where = self._records.get(rep.group, ())
         while rep.sm.applied_index() < target:
-            if rep.consumed + _REC.size > log_head:
+            if rep.consumed >= len(where):
                 return False
-            off = self._log_off + rep.consumed
-            ln, kind, group, index = _REC.unpack(
-                self._mm[off:off + _REC.size])
-            if rep.consumed + _REC.size + ln > log_head:
-                return False
+            off = self._log_off + where[rep.consumed]
+            ln, kind, _group, index = _REC.unpack_from(self._mm, off)
             payload = bytes(self._mm[off + _REC.size:
                                      off + _REC.size + ln])
-            rep.consumed += _REC.size + ln
-            if group != g:
-                continue
+            rep.consumed += 1
             if kind == KIND_BASE:
                 if index > rep.sm.applied_index():
                     rep.sm.install(payload, index)

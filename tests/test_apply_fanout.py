@@ -177,7 +177,8 @@ def test_a_runs_groups_overlap_on_the_workers(rig_of, groups):
     spans.sort(key=lambda s: s[0])
     assert any(b[0] < a[1] for a, b in zip(spans, spans[1:]))   # overlap
     assert counters(rig) == {"runs": 1, "groups": groups,
-                             "fanout_runs": 1}
+                             "fanout_runs": 1, "native_txns": 0,
+                             "python_txns": groups}
     pair = rig.pipe.node.prof.stages_doc()["put"]["apply_batch"]
     assert pair["n"] == groups
     assert pair["total_ms"] >= groups * delay * 1e3 * 0.99
@@ -191,7 +192,8 @@ def test_a_run_of_one_group_stays_on_the_reader_thread(rig_of, entries):
     (_t0, _t1, thread), = rig.sms[2].intervals
     assert thread is rig.db._reader
     assert not rig.db._apply_pool._threads      # no worker was started
-    assert counters(rig) == {"runs": 1, "groups": 1, "fanout_runs": 0}
+    assert counters(rig) == {"runs": 1, "groups": 1, "fanout_runs": 0,
+                             "native_txns": 0, "python_txns": 1}
     assert rig.pipe.node.prof.stages_doc()["put"]["apply_batch"]["n"] == 1
 
 

@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
+from raftsql_tpu.native.build import load_native_apply  # noqa: E402
 
 
 def test_phases_against_cpu_server(tmp_path, monkeypatch):
@@ -45,6 +46,9 @@ def test_phases_against_cpu_server(tmp_path, monkeypatch):
     assert (out["platform"], out["device_kind"], out["devices"]) == \
         ("cpu", "cpu", 1)
     assert out["native_wal"] is True
+    # Where the host can build it (g++ and libsqlite3.so.0): a node
+    # without serves on the Python loop and says so.
+    assert out["native_apply"] is (load_native_apply() is not None)
     assert out["requests"] == {"attempted": 16 + 64 + 1,
                                "acked": 16 + 64 + 1, "failed": 0}
     assert out["rows"]["mismatched"] == 0

@@ -207,6 +207,33 @@ def test_deployment_against_the_plain_reference(tmp_path, monkeypatch):
         ref.close()
 
 
+def test_a_compaction_round_is_seen_only_once_it_runs(tmp_path,
+                                                       monkeypatch):
+    """`close()` joins the round it finds in `_compactor`, from another
+    thread than the one that starts rounds: a thread put there before
+    its `start()` cannot be joined ("cannot join thread before it is
+    started": where the driver's run of PR 34's tree died, in the test
+    above).  So a round is started first and published after."""
+    from raftsql_tpu.runtime import db as db_mod
+    box, published_early = [], []
+
+    class Watched(threading.Thread):
+        def start(self):
+            if self.name == "raftdb-compact":
+                published_early.append(box[0]._compactor is self)
+            super().start()
+
+    monkeypatch.setattr(db_mod.threading, "Thread", Watched)
+    node, rdb = deployment(str(tmp_path), {})
+    box.append(rdb)
+    try:
+        for g, sql in ycsb_history(20261004, ops=60):
+            put(rdb, g, sql)
+    finally:
+        rdb.close()
+    assert published_early and not any(published_early)
+
+
 def test_power_loss_after_a_sweep_loses_no_acked_write(tmp_path,
                                                        monkeypatch):
     """A state machine commits without a sync (`synchronous=NORMAL`):

@@ -73,9 +73,22 @@ def test_linear_read_your_writes_at_leader(cluster):
             f"INSERT INTO t (v) VALUES ('k{k}')").wait(TIMEOUT) is None
         # Immediately after the ack, a linear read at the leader must see
         # the write (the ack already implies local apply; the quorum
-        # round proves the leader is still current).
-        got = dbs[lead].query("SELECT count(*) FROM t", linear=True,
-                              timeout=TIMEOUT)
+        # round proves the leader is still current).  The election
+        # timeout is 50 ms: on a loaded box the leadership can move
+        # between two lines, the old leader then refuses, as it must,
+        # and the read goes to whoever leads now, where the
+        # acknowledged write must show all the same.
+        deadline = time.monotonic() + TIMEOUT
+        while True:
+            try:
+                got = dbs[lead].query("SELECT count(*) FROM t", linear=True,
+                                      timeout=TIMEOUT)
+                break
+            except NotLeaderError:
+                if time.monotonic() > deadline:
+                    raise
+                time.sleep(0.05)
+                lead = leader_index(dbs)
         assert got == f"|{k + 1}|\n", got
 
 

@@ -75,6 +75,60 @@ def test_local_and_session_roundtrip(tmp_path):
         pub.close()
 
 
+def test_a_replica_is_fed_its_own_records_and_the_log_is_walked_once(
+        tmp_path):
+    """The log is one stream for all groups.  A replica is fed its own
+    group's records, in order, from an index the reader builds in ONE
+    walk that goes on where it stopped: reading a group costs what
+    that group wrote, not what every group wrote (the read-back of
+    10,000 groups after 100,000 writes walked 10^9 records and passed
+    the benchmark's deadline)."""
+    groups, rounds = 24, 6
+    pub, rdr = _mk_pair(tmp_path, groups=groups)
+    walked = []
+    real = rdr._index_log
+
+    def counting(log_head):
+        before = rdr._indexed
+        real(log_head)
+        walked.append(rdr._indexed - before)
+
+    rdr._index_log = counting
+    try:
+        pub.publish_deltas({g: [(SCHEMA, 1)] for g in range(groups)})
+        for r in range(rounds):             # interleaved, run by run
+            pub.publish_deltas({
+                g: [(f"INSERT INTO t VALUES ({r * 10 + j}, 'g{g}')",
+                     2 + r * 2 + j) for j in range(2)]
+                for g in range(groups) if (g + r) % 3})
+        count = "SELECT count(*), min(v), max(v) FROM t"
+        for g in range(groups):
+            mine = [r for r in range(rounds) if (g + r) % 3]
+            n = 2 * len(mine)
+            rows, wm = rdr.try_read("local", g, count)
+            assert rows == f"|{n}|g{g}|g{g}|\n" and wm == 3 + 2 * mine[-1]
+            assert rdr._replicas[g].consumed == 1 + n
+        # One walk found every record; the other 23 reads walked nothing.
+        total = sum(len(v) for v in rdr._records.values())
+        assert total == groups + sum(
+            2 for r in range(rounds) for g in range(groups) if (g + r) % 3)
+        assert walked[0] > 0 and not any(walked[1:])
+        # More records: the walk goes on from where it stopped, and a
+        # replica from where it was fed.
+        pub.publish_deltas({5: [("INSERT INTO t VALUES (900, 'late')",
+                                 2 + 2 * rounds)],
+                            7: [("DELETE FROM t", 2 + 2 * rounds)]})
+        assert rdr.try_read("local", 7, count)[0] == "|0|||\n"
+        assert rdr.try_read("local", 5, count)[0].endswith("|late|\n")
+        assert 0 < walked[-2] < walked[0] and walked[-1] == 0
+        assert sum(len(v) for v in rdr._records.values()) == total + 2
+        # A session read past what the log holds still falls back.
+        assert rdr.try_read("session", 5, count, watermark=99) is None
+    finally:
+        rdr.close()
+        pub.close()
+
+
 def test_follower_and_linear_gates(tmp_path):
     """follower needs applied >= commit; linear additionally needs a
     live published lease and a fresh publisher heartbeat."""

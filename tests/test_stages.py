@@ -140,7 +140,8 @@ def test_metrics_document_holds_the_new_keys(served):
     assert set(doc["stages"]["put"]) == {"engine", "propose_commit",
                                          "apply", "apply_batch"}
     assert set(doc["stages"]["get"]) == {"queue", "wait", "sql"}
-    assert set(doc["apply"]) == {"runs", "groups", "fanout_runs"}
+    assert set(doc["apply"]) == {"runs", "groups", "fanout_runs",
+                                 "native_txns", "python_txns"}
     assert set(doc["intake"]) == {"backlog", "offered", "accepted",
                                   "groups", "committed_in_dispatch"}
     assert set(doc["dispatch"]) == {"steps"}
@@ -235,6 +236,13 @@ def test_apply_counters_count_runs_and_their_groups(served):
     assert batch["n"] == apply["groups"]
     assert 0 <= apply["fanout_runs"] <= apply["runs"] <= apply["groups"] \
         <= served.acked
+    # A group's batch by the arm that committed it: the one native call
+    # wherever the library loaded (no statement of this file fails).
+    assert apply["native_txns"] + apply["python_txns"] == apply["groups"]
+    status, _h, text = served.request("GET", path="/healthz")
+    assert status == 200
+    if json.loads(text)["native_apply"]:
+        assert apply["python_txns"] == 0
     assert 0 < batch["total_ms"] and batch["max_ms"] <= batch["total_ms"]
 
 
@@ -346,7 +354,8 @@ def test_apply_series_are_in_the_document_from_boot(tmp_path):
         str(tmp_path / f"g{g}.db")), FusedPipe(node), num_groups=2)
     try:
         doc = json.loads(rdb.render_metrics())
-        assert doc["apply"] == {"runs": 0, "groups": 0, "fanout_runs": 0}
+        assert doc["apply"] == {"runs": 0, "groups": 0, "fanout_runs": 0,
+                                "native_txns": 0, "python_txns": 0}
         assert doc["stages"]["put"]["apply_batch"] == {
             "total_ms": 0.0, "n": 0, "max_ms": 0.0}
         check_prom = _load_check_prom()
@@ -670,8 +679,9 @@ def test_a_record_never_waits_for_a_scrape():
     assert p.counters_doc()["wal"]["bytes"] == 7
     assert p.stages_doc()["put"]["apply"]["n"] == 1
     p.count((("apply.runs", 1), ("apply.groups", 3)))   # owned by no tick
-    assert p.counters_doc()["apply"] == {"runs": 1, "groups": 3,
-                                         "fanout_runs": 0}
+    assert p.counters_doc()["apply"] == {
+        "runs": 1, "groups": 3, "fanout_runs": 0, "native_txns": 0,
+        "python_txns": 0}
     assert p.phase_ticks("launch") == [1]
 
 
