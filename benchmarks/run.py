@@ -20,8 +20,12 @@ Steps (benchmarks/README.md has the layout and how to add to it):
      plain reference, SIGTERM the engine, reduce the trace;
   7. on every exit path kill the engine's process group and the
      generators and remove the scratch directory;
-  8. last line of stdout: the result object.  Everything else the run saw
-     goes on earlier lines, prefixed `bench:`.
+  8. last line of stdout: the result object; its last key, `compared`,
+     holds each number the verdict compared beside its limit, and the
+     same is the last line of stderr.  Everything else the run saw goes
+     on earlier lines, prefixed `bench:` (benchmarks/README.md lists
+     them; `slices` and `workers` say how the window went, 5 s by 5 s
+     and HTTP worker by HTTP worker).
 
 With `--trace 0` the metrics are the cell's end-to-end metrics, with
 `--trace 1` its per-layer metrics (benchmarks/layers/<name>.py each).
@@ -35,10 +39,12 @@ import argparse
 from http.client import HTTPConnection
 import importlib
 import json
+import math
 import os
 import resource
 import shutil
 import signal
+import statistics
 import subprocess
 import sys
 import tempfile
@@ -67,6 +73,7 @@ READBACK_SAMPLE = 1000
 READBACK_CONNECTIONS = 128
 READBACK_KEYS = 64          # keys of one group to a read-back statement
 NOFILE_SPARE = 2048
+SLICE_S = 5.0               # the `slices` line cuts the window this fine
 
 
 class RunFailure(Exception):
@@ -281,6 +288,49 @@ def client_numbers(log: List[list], t0: float, t1: float) -> dict:
     return out
 
 
+def window_slices(docs: List[dict], t0: float, t1: float) -> dict:
+    """The window cut into slices of SLICE_S seconds (the last one takes
+    what is left): per slice [requests answered, median of the writes
+    answered 204 in ms or None], over all clients (`all`) and per
+    generator process (`by_process`, in `docs`' order).  A request falls
+    into the slice it was ANSWERED in, as in client_numbers, so a row's
+    counts sum to `attempted`.  A run that changes regime inside its
+    window shows as slices that differ; a generator process that lags
+    shows as one row of `by_process` that differs from the others.  `t0`
+    is the window's start on CLOCK_MONOTONIC, the clock of the engine's
+    own log on this machine."""
+    n = max(1, math.ceil(round((t1 - t0) / SLICE_S, 6)))    # 40.0000001 s: 8
+
+    def cut(log: List[list]) -> List[list]:
+        cells: List[List[list]] = [[] for _ in range(n)]
+        for r in log:
+            if t0 <= r[6] <= t1:
+                cells[min(int((r[6] - t0) // SLICE_S), n - 1)].append(r)
+        out = []
+        for rows in cells:
+            ok_w = [(r[6] - r[5]) * 1e3 for r in rows
+                    if r[1] == "w" and r[7] == 204]
+            out.append([len(rows), round(statistics.median(ok_w), 1)
+                        if ok_w else None])
+        return out
+
+    return {"slice_s": SLICE_S, "t0": round(t0, 3),
+            "all": cut([r for d in docs for r in d["ops"]]),
+            "by_process": [cut(d["ops"]) for d in docs]}
+
+
+def worker_shares(before: dict, after: dict) -> List[list]:
+    """Per scrape connection [writes its worker answered in the window,
+    their mean ring round trip in ms]: a connection stays on one of the
+    SO_REUSEPORT workers, so rows that agree are one worker's and rows
+    that differ say how the kernel split the clients between them.  None
+    where the program counts no `worker_stages.put.ring_rtt`."""
+    path = "worker_stages.put.ring_rtt."
+    return [[stats.delta(b, a, path + "n"),
+             stats.per(b, a, path + "total_ms", path + "n")]
+            for b, a in zip(before["workers"], after["workers"])]
+
+
 def read_back(gens: Generators, port: int, ops, p: dict, seed: int,
               log: List[list]) -> Dict[str, Dict[str, str]]:
     """After the last write was answered: every key written and a seeded
@@ -416,6 +466,8 @@ def run(args, found: dict, scratch: str, live: dict) -> int:
         "attempted", "failed", "writes", "reads", "statuses", "ops_per_s",
         "write_p50_ms", "write_p95_ms", "read_p50_ms", "read_p95_ms",
         "generator_cpu_s")}))
+    say("slices", json.dumps(window_slices(docs, t0, t1)))
+    say("workers", json.dumps(worker_shares(before, after)))
     compiles = stats.delta(before["engine"], after["engine"],
                            "device.compile_cache.misses")
     say("compiled inside the window", compiles)
@@ -477,6 +529,10 @@ def run(args, found: dict, scratch: str, live: dict) -> int:
     if traced:
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    # What `correct` compared, each beside its limit: the comparison with
+    # the plain reference is exact, so the limit is 0.
+    result["compared"] = {"mismatched": {"value": verdict["mismatched"],
+                                         "limit": 0}}
     live["result"] = result
     return 0 if verdict["correct"] else 1
 
@@ -522,6 +578,9 @@ def main(argv=None) -> int:
         if scratch is not None:
             shutil.rmtree(scratch, ignore_errors=True)
     if "result" in live:
+        for name, c in live["result"]["compared"].items():
+            print(f"bench: compared: {name} {c['value']} (limit "
+                  f"{c['limit']})", file=sys.stderr, flush=True)
         print(json.dumps(live["result"]), flush=True)
     return rc
 

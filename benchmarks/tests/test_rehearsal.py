@@ -9,7 +9,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 def run_cell(workload, trace, seconds=4):
@@ -35,6 +36,13 @@ def assert_nothing_left(scratch):
         assert not cwd.startswith(scratch), f"pid {pid} still in {cwd}"
 
 
+def said(lines, key):
+    """The JSON of the run's one `bench: <key>:` line."""
+    found = [ln for ln in lines if ln.startswith(f"bench: {key}: ")]
+    assert len(found) == 1, (key, found)
+    return json.loads(found[0][len(f"bench: {key}: "):])
+
+
 def names(kind, cell):
     return {m["name"] for m in MANIFEST[kind]
             if "workloads" not in m or cell in m["workloads"]}
@@ -53,6 +61,25 @@ def test_untraced_run_reports_end_to_end_metrics_on_cpu():
     assert set(result["metrics"]) == names("end_to_end", "ycsb-a-10kgroups")
     for m in result["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
+    # What `correct` compared, beside its limit: last in the result and
+    # last on stderr.
+    assert list(result)[-1] == "compared"
+    assert result["compared"] == {"mismatched": {"value": 0, "limit": 0}}
+    assert r.stderr.strip().splitlines()[-1] == \
+        "bench: compared: mismatched 0 (limit 0)"
+    # The slices line: 4 s is one slice; over all clients and per
+    # generator process its answered counts sum to `attempted`.
+    slices = said(lines, "slices")
+    traffic = json.load(open(os.path.join(
+        ROOT, "benchmarks", "traffic", "rehearsal-mix.json")))
+    assert slices["slice_s"] == 5.0 and len(slices["all"]) == 1
+    assert len(slices["by_process"]) == traffic["processes"]
+    assert slices["all"][0][0] == result["attempted"] == sum(
+        row[0][0] for row in slices["by_process"])
+    assert slices["all"][0][1] > 0
+    rows = said(lines, "workers")
+    assert len(rows) == 4                           # SCRAPE_CONNECTIONS
+    assert all(n > 0 and ms > 0 for n, ms in rows)
     assert_nothing_left(scratch)
 
 
@@ -61,7 +88,9 @@ def test_traced_run_reports_per_layer_metrics_and_breakdown():
     assert r.returncode == 0, r.stderr[-2000:]
     result = json.loads(lines[-1])
     assert set(result) == RESULT_KEYS | {"breakdown"}
+    assert list(result)[-1] == "compared"
     assert result["correct"] is True
+    assert len(said(lines, "slices")["all"]) == 2      # 7 s: 5 + 2
     got = set(result["metrics"])
     assert got <= names("per_layer", "ycsb-a-10kgroups")
     assert {"tick_ms", "tick_wal_write_ms", "device_idle_pct",

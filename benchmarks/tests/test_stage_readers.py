@@ -5,10 +5,11 @@ them with the tick's phases named in the idle gaps."""
 import copy
 import importlib
 import json
+import os
 
 import pytest
 
-from test_rehearsal import run_cell
+from test_rehearsal import MANIFEST, ROOT, run_cell
 
 MANIFEST_NEW = [
     "edge_in_ms", "ring_hop_ms", "edge_out_ms", "engine_ack_ms",
@@ -141,18 +142,40 @@ def test_stage_readers_are_silent_on_the_parents_documents(name):
     assert reader.read(BEFORE, BEFORE, CLIENT, None) is None
 
 
+def test_tick_epoch_commit_ms_reads_the_commit_record_alone():
+    """PR 38: `phase_profile.epoch_commit` (recorded since PR 33) per tick
+    of the window; silent where a dispatch writes no commit record (the
+    mesh, the parents before PR 33) and where the window saw no tick."""
+    reader = importlib.import_module("layers.tick_epoch_commit_ms")
+
+    def doc(k, has=True):
+        d = engine_doc(k)
+        if has:
+            d["phase_profile"]["epoch_commit"] = pair(1100.0 * k, 100 * k)
+        return {"t": 20.0 * k, "engine": d, "workers": [d]}
+
+    assert reader.read(doc(1), doc(3), CLIENT, None) == pytest.approx(11.0)
+    assert reader.read(doc(1, False), doc(3, False), CLIENT, None) is None
+    assert reader.read(doc(2), doc(2), CLIENT, None) is None
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    entry = by_name["tick_epoch_commit_ms"]
+    assert entry["moves"] == "write_p50_ms"
+    assert entry["layer"] == by_name["tick_fsync_ms"]["layer"]
+    assert sorted(entry["workloads"]) == sorted(
+        w["name"] for w in MANIFEST["workloads"] if w["chips"] == 1)
+
+
 def test_every_new_reader_is_in_the_manifest_once():
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    manifest = json.load(open(os.path.join(root, "BENCHMARK.json")))
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(MANIFEST_NEW):] == MANIFEST_NEW
+    """Looked up by name: later PRs append entries (and a `benchmark` PR
+    may take one away), so no position in `per_layer` is pinned."""
+    names = [m["name"] for m in MANIFEST["per_layer"]]
     assert len(set(names)) == len(names)
-    for m in manifest["per_layer"][-len(MANIFEST_NEW):]:
-        assert m["source"] == "program_counter"
+    assert set(MANIFEST_NEW) <= set(names)
+    for m in MANIFEST["per_layer"]:     # ... and every entry has its reader
+        if m["name"] in MANIFEST_NEW:
+            assert m["source"] == "program_counter"
         assert os.path.exists(os.path.join(
-            root, "benchmarks", "layers", m["name"] + ".py"))
+            ROOT, "benchmarks", "layers", m["name"] + ".py")), m["name"]
 
 
 @pytest.mark.parametrize("workload", ["rehearsal-mix", "rehearsal-ycsb-b"])
