@@ -1602,11 +1602,7 @@ def bench_durable_fused(groups: int, peers: int, ticks: int, repeats: int,
                "durable_phase_overlap": overlapped,
                "durable_tick_ms": round(tick_ms, 3),
                "durable_lat": lat_stats,
-               "repeat_rates": repeat_rates,
-               # Serving-stack levers (PR 7): double-buffered dispatch
-               # engagement + the group-commit batch-size histogram
-               # (peers coalesced per fsync -> count).
-               "overlap_ticks": node.metrics.overlap_ticks}
+               "repeat_rates": repeat_rates}
         # Tick-phase profile (PR 8, obs/prof.py, default on —
         # RAFTSQL_PROF=0 for the A/B): per-phase shares of tick time
         # (fsync vs dispatch vs publish) + the p50/p95/p99 window, so

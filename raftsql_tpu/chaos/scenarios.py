@@ -451,12 +451,10 @@ class FusedChaosRunner:
                     self.node.timer_inc = ti
                     try:
                         self.node.tick()
-                        # With double-buffered dispatch (hostplane
-                        # overlap) the tick's durable phase is stashed;
-                        # this drain retires it, so the injected
-                        # storage faults fire HERE — same ops, same
-                        # order, same crash posture as the serialized
-                        # pipeline (digests must not move).
+                        # The tick's durable phase ran inside tick()
+                        # (the injected storage faults fire there);
+                        # this joins its publish and re-raises a
+                        # publish fault.
                         self.node.publish_flush()
                     except fsio.EnospcError:
                         # Disk full on a WAL append: the tick's durable

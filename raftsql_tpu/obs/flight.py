@@ -37,10 +37,8 @@ class FlightRecorder:
         write failed (never raises — the invariant error must win).
 
         `node` (a ClusterHostPlane) adds the SERVING-PLANE state the
-        post-PR-7 stack crashes with: the double-buffered overlap
-        stash's status at crash time (was a durable phase in flight,
-        and for which tick?), the WAL group-commit batch histogram,
-        and the tick-phase profile — plus the transfer plane's
+        post-PR-7 stack crashes with: the WAL group-commit batch
+        histogram and the tick-phase profile — plus the transfer plane's
         in-flight latches and recent outcomes (PR 11).  `ring_server`
         (runtime/ring.py RingServer) adds per-worker propose/completion
         ring cursors and depths.  `placement` (a PlacementController)
@@ -85,21 +83,6 @@ class FlightRecorder:
         """Serving-plane snapshot off a ClusterHostPlane (every field
         getattr-guarded: older/foreign engines just contribute less)."""
         out: dict = {}
-        stash = getattr(node, "_stash", None)
-        overlap = {"enabled": bool(getattr(node, "_overlap", False)),
-                   "stashed": stash is not None}
-        if stash is not None:
-            try:
-                _infos, staged, stick = stash
-                overlap["stash_tick"] = int(stick)
-                # Entries whose durable phase had NOT yet retired — the
-                # exact set a crash at this instant would lose.
-                overlap["stash_entries"] = int(sum(
-                    len(per_peer[4]) for step in staged
-                    for per_peer in step))
-            except Exception:       # noqa: BLE001 - diagnostics only
-                pass
-        out["overlap"] = overlap
         gcw = getattr(node, "_gcwal", None)
         if gcw is not None:
             out["wal_group_commit"] = {

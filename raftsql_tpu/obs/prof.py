@@ -88,13 +88,11 @@ such an event would take the name of every gap it spans (measured on
 the chip, PERF.md PR 26).  `StageSet` alone never touches JAX: it is
 what an HTTP worker process uses.
 
-OVERLAP-AWARE ATTRIBUTION: under double-buffered dispatch
-(runtime/hostplane.py, default on) tick t's stashed durable phase
-retires inside tick t+1's device window.  Every sample carries the
-tick that OWNS the work — the stash remembers its originating tick and
-the publish queue items carry theirs — so a phase histogram keyed by
-tick is identical whether the pipeline overlaps or not (pinned by
-tests/test_obs.py's attribution test).
+TICK ATTRIBUTION: the publish workers (and a serial host's deferred
+publish) run a tick's publish while a later tick runs.  Every sample
+carries the tick that OWNS the work — the publish queue items carry
+theirs — so a phase histogram keyed by tick counts each phase once per
+tick that owns it (pinned by tests/test_obs.py's attribution test).
 
 Default **on**.  The hot paths read `time.monotonic()` in place and
 hand a tick's samples and counts over in ONE call (`record_tick`: the

@@ -42,11 +42,6 @@ all-gathers the serialized GroupLogs so each host rebuilds the FULL
 replicated image — the cross-host analogue of ShardedWAL's merged
 replay, with the same wrong-shard refusal plus the PODMETA assignment
 check (pod/config.py).
-
-Overlap is disabled on the pod (`self._overlap = False`): the
-collective is the pipeline barrier, and stashing a durable phase past
-it would let this host's disk lag a dispatch other hosts already
-observed — exactly the hazard the barrier exists to exclude.
 """
 from __future__ import annotations
 
@@ -198,12 +193,6 @@ class PodClusterNode(MeshClusterNode):
             self._pod_exchange_replay(cfg, data_dir, g_loc)
         super().__init__(cfg, data_dir, mesh, seed)
         self._pod_replay = None
-        # The collective is the pipeline barrier: durable phase t must
-        # complete before this host contributes gather t+1, so the
-        # double-buffered stash (overlap) is disabled; tick() below
-        # also retires any deferred publish before returning, so
-        # in-memory == durable == published at every barrier.
-        self._overlap = False
 
     # -- boot: the cross-host replay exchange ---------------------------
 

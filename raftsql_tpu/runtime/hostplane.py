@@ -161,10 +161,10 @@ class ClusterHostPlane:
         #     /metrics group_traffic top-K hot-groups table.
         self.prof = TickPhaseProfiler.from_env(G)
         self.traffic = GroupTraffic(G)
-        # Overlap-aware phase attribution: the tick that OWNS the
-        # durable/publish work currently running (a stashed durable
-        # phase retiring inside tick t+1's dispatch window is tick
-        # t's).  _pending_tick tags the deferred-publish pinfo.
+        # Phase attribution: the tick that OWNS the durable/publish
+        # work currently running (a serial host's deferred publish,
+        # delivered inside tick t+1's dispatch window, is tick t's).
+        # _pending_tick tags the deferred-publish pinfo.
         self._prof_tick = 0
         self._pending_tick = 0
         self._fsync_span: Optional[tuple] = None    # (t0, dur) last tick
@@ -384,28 +384,6 @@ class ClusterHostPlane:
         # the next tick(); plumbed through the runtime's per-peer
         # timer_inc (core/cluster.py, parallel/sharded.py).
         self.timer_inc: Optional[np.ndarray] = None
-        # Double-buffered dispatch (RAFTSQL_OVERLAP_DISPATCH, default
-        # on): tick t's heavy durable phase (WAL writes + the fsync
-        # barrier) is STASHED at the end of tick t and retired inside
-        # tick t+1's device-dispatch window — the disk and the device
-        # work at the same time instead of in series.  Correctness gate
-        # (the module-doc contract, re-proved for the pipeline):
-        # durable phase t+1 begins only after durable phase t fully
-        # completed, and publish/acks for tick t follow its own
-        # barrier — so when any effect of a message is durable or
-        # externalized, its cause is durable.  The speculative dispatch
-        # t+1 (which observes tick t's not-yet-fsynced messages) lives
-        # only in volatile device memory until then; a crash loses the
-        # stash and dispatch together, and replay resumes from the last
-        # completed barrier (multi-step dispatches keep their epoch
-        # framing — an uncommitted epoch is erased on every peer).
-        # Proposal POPS for the stashed tick happen at stage time, so
-        # the next _build_prop_n snapshot (and its re-routes) see
-        # exactly the queue state the serialized pipeline would — the
-        # chaos digest must not move under overlap.
-        self._overlap = os.environ.get(
-            "RAFTSQL_OVERLAP_DISPATCH", "1") == "1"
-        self._stash: Optional[tuple] = None    # (step_infos, staged)
 
         # Multi-step dispatch epoch state (see tick()): the committed
         # epoch lives in data_dir/EPOCHS (12-byte records, fsynced once
@@ -1201,11 +1179,7 @@ class ClusterHostPlane:
         """Block until every enqueued publish has been delivered (the
         bench and tests read apply-plane state right after a tick
         loop).  Re-raises a publish fault — the async path must fail as
-        loudly as the inline one did.  Manual-tick callers (no tick
-        thread) also retire any stashed double-buffered durable phase
-        first — this is the pipeline drain."""
-        if self._thread is None:
-            self._drain_pipeline()
+        loudly as the inline one did."""
         for q in self._pub_qs:
             q.join()
         if self.error is not None:
@@ -1305,13 +1279,15 @@ class ClusterHostPlane:
     def tick(self) -> None:
         """One device step + the durable host phase.
 
-        Order (the contract in the module docstring): dispatch → (while
-        the device runs: publish the PREVIOUS tick's commits — they are
-        already durable) → read packed info → mirror-reads → WAL +
-        payload-log writes → fsync every peer.  The NEXT dispatch cannot
-        happen before this method returns, so every message composed
-        this tick is durable on its sender before any receiver observes
-        it; publish always runs after the save of the tick it publishes.
+        Order (the contract in the module docstring): offer → dispatch
+        → read packed info → stage → WAL + payload-log writes → fsync
+        every peer (→ the epoch commit) → publish.  The NEXT dispatch
+        cannot happen before this method returns, so every message
+        composed this tick is durable on its sender before any receiver
+        observes it, and a dispatch's commits reach the publish workers
+        before the next offer; publish always runs after the save of
+        the tick it publishes.  (A serial host may defer a heavy
+        publish into the next dispatch window: `_pending_pinfo`.)
         """
         import time as _t
         # The telemetry plane (obs/prof.py): the clock is read in place
@@ -1358,16 +1334,6 @@ class ClusterHostPlane:
                                  self.states.votes, self.inboxes.v_type,
                                  self.inboxes.a_type, self._applied)
         t1 = _t.monotonic()
-        # Double-buffered dispatch: the PREVIOUS tick's stashed durable
-        # phase (WAL writes + fsync barrier + publish) runs HERE, inside
-        # this dispatch's device window — tick t's disk time overlaps
-        # tick t+1's compute.  Strictly ordered: this completes before
-        # this tick's own durable phase can begin.
-        if self._stash is not None:
-            tw0 = _t.monotonic()
-            self._retire_stash()
-            self.metrics.overlap_ticks += 1
-            self.metrics.t_wal_ms += (_t.monotonic() - tw0) * 1e3
         # Overlap: tick t-1's commits are durable (fsynced last tick).
         # Parallel hosts hand them to the publish workers (the apply
         # plane runs concurrently with this whole tick); a 1-core host
@@ -1381,9 +1347,9 @@ class ClusterHostPlane:
                                      self._pending_tick)
             self._pending_pinfo = None
         if self._compact_req is not None:
-            # Between two durable phases: the previous tick's is down
-            # (retired above, or finished inline last tick), this
-            # tick's starts after the readback below.
+            # Between two durable phases: the previous tick's finished
+            # before this dispatch, this tick's starts after the
+            # readback below.
             self._run_compact_request()
         t2 = _t.monotonic()
         if self.overlap_hook is not None:
@@ -1416,12 +1382,8 @@ class ClusterHostPlane:
         self._device_steps += len(step_infos)
         if self._xfers:
             self._transfer_advance(pinfo)
-        # Stage the 2a ranges NOW (this pops the device-accepted
-        # proposals off the queues): whether the durable phase runs
-        # inline below or stashed into the next dispatch window, the
-        # next _build_prop_n snapshot must see post-pop queue state —
-        # that is what keeps the overlapped pipeline's trajectory
-        # bit-identical to the serialized one.
+        # Stage the 2a ranges (this pops the device-accepted proposals
+        # off the queues) before the durable phase that writes them.
         ts0 = _t.monotonic()
         with span(ann, "tick.pop", tick_no):
             staged = self._stage_ranges(step_infos)
@@ -1459,8 +1421,7 @@ class ClusterHostPlane:
             # Overload plane tick feed: drain-rate EWMA (Retry-After)
             # + queue-depth EWMA (the brownout governor's hysteresis).
             self.overload.note_tick()
-        # Content-derived activity signals (durable-independent so the
-        # stash decision cannot change them): any append staged or
+        # Content-derived activity signals: any append staged or
         # mirrored, or any hard state due to change.
         tick_active = any(
             bool(st_p[0]) for st in staged for st_p in st)
@@ -1484,27 +1445,15 @@ class ClusterHostPlane:
         # hot — warmup paces at interval_s instead of starving the
         # host core the cluster shares with its clients.
         self._spin_hot = tick_active or dev_busy or bool(self._queued)
-        # Double-buffer decision: while the pipeline is HOT another
-        # dispatch follows immediately, so this tick's durable phase is
-        # stashed and retired inside that dispatch's device window.
-        # Cold/parking ticks finish inline — deferring would add a
-        # whole (possibly parked) tick of ack latency for no overlap.
-        if self._overlap and self._spin_hot:
-            # The stash remembers its ORIGINATING tick: when it retires
-            # inside the next dispatch window, its durable/publish
-            # phases are attributed to this tick, not the one that
-            # happens to host the work (overlap-aware profiling).
-            self._stash = (step_infos, staged, self._tick_no)
-            self.metrics.t_device_ms += ((t1 - t0) + (t3 - t2b)) * 1e3
-            self._tick_active = base_active
-            self._tick_no += 1
-            self.metrics.ticks += 1
-            return
+        # The durable phase runs here, in the tick that dispatched, and
+        # its commits go to the publish workers before the next offer:
+        # a launch costs tens of ms of host time to start ~3 ms of
+        # device, so nothing is left to overlap it with (PERF.md, PR 39).
+        t4 = _t.monotonic()
         self._prof_tick = self._tick_no
         tick_active = self._finish_durable(step_infos, staged) \
             or tick_active
         base_active = base_active or tick_active
-        t4 = _t.monotonic()
         if base_active:
             if self._host_parallel:
                 # The publish workers ARE the overlap: hand the tick's
@@ -1542,21 +1491,6 @@ class ClusterHostPlane:
         self._tick_no += 1
         self.metrics.ticks += 1
 
-    def _retire_stash(self) -> None:
-        """Run the stashed tick's durable phase + publish (the
-        double-buffered pipeline's back half).  Caller order guarantees
-        this precedes the NEXT durable phase and its publish."""
-        step_infos, staged, stick = self._stash
-        self._stash = None
-        # Attribute the whole retired phase to its ORIGINATING tick.
-        self._prof_tick = stick
-        self._finish_durable(step_infos, staged)
-        pinfo = step_infos[-1]
-        if self._host_parallel:
-            self._enqueue_publish(pinfo)
-        else:
-            self._publish_inline(pinfo, stick)
-
     def _publish_inline(self, pinfo: np.ndarray, tick_no: int) -> None:
         """Deliver on the tick thread (serial hosts), timed as the
         `publish` phase of the tick that owns the commits."""
@@ -1570,21 +1504,13 @@ class ClusterHostPlane:
             self.prof.record_tick(tick_no, (("publish", tp, pdur),),
                                   (("publish.groups", n_groups),))
 
-    def _drain_pipeline(self) -> None:
-        """Retire any stashed durable phase (manual-tick callers: the
-        bench, chaos runners, tests).  NOT safe against a concurrently
-        running tick thread — stop() joins the thread first."""
-        if self._stash is not None:
-            self._retire_stash()
-
     def _finish_durable(self, step_infos, staged) -> bool:
         """The whole durable back half for one dispatch, from its
         packed info [S, P, G, C] and its S staged write plans: the
         durable phases (epoch-framed when multi-step), the epoch
         commit, and membership apply-at-commit.  Returns tick_active
         (anything written).  Attributed to `self._prof_tick` (set by
-        the caller: the live tick inline, the originating tick when a
-        stash retires)."""
+        the caller: the live tick)."""
         import time as _t
         pinfo = step_infos[-1]
         prof = self.prof
@@ -1699,8 +1625,7 @@ class ClusterHostPlane:
         packed info [S, P, G, C].  Runs at stage time, in the tick that
         read it: the pops must settle before the next tick's
         _build_prop_n snapshot (offer counts and re-routes read queue
-        lengths), whether the heavy durable write runs inline or
-        stashed into the next dispatch window.  Side effects that ride
+        lengths).  Side effects that ride
         the pop (conf-entry notes, tracer append stamps, the proposals
         counter) happen here too, in step order.
 
@@ -2326,16 +2251,6 @@ class ClusterHostPlane:
             # small grace).  The loop re-checks _stop_evt every tick.
             self._thread.join()
             self._thread = None
-        if self.error is None:
-            # Clean shutdown retires the double-buffered tail (WAL
-            # write + fsync + publish) so nothing acked-able is lost;
-            # an errored engine must NOT touch the WALs again.
-            try:
-                self._drain_pipeline()
-            except Exception as e:      # pragma: no cover - defensive
-                self.error = e
-        else:
-            self._stash = None
         if self._pending_pinfo is not None:
             self._prof_tick = self._pending_tick
             self._enqueue_publish(self._pending_pinfo)  # already durable

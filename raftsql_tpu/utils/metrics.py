@@ -39,13 +39,10 @@ class NodeMetrics:
     # quorum.{write_size,election_size,witnesses} are computed from the
     # config at export time (runtime/db.py metrics()).
     witness_appends: int = 0
-    # Serving-plane 10x counters (PR 7): WAL group commits — one
+    # Serving-plane counter (PR 7): WAL group commits — one
     # write+fsync covering EVERY peer's tick records (storage/wal.py
-    # GroupCommitWAL) — and double-buffered dispatch ticks, where the
-    # previous tick's durable phase ran inside the next dispatch's
-    # device window (runtime/hostplane.py overlap pipeline).
+    # GroupCommitWAL).
     wal_group_commits: int = 0
-    overlap_ticks: int = 0
     # Read-plane counters (the lease/ReadIndex/session read plane,
     # runtime/db.py query modes): how each served read was satisfied,
     # plus the lease lifecycle — grants (a linear read served straight
@@ -165,7 +162,6 @@ class NodeMetrics:
             "conf_changes_applied": self.conf_changes_applied,
             "witness_appends": self.witness_appends,
             "wal_group_commits": self.wal_group_commits,
-            "overlap_ticks": self.overlap_ticks,
             "reads": {
                 "local": self.reads_local,
                 "session": self.reads_session,

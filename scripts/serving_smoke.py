@@ -131,8 +131,7 @@ def main() -> int:
         print(f"serving-smoke: {j['n']} PUTs in {j['secs']:.1f}s -> "
               f"{rate:,.0f} req/s, {j['errors']} errors; "
               f"ring_workers={m.get('ring_workers')} "
-              f"wal_group_commits={m.get('wal_group_commits')} "
-              f"overlap_ticks={m.get('overlap_ticks')}")
+              f"wal_group_commits={m.get('wal_group_commits')}")
         if j["errors"]:
             return fail(f"{j['errors']} errored requests")
         if rate < min_rps:

@@ -785,7 +785,6 @@ def test_sweep_equals_the_parent_where_the_keep_rule_decides(
                 node.propose_many(g, payloads)
             for _ in range(5):
                 node.tick()
-            node._drain_pipeline()
             node.publish_flush()
             drain(node, applied[k])
         assert (applied[0] == applied[1]).all()
@@ -928,7 +927,6 @@ def loaded_node(data_dir, group_commit=True, rounds=10):
             node.tick()
     for _ in range(4):
         node.tick()
-    node._drain_pipeline()
     node.publish_flush()
     drain(node, applied)
     return node, applied
@@ -969,7 +967,6 @@ def test_quiet_groups_stop_pinning_segments(tmp_path, group_commit):
     node.propose_many(2, [b"SET late 1"])
     for _ in range(8):
         node.tick()
-    node._drain_pipeline()
     node.publish_flush()
     drain(node, applied)
     assert applied[2] == 2
@@ -993,7 +990,6 @@ def test_quiet_groups_stop_pinning_segments(tmp_path, group_commit):
         again.propose_many(5, [b"SET after restart"])
         for _ in range(8):
             again.tick()
-        again._drain_pipeline()
         again.publish_flush()
         drain(again, replayed)
         assert replayed[5] >= 3         # floor 1, a new no-op, the write

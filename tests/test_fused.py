@@ -70,12 +70,9 @@ def test_fused_commits_identically_on_all_peers(tmp_path):
 
 
 def test_fused_durable_barrier_every_dispatch(tmp_path, monkeypatch):
-    """SERIALIZED pipeline (overlap off): between any two consecutive
-    device dispatches, every peer's WAL was fsynced — the fused analog
-    of save-before-send (reference raft.go:227-235; the dispatch IS
-    the send).  The double-buffered default relaxes dispatch timing
-    but not durability ordering — pinned separately below."""
-    monkeypatch.setenv("RAFTSQL_OVERLAP_DISPATCH", "0")
+    """Between any two consecutive device dispatches, every peer's WAL
+    was fsynced — the fused analog of save-before-send (reference
+    raft.go:227-235; the dispatch IS the send)."""
     events = []
     real_step = fused_mod.cluster_step_host
     real_sync = WAL.sync
@@ -104,69 +101,60 @@ def test_fused_durable_barrier_every_dispatch(tmp_path, monkeypatch):
         assert gap.count("sync") >= cfg.num_peers, events[:30]
 
 
-def test_fused_overlap_barrier_ordering(tmp_path, monkeypatch):
-    """DOUBLE-BUFFERED pipeline (the default): tick t's durable phase
-    may run inside dispatch t+1's device window, but (a) barriers never
-    interleave — a dispatch gap carries a WHOLE tick's syncs or none —
-    and (b) no tick's commits are handed to the publish plane before
-    that tick's own barrier completed (save-before-externalize)."""
-    monkeypatch.setenv("RAFTSQL_OVERLAP_DISPATCH", "1")
-    events = []
-    real_step = fused_mod.cluster_step_host
-    real_sync = WAL.sync
+@pytest.mark.parametrize("steps", [1, 4])
+def test_fused_commits_publish_before_next_dispatch(tmp_path, monkeypatch,
+                                                    steps):
+    """A dispatch's commits reach the publish workers (_enqueue_publish)
+    after its OWN durable barrier and before the NEXT dispatch's device
+    call: every tick is dispatch -> barrier -> publish, in that order
+    (PR 39 removed the double-buffered stash, which held a dispatch's
+    durable phase and publish behind the next launch).  The regression
+    guard against any such deferral coming back."""
+    monkeypatch.setenv("RAFTSQL_FUSED_PARALLEL", "1")   # publish workers
+    events = []                 # (what, the tick it belongs to)
     real_finish = FusedClusterNode._finish_durable
+    nodes = []
 
-    def spy_step(*a, **k):
-        events.append("dispatch")
-        return real_step(*a, **k)
-
-    def spy_sync(self):
-        events.append("sync")
-        return real_sync(self)
+    def spy_device(real):
+        def call(*a, **k):
+            events.append(("dispatch", nodes[0]._tick_no))
+            return real(*a, **k)
+        return call
 
     def spy_finish(self, step_infos, staged):
         got = real_finish(self, step_infos, staged)
-        events.append("barrier")
+        events.append(("barrier", self._prof_tick))
         return got
 
-    monkeypatch.setattr(fused_mod, "cluster_step_host", spy_step)
-    monkeypatch.setattr(WAL, "sync", spy_sync)
+    monkeypatch.setattr(fused_mod, "cluster_step_host",
+                        spy_device(fused_mod.cluster_step_host))
+    monkeypatch.setattr(fused_mod, "cluster_multistep_host",
+                        spy_device(fused_mod.cluster_multistep_host))
     monkeypatch.setattr(FusedClusterNode, "_finish_durable", spy_finish)
 
-    publishes = []
     cfg = mkcfg(groups=2)
-    node = FusedClusterNode(cfg, str(tmp_path))
+    node = FusedClusterNode(cfg, str(tmp_path), steps=steps)
+    nodes.append(node)
     real_enq = node._enqueue_publish
-    real_pub = node._publish
 
     def spy_enq(pinfo):
-        publishes.append(len([e for e in events if e == "barrier"]))
-        events.append("publish")
+        events.append(("publish", node._prof_tick))
         return real_enq(pinfo)
 
-    def spy_pub(pinfo):
-        publishes.append(len([e for e in events if e == "barrier"]))
-        events.append("publish")
-        return real_pub(pinfo)
-
     node._enqueue_publish = spy_enq
-    node._publish = spy_pub
     elect(node)
     node.propose_many(0, [b"SET a 1", b"SET b 2"])
     for _ in range(10):
         node.tick()
-    assert node.metrics.overlap_ticks > 0       # the pipeline engaged
+    node.publish_flush()
+    got = [q for (_g, _i, q) in drain(node, 0)[0]]
     node.stop()
-    # (b) the k-th publish only after the k-th completed barrier.
-    for k, barriers_before in enumerate(publishes):
-        assert barriers_before >= k + 1, (k, publishes)
-    # (a) barriers never straddle a dispatch: the syncs between two
-    # consecutive barriers live in one dispatch gap.
-    gaps = " ".join(events).split("dispatch")
-    P = cfg.num_peers
-    for gap in gaps[1:-1]:
-        assert gap.count("sync") % P == 0 or "barrier" in gap, \
-            events[:40]
+    assert {"SET a 1", "SET b 2"} <= set(got)
+    n = node._tick_no
+    assert n >= 10
+    assert events == [(what, k) for k in range(n)
+                      for what in ("dispatch", "barrier", "publish")], \
+        events[:12]
 
 
 def test_fused_restart_replays_wal(tmp_path):

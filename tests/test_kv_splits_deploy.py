@@ -356,6 +356,9 @@ def test_configuration_is_multiraft_10ks_node(kv):
     # Not `write_p95_ms`: 1,000 writes are always in flight behind an
     # apply plane of ~270 transactions a second, so the tail swings run
     # by run (20.8% over fourteen seeds on the chip, PERF.md PR 34).
+    # Not `ops_per_s` (PR 38): in a closed loop with nothing saturated
+    # behind it, it is 1,000 / mean latency and the driver's pairs of
+    # one commit spread 21%, 18% and 37% against a bound of 15%.
     listed = {m["name"] for m in manifest["end_to_end"]
               if "kv0-10ksplits" in m.get("workloads", ["kv0-10ksplits"])}
-    assert listed == {"ops_per_s", "write_p50_ms", "setup_s"}
+    assert listed == {"write_p50_ms", "setup_s"}

@@ -370,39 +370,40 @@ def test_per_group_traffic_ranks_hot_group_first(tmp_path):
         node.stop()
 
 
-def test_profiler_attribution_matches_across_overlap_modes(
-        tmp_path, monkeypatch):
-    """Overlap-aware attribution: a stashed durable phase that retires
-    inside tick t+1's dispatch window belongs to tick t.  The SAME
-    deterministic workload must therefore yield the SAME set of
-    fsync/wal_write-owning ticks with RAFTSQL_OVERLAP_DISPATCH on and
-    off (naive record-where-it-ran attribution shifts every hot tick
-    by one)."""
-    results = {}
-    for overlap in ("1", "0"):
-        monkeypatch.setenv("RAFTSQL_OVERLAP_DISPATCH", overlap)
-        node = FusedClusterNode(mkcfg(groups=2),
-                                str(tmp_path / f"ov{overlap}"))
-        try:
-            assert node.prof is not None        # default ON
-            elect(node)
-            for i in range(6):
-                node.propose_many(0, [f"SET a{i} v".encode()])
-                node.tick()
-            for _ in range(6):
-                node.tick()
-            node.publish_flush()                # retires any stash
-            results[overlap] = {
-                "fsync": node.prof.phase_ticks("fsync"),
-                "wal": node.prof.phase_ticks("wal_write"),
-                "overlap_ticks": node.metrics.overlap_ticks,
-            }
-        finally:
-            node.stop()
-    assert results["1"]["overlap_ticks"] > 0    # the pipeline engaged
-    assert results["0"]["overlap_ticks"] == 0
-    assert results["1"]["fsync"] == results["0"]["fsync"]
-    assert results["1"]["wal"] == results["0"]["wal"]
+def test_profiler_attribution_matches_the_dispatching_tick(tmp_path):
+    """A durable phase's samples belong to the tick that dispatched it
+    (since PR 39 the tick that also runs it): the fsync- and
+    wal_write-owning ticks are exactly the ticks whose durable phase
+    wrote something, and every tick that carried a proposal is one of
+    them."""
+    node = FusedClusterNode(mkcfg(groups=2), str(tmp_path / "d"))
+    wrote = []
+    real = node._durable_phases
+
+    def spy(step_infos, staged):
+        active = real(step_infos, staged)
+        if active:
+            wrote.append(node._tick_no)
+        return active
+
+    node._durable_phases = spy
+    try:
+        assert node.prof is not None        # default ON
+        elect(node)
+        carried = []
+        for i in range(6):
+            node.propose_many(0, [f"SET a{i} v".encode()])
+            carried.append(node._tick_no)
+            node.tick()
+        for _ in range(6):
+            node.tick()
+        node.publish_flush()
+        fsync = node.prof.phase_ticks("fsync")
+        wal = node.prof.phase_ticks("wal_write")
+    finally:
+        node.stop()
+    assert fsync == wal == sorted(set(wrote))
+    assert set(carried) <= set(fsync)
 
 
 def test_phase_tracks_in_trace_doc(traced_node):
@@ -425,9 +426,8 @@ def test_phase_tracks_in_trace_doc(traced_node):
 
 
 def test_flight_bundle_carries_serving_state(tmp_path):
-    """Flight bundles now carry the PR 7 serving-plane state: overlap
-    stash status at crash time, the group-commit batch histogram, and
-    per-worker ring cursors/depths."""
+    """Flight bundles now carry the PR 7 serving-plane state: the
+    group-commit batch histogram and per-worker ring cursors/depths."""
     from raftsql_tpu.obs.flight import FlightRecorder
     from raftsql_tpu.runtime.ring import RingServer
 
@@ -437,8 +437,7 @@ def test_flight_bundle_carries_serving_state(tmp_path):
     try:
         elect(node)
         node.propose_many(0, [b"SET x 1", b"SET y 2"])
-        node.tick()     # hot tick: the overlap pipeline stashes
-        assert node._stash is not None
+        node.tick()
 
         class _Rdb:
             serving_metrics = None
@@ -450,10 +449,7 @@ def test_flight_bundle_carries_serving_state(tmp_path):
         with open(path) as f:
             doc = json.load(f)
         s = doc["serving"]
-        assert s["overlap"]["enabled"] is True
-        assert s["overlap"]["stashed"] is True
-        assert isinstance(s["overlap"]["stash_tick"], int)
-        assert s["overlap"]["stash_entries"] >= 2
+        assert "overlap" not in s
         assert s["wal_group_commit"]["group_commits"] >= 1
         assert isinstance(s["wal_group_commit"]["batch_hist"], dict)
         assert "phase_profile" in s and "group_traffic" in s

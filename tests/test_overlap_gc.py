@@ -1,16 +1,17 @@
-"""Double-buffered dispatch + WAL group commit (PR 7).
+"""A dispatch's durable phase + WAL group commit (PRs 7 and 39).
 
-Covers the two durable-plane levers of the serving-stack PR:
+Covers the durable plane of the serving stack:
 
-  * overlap pipeline (runtime/hostplane.py): crash mid-overlap loses
-    exactly the un-externalized pipeline tail — everything published
-    survives replay, the stashed tick vanishes atomically, and with
-    multi-step dispatch the epoch-erase semantics still hold (an
-    uncommitted dispatch whose records ARE durable is dropped on every
-    peer);
-  * chaos digest stability: the same seeded schedule produces
-    bit-identical schedule+result digests with the overlap pipeline on
-    and off, and with group commit layered on top;
+  * the durable phase (runtime/hostplane.py tick()): a crash inside a
+    dispatch's durable phase loses exactly that dispatch — everything
+    published survives replay, the dispatch's write vanishes
+    atomically, and with multi-step dispatch the epoch-erase semantics
+    hold (an uncommitted dispatch whose records ARE durable is dropped
+    on every peer);
+  * chaos digest stability: a seeded schedule's schedule+result
+    digests are pinned to the values the double-buffered dispatch gave
+    before PR 39 removed it (both of its arms agreed), and group commit
+    layered on top does not move them;
   * GroupCommitWAL (storage/wal.py): one fsync per barrier round for
     all P peers, per-peer replay split, and bit-identical cluster
     behavior vs the per-peer-file layout.
@@ -41,9 +42,8 @@ def elect(node, max_ticks=200):
 
 
 def _published(node):
-    """Everything delivered to peer 0's commit stream so far, WITHOUT
-    draining the double-buffer stash (only the async publish queues are
-    joined) — the crash tests depend on the stash staying pending."""
+    """Everything delivered to peer 0's commit stream so far (the async
+    publish queues are joined first)."""
     from raftsql_tpu.runtime.db import _expand_commit_item
     for q in node._pub_qs:
         q.join()
@@ -60,20 +60,23 @@ def _published(node):
     return out
 
 
-# -- crash mid-overlap -------------------------------------------------------
+# -- crash inside a dispatch's durable phase ----------------------------------
 
 
-def test_crash_mid_overlap_keeps_published_drops_stash(tmp_path):
-    """Crash with a stashed (never fsynced) tick in the pipeline: the
-    stash vanishes atomically; every entry ever PUBLISHED before the
-    crash replays."""
+class _SimCrash(RuntimeError):
+    pass
+
+
+def test_crash_mid_durable_phase_keeps_published_drops_dispatch(tmp_path):
+    """Crash inside a dispatch's durable phase, after the first peer's
+    appends and before the barrier: the dispatch's write vanishes
+    atomically; every entry ever PUBLISHED before the crash replays."""
     from raftsql_tpu.chaos.scenarios import hard_crash_fused
 
     inj = fsio.StorageFaultInjector()     # forces the Python backend:
     with fsio.installed(inj):             # buffered bytes die on crash
         cfg = mkcfg()
         node = FusedClusterNode(cfg, str(tmp_path))
-        assert node._overlap
         elect(node)
         node.propose_many(0, [b"SET a 1", b"SET b 2"])
         for _ in range(12):
@@ -81,11 +84,20 @@ def test_crash_mid_overlap_keeps_published_drops_stash(tmp_path):
         published = _published(node)
         keys_a = {(g, i) for (g, i, _q) in published}
         assert any(q == "SET a 1" for (_g, _i, q) in published)
-        # Tick once more with a FRESH batch so it sits in the stash,
-        # accepted by the device but never written to any WAL.
+        # Tick once more with a FRESH batch: the device accepts it, the
+        # first peer to append it writes its records, then the process
+        # dies before any fsync.
+        for w in node.wals:
+            real = w.append_ranges
+
+            def append_then_die(*a, _real=real, **k):
+                _real(*a, **k)
+                raise _SimCrash("crash inside the durable phase")
+
+            w.append_ranges = append_then_die
         node.propose_many(1, [b"SET z 9"])
-        node.tick()
-        assert node._stash is not None, "pipeline should be hot"
+        with pytest.raises(_SimCrash):
+            node.tick()
         published += _published(node)
         hard_crash_fused(node)
 
@@ -96,7 +108,7 @@ def test_crash_mid_overlap_keeps_published_drops_stash(tmp_path):
         # the replay, verbatim.
         for (g, i, q) in published:
             assert rkeys.get((g, i)) == q, (g, i, q)
-        # Atomic loss: the stashed tick's write never happened.
+        # Atomic loss: the crashed dispatch's write never happened.
         assert not any(q == "SET z 9" for q in rkeys.values())
         # The cluster continues: the lost write can be re-proposed.
         elect(node2, max_ticks=60)
@@ -110,16 +122,13 @@ def test_crash_mid_overlap_keeps_published_drops_stash(tmp_path):
         assert keys_a <= set(rkeys)
 
 
-class _SimCrash(RuntimeError):
-    pass
-
-
 def test_crash_before_epoch_commit_erases_dispatch(tmp_path):
-    """Multi-step dispatch + overlap: the stashed dispatch's WAL
-    records land and FSYNC on every peer, but the crash hits before the
-    cluster-atomic epoch commit — replay must ERASE the whole dispatch
-    on every peer (repair_epochs), because within a multi-step dispatch
-    peers observed each other's un-fsynced messages."""
+    """Multi-step dispatch: the dispatch's WAL records land and FSYNC
+    on every peer in the tick that dispatched it, but the crash hits
+    before the cluster-atomic epoch commit — replay must ERASE the
+    whole dispatch on every peer (repair_epochs), because within a
+    multi-step dispatch peers observed each other's un-fsynced
+    messages."""
     from raftsql_tpu.chaos.scenarios import hard_crash_fused
 
     inj = fsio.StorageFaultInjector()
@@ -136,15 +145,13 @@ def test_crash_before_epoch_commit_erases_dispatch(tmp_path):
                        for g in range(cfg.num_groups)]
 
         node.propose_many(1, [b"SET doomed 1"])
-        node.tick()                       # stash holds the dispatch
-        assert node._stash is not None
 
         def boom(no):
             raise _SimCrash(f"crash before epoch {no} commit")
 
         node._commit_epoch = boom
         with pytest.raises(_SimCrash):
-            node.tick()                   # retire writes+fsyncs, then dies
+            node.tick()                   # writes + fsyncs, then dies
         hard_crash_fused(node)
 
         node2 = FusedClusterNode(cfg, str(tmp_path))
@@ -158,35 +165,33 @@ def test_crash_before_epoch_commit_erases_dispatch(tmp_path):
         node2.stop()
 
 
-# -- chaos digests under the new pipeline ------------------------------------
+# -- chaos digests --------------------------------------------------------------
 
 
-def _chaos_digest(monkeypatch, overlap: str, gc: str, sched):
+def _chaos_digest(monkeypatch, gc: str, sched):
     from raftsql_tpu.chaos.scenarios import FusedChaosRunner
-    monkeypatch.setenv("RAFTSQL_OVERLAP_DISPATCH", overlap)
     monkeypatch.setenv("RAFTSQL_WAL_GROUP_COMMIT", gc)
     with tempfile.TemporaryDirectory(prefix="chaos-ovl-") as d:
         r = FusedChaosRunner(sched, d).run()
     return r["schedule_digest"], r["result_digest"]
 
 
-def test_chaos_digest_stable_under_overlap(monkeypatch):
-    """The same seeded fault schedule — partitions, crashes, storage
-    faults, the full invariant suite — produces IDENTICAL digests with
-    the double-buffered pipeline off and on: overlap moves work in
+def test_chaos_digest_pinned_across_dispatch_order(monkeypatch):
+    """The seeded fault schedule — partitions, crashes, storage faults,
+    the full invariant suite — gives the digests the parent of PR 39
+    printed with its double-buffered dispatch off AND on (they agreed):
+    running a dispatch's durable phase in its own tick moves work in
     time, never in content."""
     from raftsql_tpu.chaos.schedule import generate
     sched = generate(5, ticks=120)
-    base = _chaos_digest(monkeypatch, "0", "0", sched)
-    ovl = _chaos_digest(monkeypatch, "1", "0", sched)
-    assert base == ovl
+    assert _chaos_digest(monkeypatch, "0", sched) == (
+        "33c95ba64c902caa", "c78e3c63e1e407e7")
 
 
 def test_chaos_digest_stable_under_group_commit(monkeypatch):
     """Group commit is a WAL LAYOUT change: with the storage-fault
     windows stripped (they key on per-peer paths), the committed
-    history digest must match the per-peer layout exactly — under the
-    overlap pipeline too."""
+    history digest must match the per-peer layout exactly."""
     import dataclasses
 
     from raftsql_tpu.chaos.schedule import generate
@@ -195,8 +200,8 @@ def test_chaos_digest_stable_under_group_commit(monkeypatch):
     sched = dataclasses.replace(sched, fsync_faults=(), torn_writes=(),
                                 enospc_faults=(), fsync_stalls=())
     # Crash/restart events stay: replay must be layout-equivalent.
-    base = _chaos_digest(monkeypatch, "1", "0", sched)
-    gc = _chaos_digest(monkeypatch, "1", "1", sched)
+    base = _chaos_digest(monkeypatch, "0", sched)
+    gc = _chaos_digest(monkeypatch, "1", sched)
     assert base == gc
 
 

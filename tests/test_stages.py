@@ -405,45 +405,40 @@ def test_prof_off_leaves_the_new_keys_out(tmp_path, monkeypatch):
         rdb.close()
 
 
-def test_finer_phases_keep_overlap_attribution(tmp_path, monkeypatch):
+def test_finer_phases_keep_dispatch_attribution(tmp_path):
     """launch/readback belong to the tick that dispatched; wal_plan/
     wal_append/wal_hardstate to the tick that OWNS the durable phase,
-    as wal_write does: the same ticks with the pipeline overlapped and
-    serialized, and each part inside its whole."""
-    results = {}
-    for overlap in ("1", "0"):
-        monkeypatch.setenv("RAFTSQL_OVERLAP_DISPATCH", overlap)
-        node = FusedClusterNode(mkcfg(groups=2),
-                                str(tmp_path / f"ov{overlap}"))
-        try:
-            elect(node)
-            for i in range(6):
-                node.propose_many(0, [f"SET a{i} v".encode()])
-                node.tick()
-            for _ in range(6):
-                node.tick()
-            node.publish_flush()
-            p = node.prof
-            results[overlap] = {ph: p.phase_ticks(ph) for ph in (
-                "launch", "readback", "wal_write", "wal_plan",
-                "wal_append", "wal_hardstate")}
-            snap = p.snapshot()
-            ticks = node.metrics.ticks
-        finally:
-            node.stop()
-        got = results[overlap]
-        assert got["launch"] == got["readback"] == list(range(ticks))
-        assert got["wal_plan"] == got["wal_append"] \
-            == got["wal_hardstate"] == got["wal_write"]
-        assert snap["launch"]["n"] == snap["readback"]["n"] == ticks
-        assert snap["dispatch"]["n"] == 2 * ticks
-        assert abs(snap["dispatch"]["total_ms"] - snap["launch"]["total_ms"]
-                   - snap["readback"]["total_ms"]) < 0.01
-        parts = sum(snap[ph]["total_ms"] for ph in (
-            "wal_plan", "wal_append", "wal_hardstate"))
-        assert 0 < parts <= snap["wal_write"]["total_ms"] * 1.05 + 0.5
-        assert "launch_share" not in p.shares()
-    assert results["1"] == results["0"]
+    as wal_write and fsync do, which since PR 39 is the tick that
+    dispatched it too; each part inside its whole."""
+    node = FusedClusterNode(mkcfg(groups=2), str(tmp_path / "d"))
+    try:
+        elect(node)
+        for i in range(6):
+            node.propose_many(0, [f"SET a{i} v".encode()])
+            node.tick()
+        for _ in range(6):
+            node.tick()
+        node.publish_flush()
+        p = node.prof
+        got = {ph: p.phase_ticks(ph) for ph in (
+            "launch", "readback", "wal_write", "wal_plan",
+            "wal_append", "wal_hardstate", "fsync")}
+        snap = p.snapshot()
+        ticks = node.metrics.ticks
+    finally:
+        node.stop()
+    assert got["launch"] == got["readback"] == list(range(ticks))
+    assert got["wal_plan"] == got["wal_append"] \
+        == got["wal_hardstate"] == got["wal_write"] == got["fsync"]
+    assert got["wal_write"] and set(got["wal_write"]) <= set(range(ticks))
+    assert snap["launch"]["n"] == snap["readback"]["n"] == ticks
+    assert snap["dispatch"]["n"] == 2 * ticks
+    assert abs(snap["dispatch"]["total_ms"] - snap["launch"]["total_ms"]
+               - snap["readback"]["total_ms"]) < 0.01
+    parts = sum(snap[ph]["total_ms"] for ph in (
+        "wal_plan", "wal_append", "wal_hardstate"))
+    assert 0 < parts <= snap["wal_write"]["total_ms"] * 1.05 + 0.5
+    assert "launch_share" not in p.shares()
 
 
 def test_intake_and_wal_counters_in_process(tmp_path):
