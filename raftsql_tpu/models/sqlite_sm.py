@@ -25,6 +25,7 @@ import functools
 import os
 import sqlite3
 import threading
+import time
 from typing import Optional
 
 from raftsql_tpu.native.build import load_native_apply
@@ -128,7 +129,7 @@ class SQLiteStateMachine:
             "SELECT v FROM _raft_meta WHERE k='applied_index'").fetchone()
         return int(row[0]) if row else 0
 
-    def _connect(self) -> None:
+    def _connect(self, reopening: bool = False) -> None:
         """Open self.path configured for this state machine, as
         `self._conn`, and borrow its handle as `self._txn`: manual
         transaction control (apply_batch brackets its own BEGIN/COMMIT
@@ -146,12 +147,15 @@ class SQLiteStateMachine:
             the raft log replays forward from there — exactly-once
             preserved at a fraction of the fsync cost.  The log must
             still BE there: a compaction sweep drops it only under an
-            index `checkpoint()` has put on disk."""
+            index `checkpoint()` has put on disk.
+        A file this machine made or opened is in WAL mode for good (the
+        file says so), so `reopening` it asks only for the syncs."""
         conn = sqlite3.connect(self.path, check_same_thread=False)
         conn.isolation_level = None
         try:
             if self.has_durable_snapshot:
-                conn.execute("PRAGMA journal_mode=WAL")
+                if not reopening:
+                    conn.execute("PRAGMA journal_mode=WAL")
                 conn.execute("PRAGMA synchronous=NORMAL")
             else:
                 conn.execute("PRAGMA journal_mode=MEMORY")
@@ -170,15 +174,24 @@ class SQLiteStateMachine:
     def applied_index(self) -> int:
         return self._applied
 
-    def release(self) -> None:
+    def release(self) -> int:
         """Close the connection and keep the machine (models/store.py:
-        the least recently used handle gives its descriptors back).
-        Closing the last connection of a WAL-journal database
-        checkpoints it; the file stays, in either mode, and `reopen`
+        the least recently used handle gives its descriptors back);
+        returns the applied index the close put on disk, or 0.  Closing
+        the last connection of a WAL-journal database checkpoints it as
+        `checkpoint` does, with the same syncs, and drops the `-wal`
+        only where that checkpoint ran to its end: a `-wal` left behind
+        says it did not.  The file stays, in either mode, and `reopen`
         finds it as it was left."""
         with self._lock:
-            if self._conn is not None:
-                self._disconnect()
+            if self._conn is None:
+                return 0
+            self._disconnect()
+            if not self.has_durable_snapshot \
+                    or os.path.exists(self.path + "-wal"):
+                return 0
+            self._sync_dir()
+            return self._applied
 
     def checkpoint(self) -> int:
         """Make everything applied so far survive a power loss, and
@@ -192,20 +205,74 @@ class SQLiteStateMachine:
         trust an index this has returned (models/store.py
         `durable`)."""
         with self._lock:
-            if not self.has_durable_snapshot or self._conn is None:
+            if self._conn is None:
                 return 0
-            try:
-                busy, in_log, moved = self._conn.execute(
-                    "PRAGMA wal_checkpoint(FULL)").fetchone()
-            except sqlite3.Error:
-                return 0                # disk full: the log stays
-            if busy or in_log != moved:
-                return 0
-            if not self._dir_synced:
-                # The file's own directory entry, once.
-                fsio.fsync_dir(os.path.dirname(self.path) or ".")
-                self._dir_synced = True
-            return self._applied
+            return self._checkpoint()
+
+    def _checkpoint(self) -> int:
+        """`checkpoint` with the lock held and the connection open."""
+        if not self.has_durable_snapshot:
+            return 0
+        try:
+            busy, in_log, moved = self._conn.execute(
+                "PRAGMA wal_checkpoint(FULL)").fetchone()
+        except sqlite3.Error:
+            return 0                # disk full: the log stays
+        if busy or in_log != moved:
+            return 0
+        self._sync_dir()
+        return self._applied
+
+    def _sync_dir(self) -> None:
+        if not self._dir_synced:
+            # The file's own directory entry, once.
+            fsio.fsync_dir(os.path.dirname(self.path) or ".")
+            self._dir_synced = True
+
+    @staticmethod
+    def checkpoint_many(machines: list, threads: int) -> list:
+        """`checkpoint` each of `machines` (a compaction round's batch):
+        [(the applied index now on disk or 0, the seconds its checkpoint
+        took)], one a machine.  Every machine's lock is held for the
+        whole batch.  Those on the native arm go through ONE call into
+        SQLite, `threads` files at a time, with the interpreter given up
+        once (native/apply.cc `apply_checkpoint_many`); the others one
+        by one here.  A directory that holds a file put on disk for the
+        first time is synced once for all of them."""
+        out = [(0, 0.0)] * len(machines)
+        held, native = [], []
+        try:
+            for i, sm in enumerate(machines):
+                sm._lock.acquire()
+                held.append(sm)
+                if sm._conn is None or not sm.has_durable_snapshot:
+                    continue
+                if sm._txn is None:
+                    t0 = time.monotonic()
+                    out[i] = (sm._checkpoint(), time.monotonic() - t0)
+                else:
+                    native.append(i)
+            if native:
+                n = len(native)
+                ok, secs = (ctypes.c_int * n)(), (ctypes.c_double * n)()
+                # `_txn` is `apply_txn` bound to the borrowed handle.
+                dbs = (ctypes.c_void_p * n)(
+                    *(machines[i]._txn.args[0] for i in native))
+                load_native_apply().apply_checkpoint_many(
+                    dbs, n, threads, ok, secs)
+                for d in {os.path.dirname(machines[i].path) or "."
+                          for j, i in enumerate(native)
+                          if ok[j] and not machines[i]._dir_synced}:
+                    fsio.fsync_dir(d)
+                for j, i in enumerate(native):
+                    sm = machines[i]
+                    if ok[j]:
+                        sm._dir_synced = True
+                    out[i] = (sm._applied if ok[j] else 0, secs[j])
+        finally:
+            for sm in held:
+                sm._lock.release()
+        return out
 
     def reopen(self) -> None:
         """Connect again to the file `release` left.  Never deletes:
@@ -215,7 +282,7 @@ class SQLiteStateMachine:
         with self._lock:
             if self._conn is not None:
                 return
-            self._connect()
+            self._connect(reopening=True)
             if self.resume:
                 on_file = self._applied_on_file()
                 if on_file != self._applied:

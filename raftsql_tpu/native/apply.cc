@@ -30,10 +30,21 @@
 // library that made it, and apply_sqlite_id() lets the loader check
 // that it is (native/build.py).
 //
+// apply_checkpoint_many() is a compaction round's batch the same way:
+// the FULL checkpoints of many borrowed handles, several threads at a
+// time, in one call, where one `PRAGMA wal_checkpoint(FULL)` a file
+// through the module gives the interpreter up and takes it back for
+// each file on each of a pool's threads.
+//
 // ABI: plain C, consumed via ctypes (no pybind11 in this environment).
 
+#include <atomic>
+#include <chrono>
 #include <cstdio>
 #include <cstring>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 extern "C" {
 
@@ -51,6 +62,7 @@ int sqlite3_bind_parameter_count(sqlite3_stmt*);
 int sqlite3_get_autocommit(sqlite3*);
 const char* sqlite3_db_filename(sqlite3*, const char*);
 const char* sqlite3_libversion(void);
+int sqlite3_wal_checkpoint_v2(sqlite3*, const char*, int, int*, int*);
 
 }  // extern "C"
 
@@ -58,6 +70,7 @@ namespace {
 
 constexpr int kOk = 0;      // SQLITE_OK
 constexpr int kDone = 101;  // SQLITE_DONE
+constexpr int kCheckpointFull = 1;  // SQLITE_CHECKPOINT_FULL
 
 bool run(sqlite3* db, const char* sql) {
   return sqlite3_exec(db, sql, nullptr, nullptr, nullptr) == kOk;
@@ -123,6 +136,39 @@ int apply_txn(sqlite3* db, int n, const char* const* cmds, const int* lens,
   if (ok) return 0;
   if (!sqlite3_get_autocommit(db)) run(db, "ROLLBACK");
   return at + 1;
+}
+
+// Checkpoint each of `n` handles as `PRAGMA wal_checkpoint(FULL)` does
+// (the -wal synced, its pages copied into the file, the file synced),
+// `threads` handles at a time.  ok[i] = 1 where that ran to its end:
+// SQLITE_OK, and every frame of the log is in the file; secs[i] is the
+// time it took.  The caller holds each handle's machine: no statement
+// runs on one meanwhile.  A thread that cannot be started leaves its
+// share to the others.
+void apply_checkpoint_many(sqlite3* const* dbs, int n, int threads, int* ok,
+                           double* secs) {
+  std::atomic<int> next{0};
+  auto work = [&] {
+    for (int i; (i = next.fetch_add(1)) < n;) {
+      auto t0 = std::chrono::steady_clock::now();
+      int in_log = -1, moved = -1;
+      int rc = sqlite3_wal_checkpoint_v2(dbs[i], "main", kCheckpointFull,
+                                         &in_log, &moved);
+      ok[i] = rc == kOk && in_log == moved;
+      secs[i] = std::chrono::duration<double>(
+                    std::chrono::steady_clock::now() - t0).count();
+    }
+  };
+  std::vector<std::thread> pool;
+  for (int t = 1; t < threads && t < n; ++t) {
+    try {
+      pool.emplace_back(work);
+    } catch (const std::system_error&) {
+      break;
+    }
+  }
+  work();
+  for (auto& t : pool) t.join();
 }
 
 }  // extern "C"

@@ -281,6 +281,11 @@ def load_native_apply():
                 ctypes.c_void_p, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_char_p),
                 ctypes.POINTER(ctypes.c_int), ctypes.c_longlong]
+            lib.apply_checkpoint_many.restype = None
+            lib.apply_checkpoint_many.argtypes = [
+                ctypes.POINTER(ctypes.c_void_p), ctypes.c_int, ctypes.c_int,
+                ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_double)]
             if lib.apply_sqlite_id() != theirs:
                 raise OSError("bound to another SQLite than _sqlite3's")
         except (ImportError, AttributeError, OSError) as e:

@@ -48,9 +48,14 @@ document it relays) and of a tick's commits on their way to the apply
 plane (`stages.publish.queue`: handed to a publish worker -> taken up
 by it, one sample a worker a tick), and of a compaction
 (`stages.compact.checkpoint`: the state machines written since the
-last sweep put on disk, on a thread of its own, runtime/db.py; then
-`stages.compact.sweep`: the tick thread's sweep, one sample each a
-sweep).  No ring, no percentile, no per-request object.
+last sweep put on disk, on a thread of its own, runtime/db.py, with
+`stages.compact.file` for each file, its checkpoint as the thread that
+ran it timed it; then `stages.compact.sweep`: the tick thread's sweep,
+one sample each a sweep), and of the state-machine store
+(models/store.py: `stages.sm.miss`, a use that found its handle
+closed, from the pin to the file open again; `stages.sm.release`, one
+victim released, on the thread that needed its slot).  No ring, no
+percentile, no per-request object.
 
 COUNTERS — plain cumulative integers (`dispatch.steps`: the consensus
 steps the launches carried, one tick a launch; `intake.*`: what the
@@ -65,13 +70,16 @@ log, empty heartbeat acks, and were dropped before any was listed;
 `publish.groups`: the groups in which a publish worker found commits of
 the client-facing peer to deliver, one count a dispatch a worker;
 `compact.sweeps`, `compact.floors_advanced` (groups x peers whose floor
-a sweep moved) and `wal.segments_unlinked`, one count() a sweep).
+a sweep moved) and `wal.segments_unlinked`, one count() a sweep;
+`compact.rounds` and `compact.files`, the files a round put on disk,
+one count() a round).
 
 GAUGES — values that are read where they live when a document is made
 (`gauge_fn`): `wal.disk_bytes` and `wal.segments_pinned` (the WALs keep
 both as they rotate and unlink), and the state-machine store's
 `sm.opens`, `sm.closes`, `sm.evictions`, `sm.open_handles`
-(models/store.py).  They sit beside the counters in the document.
+and `sm.uses`, `sm.misses` (models/store.py).  They sit beside the
+counters in the document.
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
@@ -134,7 +142,8 @@ _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 # only after its first sample cannot be told from one that was lost).
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
                  "put.apply_batch", "get.queue", "get.wait", "get.sql",
-                 "publish.queue", "compact.sweep", "compact.checkpoint")
+                 "publish.queue", "compact.sweep", "compact.checkpoint",
+                 "compact.file", "sm.miss", "sm.release")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
 ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
@@ -148,10 +157,12 @@ ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
                    "apply.fanout_runs", "apply.native_txns",
                    "apply.python_txns",
                    "compact.sweeps", "compact.floors_advanced",
-                   "wal.segments_unlinked")
+                   "wal.segments_unlinked", "compact.rounds",
+                   "compact.files")
 # Read where they live, at export (gauge_fn); 0 until somebody says.
 ENGINE_GAUGES = ("wal.disk_bytes", "wal.segments_pinned", "sm.opens",
-                 "sm.closes", "sm.evictions", "sm.open_handles")
+                 "sm.closes", "sm.evictions", "sm.open_handles", "sm.uses",
+                 "sm.misses")
 
 
 # Appends a deque may hold before the appending thread folds them in

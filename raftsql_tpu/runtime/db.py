@@ -290,6 +290,9 @@ class RaftDB:
             prof.gauge_fn("sm.closes", lambda: store.closes)
             prof.gauge_fn("sm.evictions", lambda: store.evictions)
             prof.gauge_fn("sm.open_handles", store.open_handles)
+            prof.gauge_fn("sm.uses", lambda: store.uses)
+            prof.gauge_fn("sm.misses", lambda: store.misses)
+            store.prof = prof
         if resume:
             # Full state transfer for followers beyond the compaction
             # floor (InstallSnapshot) is only sound when re-apply is
@@ -659,18 +662,22 @@ class RaftDB:
         a state machine commits without a sync: what a loss of power
         leaves of a file is its last checkpoint.  So every group with
         `applied > synced` is checkpointed first (models/store.py; its
-        own applies and reads wait for that one file, nobody else's:
+        own applies and reads wait for that file's batch, nobody else's:
         the apply thread never waits for an fsync) and the sweep is
         handed `synced`, the indexes no power loss takes back, never
-        `applied`."""
+        `applied`.  A file a release put on disk is not opened again;
+        the open ones go several at a time in one call
+        (`StateMachineStore.checkpoint_round`)."""
         try:
             store = self.store
-            t0 = time.monotonic()
-            for g in np.flatnonzero(store.applied > store.synced).tolist():
-                store.checkpoint(g)
             prof = self._prof()
+            t0 = time.monotonic()
+            files = store.checkpoint_round(lambda: self._closed)
+            if self._closed:
+                return
             if prof is not None:
                 prof.stage("compact.checkpoint", time.monotonic() - t0)
+                prof.count((("compact.rounds", 1), ("compact.files", files)))
             # The apply thread moves on meanwhile.  `_delivered` is
             # written after the applies it stands for: read it FIRST,
             # so that where `applied` (read after) still equals

@@ -415,9 +415,15 @@ def fds():
     return len(os.listdir("/proc/self/fd"))
 
 
-@pytest.mark.parametrize("resume", [True, False], ids=["resume", "parity"])
-def test_store_holds_a_budget_under_appliers_and_readers(tmp_path, resume):
-    G, BUDGET, ROUNDS = 64, 8, 12
+@pytest.mark.parametrize("resume,rounds", [(True, False), (False, False),
+                                           (True, True)],
+                         ids=["resume", "parity", "resume-with-rounds"])
+def test_store_holds_a_budget_under_appliers_and_readers(tmp_path, resume,
+                                                         rounds):
+    """Appliers and readers (and, in the third case, compaction rounds
+    under a budget of 4) never serve from a handle being closed: a
+    statement or a query on a closed connection raises."""
+    G, BUDGET, ROUNDS = 64, (4 if rounds else 8), 12
     per_handle = 3 if resume else 1
 
     def make(tag, budget):
@@ -464,19 +470,40 @@ def test_store_holds_a_budget_under_appliers_and_readers(tmp_path, resume):
         except Exception as e:                          # noqa: BLE001
             errors.append(e)
 
+    checkpointed = []
+
+    def compactor():
+        try:
+            while not stop.wait(0.002):         # a round, then the next
+                checkpointed.append(small.checkpoint_round(stop.is_set))
+                # Never above what was applied: the sweep trusts it.
+                assert (small.synced <= small.applied).all()
+        except Exception as e:                          # noqa: BLE001
+            errors.append(e)
+
     appliers = [threading.Thread(target=applier,
                                  args=(list(range(i, G, 4)),))
                 for i in range(4)]
     readers = [threading.Thread(target=reader, args=(s,))
                for s in range(4)]
-    for t in appliers + readers:
+    others = readers + ([threading.Thread(target=compactor)]
+                        if rounds else [])
+    for t in appliers + others:
         t.start()
     for t in appliers:
         t.join()
     stop.set()
-    for t in readers:
+    for t in others:
         t.join()
     assert not errors, errors
+    if rounds:
+        assert sum(checkpointed) > 0
+        # What is on disk is on disk: a release or a round put every
+        # file there, and a last round finds nothing left to open.
+        small.checkpoint_round()
+        assert (small.synced[:G] == small.applied[:G]).all()
+        opens = small.opens
+        assert small.checkpoint_round() == 0 and small.opens == opens
     # Never more descriptors than the budget's, but for what a thread
     # holds in passing: the listing of /proc/self/fd that counts them,
     # and SQLite's own moment with a directory as it makes or drops a
@@ -554,6 +581,8 @@ def test_store_at_scale_counts_its_slots_and_looks_at_open_handles_only(
     assert len(store._entries) == G and len(store._open) == BUDGET
     assert store.open_handles() == BUDGET
     assert store.evictions == 3 * G - BUDGET
+    assert store.uses == 3 * G
+    assert store.misses == 2 * G        # every use after the first pass
     assert (store.applied == 3).all()
     assert sum(e.sm.is_open for e in store._entries.values()) == BUDGET
     # A release that raises: the victim's slot is given back all the
@@ -1147,6 +1176,66 @@ def test_compaction_readers_on_a_pair_of_scrapes(monkeypatch, name, want,
         assert reader.read(bare[0], bare[1], {}, None) is None
 
 
+def store_scrape(k, has=True):
+    """The engine's document after k rounds of the store and the
+    compaction round; `has=False` is a program without their counters."""
+    doc = {"ticks": 100 * k,
+           "stages": {"compact": {"checkpoint": {
+               "total_ms": 900.0 * k, "n": k, "max_ms": 1000.0}}},
+           "compact": {"sweeps": k, "floors_advanced": 90 * k},
+           "sm": {"opens": 300 * k, "closes": 280 * k,
+                  "evictions": 280 * k, "open_handles": 6496}}
+    if has:
+        doc["stages"]["compact"]["file"] = {
+            "total_ms": 4000.0 * k, "n": 250 * k, "max_ms": 90.0}
+        doc["stages"]["sm"] = {
+            "miss": {"total_ms": 600.0 * k, "n": 300 * k, "max_ms": 40.0},
+            "release": {"total_ms": 5600.0 * k, "n": 280 * k,
+                        "max_ms": 60.0}}
+        doc["compact"].update(rounds=k, files=250 * k)
+        doc["sm"].update(uses=1000 * k, misses=300 * k)
+    return {"t": 9.0 * k, "engine": doc, "workers": [doc]}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("sm_miss_pct", 30.0),
+    ("sm_miss_ms", 2.0),
+    ("sm_release_ms", 20.0),
+    ("compact_files_per_round", 250.0),
+    ("compact_file_ms", 16.0),
+])
+def test_store_and_round_readers_on_a_pair_of_scrapes(monkeypatch, name,
+                                                      want):
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmarks"))
+    reader = importlib.import_module("layers." + name)
+    before, after = store_scrape(2), store_scrape(6)
+    assert reader.read(before, after, {}, None) == pytest.approx(want)
+    # Nothing used, missed, released or put on disk in the window.
+    assert reader.read(after, after, {}, None) is None
+    # A program without the counters (the parent commit): silent.
+    old = [store_scrape(k, has=False) for k in (2, 6)]
+    assert reader.read(old[0], old[1], {}, None) is None
+
+
+def test_store_and_round_readers_are_in_the_manifest_for_their_cells():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    new = "kv0-10ksplits-resume"
+    for name, cells in (
+            ("sm_miss_pct", [new]), ("sm_miss_ms", [new]),
+            ("sm_release_ms", [new]),
+            ("compact_files_per_round", [new, "ycsb-a-10kgroups-resume"]),
+            ("compact_file_ms", [new, "ycsb-a-10kgroups-resume"])):
+        m = by_name[name]
+        assert m["workloads"] == cells, name
+        assert m["moves"] == "write_p50_ms" and \
+            m["source"] == "program_counter", name
+        assert m["layer"] == by_name["compact_checkpoint_ms"]["layer"]
+        assert os.path.exists(os.path.join(
+            REPO, "benchmarks", "layers", name + ".py"))
+
+
 def test_compaction_readers_are_in_the_manifest_for_their_cells():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
@@ -1154,10 +1243,15 @@ def test_compaction_readers_are_in_the_manifest_for_their_cells():
     cell = "ycsb-a-10kgroups-resume"
     every = [w["name"] for w in manifest["workloads"]]
     assert cell in every        # later cells are appended after it
-    for name in ("compact_sweep_ms", "compact_floors_per_sweep",
-                 "wal_segments_unlinked_per_sweep", "wal_segments_pinned",
-                 "wal_disk_mb", "compact_checkpoint_ms"):
+    for name in ("compact_sweep_ms", "compact_checkpoint_ms"):
         assert by_name[name]["workloads"] == [cell], name
+    # The long-lived kv0 cell sweeps too; it reports `setup_s`, not
+    # `write_p95_ms`.
+    for name in ("compact_floors_per_sweep",
+                 "wal_segments_unlinked_per_sweep", "wal_segments_pinned",
+                 "wal_disk_mb"):
+        assert by_name[name]["workloads"] == [cell, "kv0-10ksplits-resume"], \
+            name
     for name in ("sm_open_handles", "sm_evictions"):
         assert by_name[name]["workloads"] == every, name
     for name in ("read_p50_ms", "read_queue_ms", "read_wait_ms",

@@ -150,10 +150,12 @@ def test_metrics_document_holds_the_new_keys(served):
                                "mirror_rows", "mirror_fallback_rows",
                                "mirror_skipped_rows", "segments_unlinked",
                                "segments_pinned", "disk_bytes"}
-    assert set(doc["compact"]) == {"sweeps", "floors_advanced"}
-    assert set(doc["stages"]["compact"]) == {"sweep", "checkpoint"}
+    assert set(doc["compact"]) == {"sweeps", "floors_advanced", "rounds",
+                                   "files"}
+    assert set(doc["stages"]["compact"]) == {"sweep", "checkpoint", "file"}
     assert set(doc["sm"]) == {"opens", "closes", "evictions",
-                              "open_handles"}
+                              "open_handles", "uses", "misses"}
+    assert set(doc["stages"]["sm"]) == {"miss", "release"}
     assert set(doc["stages"]["publish"]) == {"queue"}
     assert "mesh_put" not in doc["phase_profile"]   # the mesh's alone
     assert {"launch", "readback", "wal_plan", "wal_append",
