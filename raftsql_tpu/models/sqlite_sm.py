@@ -39,21 +39,28 @@ def is_select(query: str) -> bool:
     return len(tokens) > 0 and tokens[0].upper() == "SELECT"
 
 
+def _handle(conn: sqlite3.Connection):
+    """The word after the head of `conn`, which CPython's connection
+    object keeps its `sqlite3 *db` in (Modules/_sqlite/connection.h; the
+    module has no accessor for it).  Only to be read where the library
+    loaded (`load_native_apply`), which it does only on an interpreter
+    whose layout was looked at (native/build.py `APPLY_TESTED_ON`):
+    asking the library about a word that is no handle is a wild
+    dereference, a crash, not a counted fallback."""
+    return ctypes.c_void_p.from_address(
+        id(conn) + object.__basicsize__).value
+
+
 def _borrow(conn: sqlite3.Connection, path: str):
     """native/apply.cc's `apply_txn` bound to the `sqlite3*` inside
-    `conn`, so that a batch's transaction runs on the connection's OWN
-    handle (a second connection would double the descriptors
-    models/store.py budgets by, and make every query re-read the pages
-    a write changed); None where the handle cannot be had or does not
-    prove itself, and the machine then stays on the Python loop.
+    `conn`, and the file name the library gave for that handle, so that
+    a batch's transaction runs on the connection's OWN handle (a second
+    connection would double the descriptors models/store.py budgets
+    by, and make every query re-read the pages a write changed); None
+    where the handle cannot be had or does not prove itself, and the
+    machine then stays on the Python loop.
 
-    CPython's connection object is `PyObject_HEAD` followed by
-    `sqlite3 *db` (Modules/_sqlite/connection.h), and the module has no
-    accessor for it.  The word is read only on an interpreter whose
-    layout was looked at (the library loads on no other: native/build.py
-    `APPLY_TESTED_ON`), because asking the library about a word that is
-    no handle is a wild dereference: a crash, not a counted fallback.
-    What is read there is then believed only if the library, asked
+    The word `_handle` reads is believed only if the library, asked
     through it for the main database's file, names this machine's file,
     outside a transaction."""
     if path == ":memory:" or type(conn) is not sqlite3.Connection:
@@ -61,15 +68,14 @@ def _borrow(conn: sqlite3.Connection, path: str):
     lib = load_native_apply()
     if lib is None:
         return None
-    db = ctypes.c_void_p.from_address(
-        id(conn) + object.__basicsize__).value
+    db = _handle(conn)
     if not db:
         return None
     try:
         name = lib.apply_db_filename(db)
         if name and os.path.samefile(name, path) \
                 and not conn.in_transaction:
-            return functools.partial(lib.apply_txn, db)
+            return functools.partial(lib.apply_txn, db), name
     except OSError:
         pass
     return None
@@ -113,6 +119,10 @@ class SQLiteStateMachine:
         # that fell back to the Python loop counts as that loop's):
         # runtime/db.py reads it after its call.
         self.last_native = False
+        # The file name the library gave for this machine's last handle
+        # that `_borrow` verified, None where the last was not: a reopen
+        # takes a new handle naming it as verified (`_reconnect`).
+        self._verified: Optional[bytes] = None
         self._connect()
         self._lock = threading.Lock()
         self._applied = 0
@@ -150,20 +160,54 @@ class SQLiteStateMachine:
             index `checkpoint()` has put on disk.
         A file this machine made or opened is in WAL mode for good (the
         file says so), so `reopening` it asks only for the syncs."""
+        self._setup(self._open(), reopening)
+
+    def _open(self) -> sqlite3.Connection:
         conn = sqlite3.connect(self.path, check_same_thread=False)
         conn.isolation_level = None
+        return conn
+
+    def _pragmas(self, reopening: bool) -> tuple:
+        if not self.has_durable_snapshot:
+            return ("PRAGMA journal_mode=MEMORY", "PRAGMA synchronous=OFF")
+        if reopening:
+            return ("PRAGMA synchronous=NORMAL",)
+        return ("PRAGMA journal_mode=WAL", "PRAGMA synchronous=NORMAL")
+
+    def _setup(self, conn: sqlite3.Connection, reopening: bool) -> None:
+        """`_connect`'s work on the new connection through the module."""
         try:
-            if self.has_durable_snapshot:
-                if not reopening:
-                    conn.execute("PRAGMA journal_mode=WAL")
-                conn.execute("PRAGMA synchronous=NORMAL")
-            else:
-                conn.execute("PRAGMA journal_mode=MEMORY")
-                conn.execute("PRAGMA synchronous=OFF")
+            for pragma in self._pragmas(reopening):
+                conn.execute(pragma)
         except sqlite3.Error:          # pragma: no cover - pragma support
             pass
         self._conn = conn
-        self._txn = _borrow(conn, self.path)
+        self._txn, self._verified = _borrow(conn, self.path) or (None, None)
+
+    def _reconnect(self) -> Optional[int]:
+        """`_connect(reopening=True)` and, in resume mode, the applied
+        index on file, in ONE native call on the new connection's own
+        handle (native/apply.cc `apply_reopen`) where this machine's
+        last handle was verified: the new one must name the same file,
+        outside a transaction, so the `stat`s of `_borrow` are not made
+        again.  The index read (0 in parity mode), or None where the
+        module did the set-up and the caller reads the index there."""
+        lib = load_native_apply() if self._verified else None
+        if lib is None:
+            self._connect(reopening=True)
+            return None
+        conn = self._open()
+        db = _handle(conn)
+        on_file = ctypes.c_longlong()
+        if db and not lib.apply_reopen(
+                db, self._verified,
+                "; ".join(self._pragmas(True)).encode(), int(self.resume),
+                ctypes.byref(on_file)):
+            self._conn = conn
+            self._txn = functools.partial(lib.apply_txn, db)
+            return on_file.value
+        self._setup(conn, reopening=True)
+        return None
 
     def _disconnect(self) -> None:
         """Close the connection; the borrowed handle dies with it."""
@@ -274,21 +318,25 @@ class SQLiteStateMachine:
                 sm._lock.release()
         return out
 
-    def reopen(self) -> None:
-        """Connect again to the file `release` left.  Never deletes:
+    def reopen(self) -> bool:
+        """Connect again to the file `release` left; True where one
+        native call did the set-up (`_reconnect`).  Never deletes:
         parity mode's delete-at-boot is the constructor's.  In resume
         mode the file's own `_raft_meta` must say what this machine
         remembers, or the file is not the one that was released."""
         with self._lock:
             if self._conn is not None:
-                return
-            self._connect(reopening=True)
+                return False
+            on_file = self._reconnect()
+            native = on_file is not None
             if self.resume:
-                on_file = self._applied_on_file()
+                if not native:
+                    on_file = self._applied_on_file()
                 if on_file != self._applied:
                     raise RuntimeError(
                         f"{self.path}: applied index {on_file} on file, "
                         f"{self._applied} remembered at release")
+            return native
 
     def apply(self, command: str, index: int = 0) -> Optional[Exception]:
         return self.apply_batch([(command, index)])[0]

@@ -142,13 +142,18 @@ class StateMachineStore:
         # made says (it holds descriptors and can be released).
         self._counted = False
         # obs/prof.py's profiler, where the owner has one (runtime/db.py):
-        # the stages `sm.miss`, `sm.release` and `compact.file`.
+        # the stages `sm.miss`, `sm.reopen`, `sm.release` and
+        # `compact.file`.
         self.prof = None
         self.opens = 0
         self.closes = 0
         self.evictions = 0
         self.uses = 0
         self.misses = 0
+        # The misses' reopens by the arm that did the set-up: one native
+        # call (a machine's `reopen` says True) or the module.
+        self.native_reopens = 0
+        self.python_reopens = 0
 
     # -- the one way to a state machine ---------------------------------
 
@@ -284,6 +289,7 @@ class StateMachineStore:
                 self.misses += 1
                 break                           # ours to reopen
         took = False
+        reopen_s, native = None, False     # None: no reopen
         try:
             self._take_slot()
             took = True
@@ -298,10 +304,10 @@ class StateMachineStore:
                         self._budget = handle_budget(
                             files if self._counted else 0)
                 e.sm = sm
-                missed = False
             else:
-                e.sm.reopen()
-                missed = True
+                t1 = time.monotonic()
+                native = e.sm.reopen() is True
+                reopen_s = time.monotonic() - t1
         except BaseException:
             with self._cv:
                 if took:
@@ -317,10 +323,16 @@ class StateMachineStore:
             self._open[group] = e
             e.busy = False
             self.opens += 1
+            if reopen_s is not None:
+                if native:
+                    self.native_reopens += 1
+                else:
+                    self.python_reopens += 1
             self._note_applied(group, e.sm)
             self._cv.notify_all()
-        if missed and self.prof is not None:
-            self.prof.stage("sm.miss", time.monotonic() - t0)
+        if reopen_s is not None and self.prof is not None:
+            self.prof.stage_many((("sm.miss", time.monotonic() - t0),
+                                  ("sm.reopen", reopen_s)))
         return e
 
     def _take_slot(self) -> None:

@@ -36,6 +36,11 @@
 // through the module gives the interpreter up and takes it back for
 // each file on each of a pool's threads.
 //
+// apply_reopen() is what a released machine's reopen asks of a fresh
+// connection (its pragmas, and in resume mode the applied index on
+// file) in one call, where through the module it is a prepare, step and
+// reset a statement, each a wait for the interpreter.
+//
 // ABI: plain C, consumed via ctypes (no pybind11 in this environment).
 
 #include <atomic>
@@ -63,6 +68,8 @@ int sqlite3_get_autocommit(sqlite3*);
 const char* sqlite3_db_filename(sqlite3*, const char*);
 const char* sqlite3_libversion(void);
 int sqlite3_wal_checkpoint_v2(sqlite3*, const char*, int, int*, int*);
+int sqlite3_column_type(sqlite3_stmt*, int);
+long long sqlite3_column_int64(sqlite3_stmt*, int);
 
 }  // extern "C"
 
@@ -70,6 +77,8 @@ namespace {
 
 constexpr int kOk = 0;      // SQLITE_OK
 constexpr int kDone = 101;  // SQLITE_DONE
+constexpr int kRow = 100;   // SQLITE_ROW
+constexpr int kInteger = 1;  // SQLITE_INTEGER
 constexpr int kCheckpointFull = 1;  // SQLITE_CHECKPOINT_FULL
 
 bool run(sqlite3* db, const char* sql) {
@@ -91,6 +100,25 @@ bool apply_one(sqlite3* db, const char* sql, int len) {
   for (const char* p = tail; ok && p < sql + len; ++p)
     ok = *p == ' ' || *p == '\t' || *p == '\n' || *p == '\r' || *p == '\f';
   ok = ok && sqlite3_step(stmt) == kDone;
+  return (sqlite3_finalize(stmt) == kOk) && ok;
+}
+
+// The applied index `_raft_meta` holds: 0 where it holds none, false
+// where the table cannot be read or the value is no integer.
+bool applied_on_file(sqlite3* db, long long* applied) {
+  sqlite3_stmt* stmt = nullptr;
+  if (sqlite3_prepare_v2(db,
+                         "SELECT v FROM _raft_meta WHERE k='applied_index'",
+                         -1, &stmt, nullptr) != kOk ||
+      stmt == nullptr)
+    return false;
+  int rc = sqlite3_step(stmt);
+  bool ok = rc == kDone;
+  *applied = 0;
+  if (rc == kRow && sqlite3_column_type(stmt, 0) == kInteger) {
+    *applied = sqlite3_column_int64(stmt, 0);
+    ok = sqlite3_step(stmt) == kDone;
+  }
   return (sqlite3_finalize(stmt) == kOk) && ok;
 }
 
@@ -136,6 +164,23 @@ int apply_txn(sqlite3* db, int n, const char* const* cmds, const int* lens,
   if (ok) return 0;
   if (!sqlite3_get_autocommit(db)) run(db, "ROLLBACK");
   return at + 1;
+}
+
+// A fresh connection's set-up for a machine that reopens its file: 0
+// where `db` names the file `expect` (the name an earlier handle of the
+// machine was verified by), is outside a transaction, ran `pragmas`, and, where
+// `read_meta` is not 0, gave the applied index on file in *applied.
+// Non-zero: the first of those that did not hold, and the caller does
+// the set-up through the module (the pragmas are idempotent).
+int apply_reopen(sqlite3* db, const char* expect, const char* pragmas,
+                 int read_meta, long long* applied) {
+  const char* name = sqlite3_db_filename(db, "main");
+  if (name == nullptr || std::strcmp(name, expect) != 0) return 1;
+  if (!sqlite3_get_autocommit(db)) return 2;
+  if (!run(db, pragmas)) return 3;
+  *applied = 0;
+  if (read_meta && !applied_on_file(db, applied)) return 4;
+  return 0;
 }
 
 // Checkpoint each of `n` handles as `PRAGMA wal_checkpoint(FULL)` does

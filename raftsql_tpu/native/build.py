@@ -286,6 +286,10 @@ def load_native_apply():
                 ctypes.POINTER(ctypes.c_void_p), ctypes.c_int, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_double)]
+            lib.apply_reopen.restype = ctypes.c_int
+            lib.apply_reopen.argtypes = [
+                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
+                ctypes.c_int, ctypes.POINTER(ctypes.c_longlong)]
             if lib.apply_sqlite_id() != theirs:
                 raise OSError("bound to another SQLite than _sqlite3's")
         except (ImportError, AttributeError, OSError) as e:

@@ -77,9 +77,9 @@ one count() a round).
 GAUGES — values that are read where they live when a document is made
 (`gauge_fn`): `wal.disk_bytes` and `wal.segments_pinned` (the WALs keep
 both as they rotate and unlink), and the state-machine store's
-`sm.opens`, `sm.closes`, `sm.evictions`, `sm.open_handles`
-and `sm.uses`, `sm.misses` (models/store.py).  They sit beside the
-counters in the document.
+`sm.opens`, `sm.closes`, `sm.evictions`, `sm.open_handles`,
+`sm.uses`, `sm.misses` and `sm.native_reopens`, `sm.python_reopens`
+(models/store.py).  They sit beside the counters in the document.
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
@@ -143,7 +143,7 @@ _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
                  "put.apply_batch", "get.queue", "get.wait", "get.sql",
                  "publish.queue", "compact.sweep", "compact.checkpoint",
-                 "compact.file", "sm.miss", "sm.release")
+                 "compact.file", "sm.miss", "sm.reopen", "sm.release")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
                  "get.ring_rtt")
 ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
@@ -162,7 +162,7 @@ ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
 # Read where they live, at export (gauge_fn); 0 until somebody says.
 ENGINE_GAUGES = ("wal.disk_bytes", "wal.segments_pinned", "sm.opens",
                  "sm.closes", "sm.evictions", "sm.open_handles", "sm.uses",
-                 "sm.misses")
+                 "sm.misses", "sm.native_reopens", "sm.python_reopens")
 
 
 # Appends a deque may hold before the appending thread folds them in

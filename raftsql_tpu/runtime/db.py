@@ -292,6 +292,8 @@ class RaftDB:
             prof.gauge_fn("sm.open_handles", store.open_handles)
             prof.gauge_fn("sm.uses", lambda: store.uses)
             prof.gauge_fn("sm.misses", lambda: store.misses)
+            prof.gauge_fn("sm.native_reopens", lambda: store.native_reopens)
+            prof.gauge_fn("sm.python_reopens", lambda: store.python_reopens)
             store.prof = prof
         if resume:
             # Full state transfer for followers beyond the compaction

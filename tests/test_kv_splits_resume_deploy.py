@@ -298,6 +298,8 @@ def test_served_node_evicts_sweeps_and_matches_the_reference(written):
     # or a round's batch may still be on its way at the scrape.
     assert 0 < sm["evictions"] - st["sm"]["release"]["n"] + 4 <= 4 + 4
     assert st["sm"]["miss"]["n"] == sm["misses"]
+    assert st["sm"]["reopen"]["n"] == sm["misses"]
+    assert sm["native_reopens"] + sm["python_reopens"] == sm["misses"]
     # Rounds put files on disk and sweeps moved floors.
     assert after["compact"]["rounds"] > 1
     assert 0 < after["compact"]["files"] <= st["compact"]["file"]["n"]
@@ -306,11 +308,14 @@ def test_served_node_evicts_sweeps_and_matches_the_reference(written):
         - before["compact"]["floors_advanced"] > 0
     # The benchmark's readers find what they read.
     for name in ("sm_miss_pct", "sm_miss_ms", "sm_release_ms",
-                 "compact_files_per_round", "compact_file_ms",
+                 "sm_reopen_ms", "compact_files_per_round", "compact_file_ms",
                  "sm_evictions", "compact_floors_per_sweep"):
         reader = importlib.import_module("layers." + name)
         value = reader.read({"engine": before}, {"engine": after}, {}, None)
         assert value is not None and value > 0, name
+    share = importlib.import_module("layers.sm_reopen_native_pct").read(
+        {"engine": before}, {"engine": after}, {}, None)
+    assert share is not None and 0 <= share <= 100
 
 
 def test_a_restart_answers_the_same(written):
@@ -430,6 +435,15 @@ def test_a_round_over_released_groups_opens_nothing(tmp_path, monkeypatch,
     assert store.checkpoint_round() == 0 and store.opens == opens
     for g in range(G):
         assert on_disk(str(tmp_path / f"g{g}.db")) == 3
+    # Every miss reopened on the arm of the machine's first open: one
+    # native call where that handle was verified.
+    assert store.misses > 0
+    if native and sqlite_sm.load_native_apply() is not None:
+        assert (store.native_reopens, store.python_reopens) == \
+            (store.misses, 0)
+    else:
+        assert (store.native_reopens, store.python_reopens) == \
+            (0, store.misses)
     store.close()
 
 

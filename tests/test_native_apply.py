@@ -4,8 +4,11 @@ for: every case runs the same runs on a file-backed machine of each arm
 (`native`: the borrowed handle; `python`: RAFTSQL_TPU_NATIVE=0, the tree
 as it was) and on a `:memory:` machine, which is always the Python loop,
 and the three must agree on every error (class and text), every table
-and the applied index.  Then which arm committed what, the counters
-`_apply_run` hands the profiler, their reader, and /healthz.
+and the applied index.  A reopen between runs sets the new connection up
+in one native call on the native arm (`apply_reopen`) and through the
+module on the other.  Then which arm committed what, the counters
+`_apply_run` and the store hand the profiler, their readers, and
+/healthz.
 
 "Agree" is by construction for whatever the native call gives back, so
 the cases that matter are the commands a bare prepare-and-step would
@@ -21,6 +24,7 @@ import pytest
 
 from raftsql_tpu.models import sqlite_sm
 from raftsql_tpu.models.sqlite_sm import SQLiteStateMachine
+from raftsql_tpu.models.store import StateMachineStore
 from raftsql_tpu.native import build
 from raftsql_tpu.native.build import load_native_apply
 
@@ -53,8 +57,10 @@ def indexed(*runs):
 
 
 def bounce(sm):
+    """Release and reopen; whether one native call set the new
+    connection up."""
     sm.release()
-    sm.reopen()
+    return sm.reopen()
 
 
 # name -> (resume, runs of (command, index), runs the native call commits
@@ -151,6 +157,15 @@ CASES = {
         False, indexed([T], [ins(1)], [ins(2), ins(3)]), [1, 1, 1], bounce),
     "resume: release() then reopen() between runs": (
         True, indexed([T], [ins(1)], [ins(2), ins(3)]), [1, 1, 1], bounce),
+    "release() then reopen() after a failed run": (
+        False, indexed([T], [ins(1), ins(1, "'again'")], [ins(2)]),
+        [1, 0, 1], bounce),
+    "resume: release() then reopen() after a failed run": (
+        True, indexed([T], [ins(1), ins(1, "'again'")], [ins(2)]),
+        [1, 0, 1], bounce),
+    "resume: release() then reopen() between every run": (
+        True, indexed([T], [ins(1)], [ins(2)], [ins(3), ins(4)]),
+        [1, 1, 1, 1], bounce),
 }
 
 
@@ -162,15 +177,16 @@ def dump(sm):
 
 
 def drive(sm, runs, between):
-    """Each run's outcomes, and whether the native call committed it."""
-    errs, native = [], []
+    """Each run's outcomes, whether the native call committed it, and
+    what each `between` said."""
+    errs, native, said = [], [], []
     for i, run in enumerate(runs):
         if i and between is not None and sm.path != ":memory:":
-            between(sm)
+            said.append(between(sm))
         errs.append([None if e is None else (type(e), str(e))
                      for e in sm.apply_batch(run)])
         native.append(int(sm.last_native))
-    return errs, native
+    return errs, native, said
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -181,8 +197,8 @@ def test_both_arms_and_the_plain_loop_agree(arm, case, tmp_path):
     try:
         assert (sm._txn is not None) == (arm == "native")
         assert ref._txn is None
-        got, by_native = drive(sm, runs, between)
-        want, ref_by_native = drive(ref, runs, between)
+        got, by_native, reopened = drive(sm, runs, between)
+        want, ref_by_native, _ = drive(ref, runs, between)
         assert got == want
         assert dump(sm) == dump(ref)
         assert sm.applied_index() == ref.applied_index()
@@ -196,6 +212,9 @@ def test_both_arms_and_the_plain_loop_agree(arm, case, tmp_path):
         assert by_native == (native_runs if arm == "native"
                              else [0] * len(runs))
         assert ref_by_native == [0] * len(runs)
+        # A reopen's set-up: one native call wherever the machine's
+        # handle was verified, the module everywhere else.
+        assert reopened == [arm == "native"] * len(reopened)
         assert not sm._conn.in_transaction
         # And the file is what another connection finds.
         sm.close()
@@ -353,6 +372,137 @@ def test_memory_never_goes_native(arm):
             sm.close()
 
 
+# -- a reopen in one call ---------------------------------------------------
+
+@pytest.mark.parametrize("resume", [False, True], ids=["parity", "resume"])
+def test_a_reopen_sets_the_connection_up_as_the_first_open_did(
+        arm, tmp_path, resume):
+    """A close forgets the connection's pragmas and the file keeps its
+    journal mode: reopened on either arm, a parity machine is back on a
+    memory journal without syncs, a resume machine on WAL with NORMAL
+    syncs, and goes on applying on its arm."""
+    sm = SQLiteStateMachine(str(tmp_path / "g.db"), resume=resume)
+    want = [("wal",), (1,)] if resume else [("memory",), (0,)]
+    try:
+        assert sm.apply_batch([(T, 1), (ins(1), 2)]) == [None, None]
+        assert [sm.rows("PRAGMA journal_mode")[0],
+                sm.rows("PRAGMA synchronous")[0]] == want
+        for k in (2, 3):
+            sm.release()
+            assert sm.reopen() is (arm == "native")
+            assert sm.reopen() is False             # already open
+            assert [sm.rows("PRAGMA journal_mode")[0],
+                    sm.rows("PRAGMA synchronous")[0]] == want
+            assert sm.apply(ins(k), k + 1) is None
+            assert sm.last_native is (arm == "native")
+        assert sm.applied_index() == 4
+        assert sm.query("SELECT count(*) FROM t") == "|3|\n"
+    finally:
+        sm.close()
+
+
+@pytest.mark.parametrize("change,on_file", [
+    ("UPDATE _raft_meta SET v = 7", 7),
+    ("DELETE FROM _raft_meta", 0),
+])
+def test_a_reopen_whose_raft_meta_differs_still_raises(arm, tmp_path,
+                                                       change, on_file):
+    """The file's `_raft_meta` must say what the machine remembers, on
+    either arm, or the file is not the one that was released."""
+    path = str(tmp_path / "g.db")
+    sm = SQLiteStateMachine(path, resume=True)
+    try:
+        assert sm.apply_batch([(T, 1), (ins(1), 2)]) == [None, None]
+        sm.release()
+        other = sqlite3.connect(path)
+        other.execute(change)
+        other.commit()
+        other.close()
+        with pytest.raises(RuntimeError, match=(
+                f"applied index {on_file} on file, 2 remembered at "
+                "release")):
+            sm.reopen()
+    finally:
+        sm.close()
+
+
+def test_a_handle_naming_another_file_reopens_through_the_module(
+        arm, tmp_path):
+    """The new handle must name the file the verified one named: where
+    it does not, the module sets the connection up, `_borrow` asks the
+    new handle again, and the machine is back on its arm.  The store
+    counts each reopen by the arm that did it."""
+    store = StateMachineStore(lambda g: SQLiteStateMachine(
+        str(tmp_path / f"g{g}.db"), resume=True), 2, budget=1)
+    try:
+        for g in (0, 1):                # 1 releases 0
+            with store.use(g) as sm:
+                assert sm.apply_batch([(T, 1), (ins(g), 2)]) == [None, None]
+        zero = store._entries[0].sm
+        assert (zero._verified is not None) == (arm == "native")
+        zero._verified = str(tmp_path / "other.db").encode()
+        with store.use(0) as sm:        # releases 1
+            assert sm._verified != str(tmp_path / "other.db").encode()
+            assert (sm._verified is not None) == (arm == "native")
+            assert sm.apply(ins(5), 3) is None
+            assert sm.last_native is (arm == "native")
+        assert (store.native_reopens, store.python_reopens) == (0, 1)
+        with store.use(1) as sm:
+            assert sm.query("SELECT k FROM t") == "|1|\n"
+        with store.use(0) as sm:
+            assert sm.query("SELECT k FROM t ORDER BY k") == "|0|\n|5|\n"
+        n = 2 if arm == "native" else 0
+        assert (store.native_reopens, store.python_reopens) == (n, 3 - n)
+        assert store.misses == 3
+    finally:
+        store.close()
+
+
+def test_memory_reopens_through_the_module(arm):
+    sm = SQLiteStateMachine(":memory:")
+    try:
+        assert sm.apply(T, 1) is None
+        sm.release()
+        assert sm.reopen() is False
+        assert sm._txn is None and sm._verified is None
+        assert sm.apply(T, 1) is None           # a new, empty database
+    finally:
+        sm.close()
+
+
+@pytest.mark.parametrize("gone", ["RAFTSQL_TPU_NATIVE=0",
+                                  "an untested interpreter"])
+def test_a_library_gone_since_the_first_open_reopens_through_the_module(
+        tmp_path, monkeypatch, gone):
+    """A machine verified at its first open reopens through the module
+    once the library does not load, and is counted for it."""
+    if load_native_apply() is None:
+        pytest.skip("no native apply here")
+    store = StateMachineStore(lambda g: SQLiteStateMachine(
+        str(tmp_path / f"g{g}.db"), resume=True), 2, budget=1)
+    try:
+        for g in (0, 1):
+            with store.use(g) as sm:
+                assert sm._txn is not None
+                assert sm.apply_batch([(T, 1), (ins(g), 2)]) == [None, None]
+        if gone == "RAFTSQL_TPU_NATIVE=0":
+            monkeypatch.setenv("RAFTSQL_TPU_NATIVE", "0")
+        else:
+            monkeypatch.delitem(build._cache, "apply_checked")
+            monkeypatch.setattr(build, "_connection_layout",
+                                lambda: ("cpython", 3, 13, 224))
+        for g in (0, 1):
+            with store.use(g) as sm:
+                assert sm._txn is None and sm._verified is None
+                assert sm.query("SELECT k FROM t") == f"|{g}|\n"
+                assert sm.apply(ins(5), 3) is None
+                assert sm.last_native is False
+        assert (store.native_reopens, store.python_reopens) == (0, 2)
+    finally:
+        store.close()
+        build._cache.pop("apply_checked", None)
+
+
 # -- the counters of a run, their reader, /healthz -------------------------
 
 def test_a_run_counts_its_batches_by_the_arm_that_committed(arm, tmp_path):
@@ -407,3 +557,40 @@ def test_apply_native_pct_on_hand_made_scrapes(monkeypatch, before, after,
                                      for w in manifest["workloads"]]
     assert entry[0]["layer"] == \
         "ack routing + apply (runtime/db.py, models/sqlite_sm.py)"
+
+
+def reopens(native, python, total_ms, has=True):
+    doc = {"sm": {"uses": 1000, "misses": native + python}}
+    if has:
+        doc["sm"].update(native_reopens=native, python_reopens=python)
+        doc["stages"] = {"sm": {"reopen": {
+            "total_ms": total_ms, "n": native + python, "max_ms": 9.0}}}
+    return {"t": 1.0, "engine": doc, "workers": []}
+
+
+@pytest.mark.parametrize("before,after,pct,ms", [
+    ((0, 0, 0.0), (300, 0, 900.0), 100.0, 3.0),
+    ((100, 7, 50.0), (400, 8, 1253.0), pytest.approx(100.0 * 300 / 301),
+     pytest.approx(1203.0 / 301)),
+    ((5, 5, 10.0), (5, 45, 1410.0), 0.0, 35.0),      # the module's
+    ((5, 5, 10.0), (5, 5, 10.0), None, None),        # nothing reopened
+])
+def test_sm_reopen_readers_on_hand_made_scrapes(monkeypatch, before, after,
+                                                pct, ms):
+    monkeypatch.syspath_prepend(BENCH)
+    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    for name, want in (("sm_reopen_native_pct", pct), ("sm_reopen_ms", ms)):
+        reader = importlib.import_module("layers." + name)
+        assert reader.read(reopens(*before), reopens(*after), {},
+                           None) == want
+        # A program from before these readers has neither: silent.
+        assert reader.read(reopens(*before, has=False),
+                           reopens(*after, has=False), {}, None) is None
+        entry = [m for m in manifest["per_layer"] if m["name"] == name]
+        assert len(entry) == 1
+        assert entry[0]["moves"] == "write_p50_ms"
+        assert entry[0]["source"] == "program_counter"
+        assert entry[0]["workloads"] == ["kv0-10ksplits-resume"]
+        assert entry[0]["layer"] == \
+            "ack routing + apply (runtime/db.py, models/sqlite_sm.py)"

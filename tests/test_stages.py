@@ -154,8 +154,9 @@ def test_metrics_document_holds_the_new_keys(served):
                                    "files"}
     assert set(doc["stages"]["compact"]) == {"sweep", "checkpoint", "file"}
     assert set(doc["sm"]) == {"opens", "closes", "evictions",
-                              "open_handles", "uses", "misses"}
-    assert set(doc["stages"]["sm"]) == {"miss", "release"}
+                              "open_handles", "uses", "misses",
+                              "native_reopens", "python_reopens"}
+    assert set(doc["stages"]["sm"]) == {"miss", "reopen", "release"}
     assert set(doc["stages"]["publish"]) == {"queue"}
     assert "mesh_put" not in doc["phase_profile"]   # the mesh's alone
     assert {"launch", "readback", "wal_plan", "wal_append",
