@@ -4,8 +4,9 @@
 
 Steps (benchmarks/README.md has the layout and how to add to it):
 
-  1. raise the open-file limit; fresh scratch directory inside the
-     checkout (the WAL's fsyncs should hit the checkout's disk); free port;
+  1. raise the open-file limit to the hard one; fresh scratch directory
+     inside the checkout (the WAL's fsyncs should hit the checkout's
+     disk); free port;
   2. start the configuration's server as a child in its own session —
      `python -m raftsql_tpu.server.main <argv of the config file>`, or
      with `--trace 1` the same entry function under lib/serve_traced.py;
@@ -117,13 +118,22 @@ def find_cell(name: str) -> dict:
     }
 
 
-def raise_nofile(need: int) -> None:
+def raise_nofile(clients: int) -> int:
+    """Raise the soft open-file limit to the hard one and return it: the
+    limit the engine inherits.  Required is only what this runner and its
+    generators hold, a socket a client and NOFILE_SPARE.  The engine's
+    store budgets its SQLite handles from the limit it inherits, and
+    server/main.py refuses a limit under the store's own least with a
+    sentence that names it."""
+    need = clients + NOFILE_SPARE
     soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
     if hard != resource.RLIM_INFINITY and hard < need:
-        raise RunFailure(f"open-file hard limit {hard} < {need} needed")
+        raise RunFailure(f"open-file hard limit {hard} < {need} needed "
+                         f"({clients} clients + {NOFILE_SPARE} spare)")
     want = hard if hard != resource.RLIM_INFINITY else max(soft, need)
     if soft < want:
         resource.setrlimit(resource.RLIMIT_NOFILE, (want, hard))
+    return max(soft, want)
 
 
 def fs_type(path: str) -> str:
@@ -403,7 +413,7 @@ def run(args, found: dict, scratch: str, live: dict) -> int:
     checker = importlib.import_module("ops." + traffic["checker"])
     peaks = load_json(os.path.join(HERE, "lib", "peaks.json"))["peaks"]
     traced = args.trace == 1
-    raise_nofile(config["groups"] + traffic["clients"] + NOFILE_SPARE)
+    nofile = raise_nofile(traffic["clients"])
     say("cell", f"{found['cell']['name']} = {config['name']} x "
         f"{traffic['name']}, seed {args.seed}, {args.seconds} s, "
         f"trace {args.trace}")
@@ -430,7 +440,8 @@ def run(args, found: dict, scratch: str, live: dict) -> int:
                               ("platform", "device_kind", "count", "jax")}))
     say("boot", f"/healthz up {health['healthz_up_s']} s, all "
         f"{config['groups']} groups led {health['all_led_s']} s after spawn; "
-        f"compile cache {json.dumps(device['compile_cache'])}")
+        f"compile cache {json.dumps(device['compile_cache'])}; open-file "
+        f"limit {nofile}")
 
     conns = p["load_connections"]
     require_204(schema, run_list(gens, engine.port, schema, conns, "schema"),

@@ -11,16 +11,17 @@ import pytest
 from test_rehearsal import MANIFEST, ROOT, names as reported
 
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
+# Not `write_p95_ms` (20.8% over fourteen seeds) and not `ops_per_s`:
+# 1,000 closed-loop clients with nothing saturated behind them make it
+# 1,000 / MEAN latency, whose tail share swings run by run (pairs of one
+# commit: 18-37% against a bound of 15%; PERF.md sections 2 and 6).  The
+# long-lived variant drives the same clients.
+KV0_CELLS = ("kv0-10ksplits", "kv0-10ksplits-resume")
 
 
-def test_kv0_is_judged_on_its_median_and_its_setup():
-    # Not `write_p95_ms` (PR 34: 20.8% over fourteen seeds) and, since
-    # PR 38, not `ops_per_s`: 1,000 closed-loop clients with nothing
-    # saturated behind them make it 1,000 / MEAN latency, whose tail
-    # share swings run by run (the driver's pairs: 18-37% against a
-    # bound of 15%; PERF.md sections 2 and 6).
-    assert reported("end_to_end", "kv0-10ksplits") == {"write_p50_ms",
-                                                       "setup_s"}
+@pytest.mark.parametrize("cell", KV0_CELLS)
+def test_kv0_is_judged_on_its_median_and_its_setup(cell):
+    assert reported("end_to_end", cell) == {"write_p50_ms", "setup_s"}
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -50,7 +51,7 @@ def test_a_listed_per_layer_metric_moves_what_its_cells_report():
 
 def test_the_other_cells_keep_ops_per_s_and_every_bound_is_as_it_was():
     for cell in CELLS:
-        if cell != "kv0-10ksplits":
+        if cell not in KV0_CELLS:
             assert "ops_per_s" in reported("end_to_end", cell)
     assert {m["name"]: m["bound"] for m in MANIFEST["end_to_end"]} == {
         "ops_per_s": 0.15, "write_p50_ms": 0.2, "write_p95_ms": 0.25,
