@@ -112,9 +112,9 @@ OWNERSHIP_REQUIRED = {
 # ---------------------------------------------------------------------
 FAILCLOSED_REQUIRED = {
     "runtime/shm.py": {
-        "fail-closed": ["_snapshot_table", "_catch_up", "try_read",
+        "fail-closed": ["_snapshot_row", "_catch_up", "try_read",
                         "leader_of"],
-        "seqlock": ["_snapshot_table", "_publish_locked"],
+        "seqlock": ["_snapshot_row", "_publish_locked"],
     },
     # The router flip is the one place a reshard can lose acked writes
     # (flip before the copy fence) or serve a moved key from the wrong

@@ -54,8 +54,14 @@ ran it timed it; then `stages.compact.sweep`: the tick thread's sweep,
 one sample each a sweep), and of the state-machine store
 (models/store.py: `stages.sm.miss`, a use that found its handle
 closed, from the pin to the file open again; `stages.sm.release`, one
-victim released, on the thread that needed its slot).  No ring, no
-percentile, no per-request object.
+victim released, on the thread that needed its slot), and of the
+serving plane (runtime/ring.py: `stages.shm.refresh`, one restamp of
+the shm table's commit/leader/lease columns on the engine's refresh
+thread; `stages.doc.render`, one document the engine made for a worker,
+/healthz or /metrics among them; `worker_stages.get.shm_snapshot`, a
+worker's seqlock read of the header and its one row inside
+runtime/shm.py `try_read`).  No ring, no percentile, no per-request
+object.
 
 COUNTERS — plain cumulative integers (`dispatch.steps`: the consensus
 steps the launches carried, one tick a launch; `intake.*`: what the
@@ -72,14 +78,20 @@ the client-facing peer to deliver, one count a dispatch a worker;
 `compact.sweeps`, `compact.floors_advanced` (groups x peers whose floor
 a sweep moved) and `wal.segments_unlinked`, one count() a sweep;
 `compact.rounds` and `compact.files`, the files a round put on disk,
-one count() a round).
+one count() a round; `doc.records`, the completion-ring records the
+documents' replies took, one count() a reply, so a document larger than
+the ring reads as more records than `stages.doc.render.n`).
 
 GAUGES — values that are read where they live when a document is made
 (`gauge_fn`): `wal.disk_bytes` and `wal.segments_pinned` (the WALs keep
 both as they rotate and unlink), and the state-machine store's
 `sm.opens`, `sm.closes`, `sm.evictions`, `sm.open_handles`,
 `sm.uses`, `sm.misses` and `sm.native_reopens`, `sm.python_reopens`
-(models/store.py).  They sit beside the counters in the document.
+(models/store.py), and two seconds on the engine's clock from the host
+plane's constructor: `boot.replay_s` to the end of the WAL replay's
+apply (runtime/db.py) and `boot.all_led_s` to the first tick at which
+every group had a leader (0 until then).  They sit beside the counters
+in the document; a float gauge keeps three decimals.
 
 ON THE PROFILER'S CLOCK: while a JAX profiler session runs, the engine
 opens each LEAF phase of the tick (pop, mesh_put, launch, readback,
@@ -143,9 +155,10 @@ _TICK_PHASES = ("pop", "dispatch", "wal_write", "fsync", "publish")
 ENGINE_STAGES = ("put.engine", "put.propose_commit", "put.apply",
                  "put.apply_batch", "get.queue", "get.wait", "get.sql",
                  "publish.queue", "compact.sweep", "compact.checkpoint",
-                 "compact.file", "sm.miss", "sm.reopen", "sm.release")
+                 "compact.file", "sm.miss", "sm.reopen", "sm.release",
+                 "shm.refresh", "doc.render")
 WORKER_STAGES = ("put.edge_in", "put.ring_rtt", "put.edge_out",
-                 "get.ring_rtt")
+                 "get.ring_rtt", "get.shm_snapshot")
 ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
                    "intake.accepted", "intake.groups",
                    "intake.committed_in_dispatch", "wal.records",
@@ -158,11 +171,12 @@ ENGINE_COUNTERS = ("dispatch.steps", "intake.backlog", "intake.offered",
                    "apply.python_txns",
                    "compact.sweeps", "compact.floors_advanced",
                    "wal.segments_unlinked", "compact.rounds",
-                   "compact.files")
+                   "compact.files", "doc.records")
 # Read where they live, at export (gauge_fn); 0 until somebody says.
 ENGINE_GAUGES = ("wal.disk_bytes", "wal.segments_pinned", "sm.opens",
                  "sm.closes", "sm.evictions", "sm.open_handles", "sm.uses",
-                 "sm.misses", "sm.native_reopens", "sm.python_reopens")
+                 "sm.misses", "sm.native_reopens", "sm.python_reopens",
+                 "boot.replay_s", "boot.all_led_s")
 
 
 # Appends a deque may hold before the appending thread folds them in
@@ -354,7 +368,9 @@ class TickPhaseProfiler(StageSet):
             flat[name] = 0
         for name, fn in list(self._gauges.items()):
             try:
-                flat[name] = int(fn())
+                v = fn()
+                flat[name] = round(v, 3) if isinstance(v, float) \
+                    else int(v)
             except Exception:                           # noqa: BLE001
                 pass            # a gauge must never break the scrape
         return _nest(flat)

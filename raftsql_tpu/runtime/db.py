@@ -252,6 +252,7 @@ class RaftDB:
         indexes are read off the files here, before anything asks
         (a group whose log was compacted away gets no replay that
         would open it)."""
+        t_built = time.monotonic()
         self.pipe = pipe
         self.num_groups = num_groups
         self.listener = listener            # queue-like or None
@@ -355,6 +356,12 @@ class RaftDB:
         # Synchronous replay consumption (db.go:40): apply until the
         # sentinel so reads see the replayed state before we return.
         self._read_commits(replay=True)
+        if prof is not None:
+            # boot.replay_s: the node's constructor (where the WAL was
+            # read) to here, the replay applied.
+            replay_s = time.monotonic() - getattr(pipe.node, "t_born",
+                                                  t_built)
+            prof.gauge_fn("boot.replay_s", lambda: replay_s)
         self._reader = threading.Thread(target=self._read_commits,
                                         daemon=True, name="raftdb-reader")
         self._reader.start()
@@ -1170,15 +1177,20 @@ class RaftDB:
         # Routing hints (PR 12, api/client.py front router): per-group
         # remaining lease seconds — a client routes linearizable reads
         # to the node reporting a live lease, writes to the leader.
+        # Columns in bulk where the node keeps them: at 100,000 groups
+        # this document is ~9.6 MB, made on every readiness poll.
         lease_fn = getattr(node, "lease_deadline_s", None)
+        leases_fn = getattr(node, "lease_deadlines_s", None)
         now = time.monotonic()
+        applied = self.store.applied.tolist()
+        leases = leases_fn().tolist() if leases_fn is not None else None
         for g in range(self.num_groups):
             row = groups.get(str(g))
             if row is not None:
-                row["applied"] = self.store.applied_index(g)
+                row["applied"] = applied[g]
                 if lease_fn is not None:
-                    row["lease_s"] = round(
-                        max(lease_fn(g) - now, 0.0), 4)
+                    d = leases[g] if leases is not None else lease_fn(g)
+                    row["lease_s"] = round(max(d - now, 0.0), 4)
         doc = {"id": int(getattr(node, "node_id", 0)),
                "ready": True, "groups": groups}
         # Where this engine runs: the device as JAX reports it plus the

@@ -144,6 +144,10 @@ class ClusterHostPlane:
                  group_commit: Optional[bool] = None,
                  steps: int = 1):
         P, G = cfg.num_peers, cfg.num_groups
+        # The engine's boot clock: boot.replay_s (runtime/db.py) and
+        # boot.all_led_s (tick()) count from here.
+        self.t_born = time.monotonic()
+        self._all_led_s = 0.0
         self.cfg = cfg
         self.metrics = NodeMetrics()
         # Telemetry plane (raftsql_tpu/obs/prof.py), DEFAULT ON — both
@@ -160,6 +164,8 @@ class ClusterHostPlane:
         #   traffic: [G] propose/commit/ack counters + EWMA rates ->
         #     /metrics group_traffic top-K hot-groups table.
         self.prof = TickPhaseProfiler.from_env(G)
+        if self.prof is not None:
+            self.prof.gauge_fn("boot.all_led_s", lambda: self._all_led_s)
         self.traffic = GroupTraffic(G)
         # Phase attribution: the tick that OWNS the durable/publish
         # work currently running (a serial host's deferred publish,
@@ -996,17 +1002,47 @@ class ClusterHostPlane:
         — every peer lives here — and "unknown" while leaderless.
         Host caches only (hints + hard-state mirror); never touches
         device arrays."""
+        hints = self._hints
+        lead = np.maximum(hints, 0)
+        groups = np.arange(self.cfg.num_groups)
+        terms = self._hard[lead, groups, 0].tolist()
+        commits = self._hard[lead, groups, 2].tolist()
         out = {}
-        for g in range(self.cfg.num_groups):
-            p = int(self._hints[g])
+        for g, p in enumerate(hints.tolist()):
             if p >= 0:
                 out[str(g)] = {"role": "leader", "leader": p + 1,
-                               "term": int(self._hard[p, g, 0]),
-                               "commit": int(self._hard[p, g, 2])}
+                               "term": terms[g], "commit": commits[g]}
             else:
                 out[str(g)] = {"role": "unknown", "leader": 0,
                                "term": 0, "commit": 0}
         return out
+
+    def shm_columns(self):
+        """commit_watermark, leader_of and lease_deadline_s of every
+        group at once, as [G] columns (runtime/shm.py refresh_columns):
+        the shm publisher's refresh without a Python call a group."""
+        hints = self._hints
+        commit = self._hard[0, :, 2].copy()      # peer 0 where unknown
+        for p in range(1, self.cfg.num_peers):
+            np.copyto(commit, self._hard[p, :, 2], where=hints == p)
+        return commit, hints, self.lease_deadlines_s(hints)
+
+    def lease_deadlines_s(self, hints=None) -> np.ndarray:
+        """lease_deadline_s of every group as one [G] float column (0.0
+        where no live lease), from the same arrays and bound."""
+        cfg = self.cfg
+        G = cfg.num_groups
+        lc = self._lease_col
+        if hints is None:
+            hints = self._hints
+        if cfg.lease_ticks <= 0 or lc is None:
+            return np.zeros(G)
+        until = lc[np.maximum(hints, 0), np.arange(G)].astype(np.int64)
+        remaining = until - (self._device_steps + cfg.max_clock_skew)
+        live = (hints >= 0) & (until > 0) & (remaining > 0)
+        ahead = np.minimum(remaining * self.step_interval_s,
+                           self._LEASE_HORIZON_S)
+        return np.where(live, time.monotonic() + ahead, 0.0)
 
     # Published-deadline horizon: see runtime/node.py — the shm
     # publisher refreshes every millisecond or two, so capping how far
@@ -1380,6 +1416,8 @@ class ClusterHostPlane:
         self._hints = pinfo[0, :, _C["leader_hint"]]
         self._lease_col = pinfo[:, :, _C["lease"]]
         self._device_steps += len(step_infos)
+        if not self._all_led_s and not (self._hints < 0).any():
+            self._all_led_s = _t.monotonic() - self.t_born
         if self._xfers:
             self._transfer_advance(pinfo)
         # Stage the 2a ranges (this pops the device-accepted proposals

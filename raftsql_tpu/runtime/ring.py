@@ -62,6 +62,16 @@ nothing else parses these):
                      refusal or ring-drain deadline shed; leader =
                      Retry-After in MILLISECONDS, the controller's
                      jittered drain-rate estimate)
+      status 5 PART (body = the next piece of a reply too large for one
+                     record; the reply's last piece comes as status 0
+                     under the same req_id, and the worker joins them)
+
+A reply that fits half the ring takes one record, as every reply did
+before; only a larger ST_OK one (a /healthz of 100,000 groups is ~9.6 MB)
+is cut into pieces of a quarter of the ring, so no document or result is
+too large for the ring and no ring is sized to the largest of them.  (A
+record longer than half the ring can meet a region end that leaves it no
+room even when the ring is empty: see `_complete`.)
 """
 from __future__ import annotations
 
@@ -89,6 +99,7 @@ _CPL = struct.Struct("<QBI")          # req_id, status, leader
 
 OP_PUT, OP_GET, OP_DOC, OP_MEMBER, OP_XFER, OP_RESHARD = 1, 2, 3, 4, 5, 6
 ST_OK, ST_ERR, ST_NOT_LEADER, ST_UNAVAILABLE, ST_OVERLOADED = 0, 1, 2, 3, 4
+ST_PART = 5
 
 DEFAULT_RING_BYTES = 4 << 20
 
@@ -330,9 +341,10 @@ class RingServer:
     """
 
     def __init__(self, rdb, dirname: str, workers: int,
-                 ring_bytes: int = DEFAULT_RING_BYTES,
+                 ring_bytes: Optional[int] = None,
                  timeout_s: float = 30.0):
         os.makedirs(dirname, exist_ok=True)
+        ring_bytes = ring_bytes or DEFAULT_RING_BYTES
         self.rdb = rdb
         self.dirname = dirname
         self.workers = workers
@@ -409,17 +421,27 @@ class RingServer:
         """Restamp the shm watermark/leader/lease columns from the
         engine's host caches every couple of milliseconds — the
         publisher heartbeat a worker's lease read requires to be
-        fresh (shm.py PUB_STALE_NS)."""
+        fresh (shm.py PUB_STALE_NS).  From the node's [G] columns in
+        bulk where it keeps them (`shm_columns`), else group by group.
+        Each refresh is one `stages.shm.refresh` sample."""
         node = self._prof_node
+        columns = getattr(node, "shm_columns", None)
         commit_of = getattr(node, "commit_watermark", lambda g: 0)
         leader_of = getattr(node, "leader_of", lambda g: -1)
         lease_of = getattr(node, "lease_deadline_s", lambda g: 0.0)
         while not self._stop.is_set():
+            t0 = time.monotonic()
             try:
-                self.shm.refresh(commit_of, leader_of, lease_of)
+                if columns is not None:
+                    self.shm.refresh_columns(*columns())
+                else:
+                    self.shm.refresh(commit_of, leader_of, lease_of)
             except Exception:                           # noqa: BLE001
                 log.exception("shm refresh failed; stopping")
                 return
+            prof = self._prof()
+            if prof is not None:
+                prof.stage("shm.refresh", time.monotonic() - t0)
             self._stop.wait(0.002)
 
     def start(self) -> None:
@@ -468,19 +490,46 @@ class RingServer:
     # -- completion path (any engine thread) ----------------------------
 
     def _complete(self, worker: int, req_id: int, status: int,
-                  leader: int, body: bytes) -> None:
+                  leader: int, body: bytes) -> int:
+        """Complete a request: one record where it fits half the ring,
+        as any reply, else (an ST_OK reply: a document, a large result)
+        ST_PART pieces of a quarter of the ring and the last piece under
+        `status`.  Half, because a record that wraps skips the bytes left
+        before the region's end: a longer one can find no room even in
+        an empty ring.  The records pushed; fewer than it took when the
+        ring stayed full past the timeout."""
+        ring = self._cpl[worker]
+        if 4 + _CPL.size + len(body) <= ring.cap // 2 or status != ST_OK:
+            return int(self._push_completion(worker, req_id, status,
+                                             leader, body))
+        piece = ring.cap // 4 - _CPL.size - 8
+        view = memoryview(body)
+        n = 0
+        for at in range(0, len(body) - piece, piece):
+            if not self._push_completion(worker, req_id, ST_PART, 0,
+                                         view[at:at + piece], False):
+                return n        # the worker's wait runs out instead
+            n += 1
+        return n + self._push_completion(worker, req_id, status, leader,
+                                         view[n * piece:])
+
+    def _push_completion(self, worker: int, req_id: int, status: int,
+                         leader: int, body, last: bool = True) -> bool:
+        """Push one completion record; False when the ring stayed full
+        past the timeout (or the server stops)."""
         rec = encode_completion(req_id, status, leader, body)
         deadline = time.monotonic() + self.timeout_s
         mu, ring = self._cpl_mu[worker], self._cpl[worker]
         while True:
             with mu:
                 if ring.push(rec):
-                    self.completed += 1
-                    return
+                    if last:
+                        self.completed += 1
+                    return True
             # Completion ring full: the worker is alive but behind —
             # wait it out (dropping an ack would hang a client).
             if time.monotonic() > deadline or self._stop.is_set():
-                return
+                return False
             time.sleep(0.0002)
 
     def _err_body(self, e: BaseException) -> bytes:
@@ -668,12 +717,19 @@ class RingServer:
                 self._complete(worker, req_id, ST_ERR, 0,
                                f"unknown document {name!r}".encode())
                 return
+            t0 = time.monotonic()
             try:
-                self._complete(worker, req_id, ST_OK, 0,
-                               render().encode("utf-8"))
+                body = render().encode("utf-8")
             except Exception as e:                      # noqa: BLE001
                 self._complete(worker, req_id, ST_ERR, 0,
                                self._err_body(e))
+                return
+            t1 = time.monotonic()
+            n = self._complete(worker, req_id, ST_OK, 0, body)
+            prof = self._prof()
+            if prof is not None:
+                prof.stage("doc.render", t1 - t0)
+                prof.count((("doc.records", n),))
 
         self._read_pool.submit(_run)
 
@@ -864,6 +920,9 @@ class RingClient:
         # slightly stale value only makes a session read wait less.
         self._wm: Dict[int, int] = {}
         self._req_group: Dict[int, int] = {}
+        # The ST_PART pieces of replies still arriving, by req_id
+        # (touched by the consumer thread alone).
+        self._parts: Dict[int, List[bytes]] = {}
         # Cross-process trace merge (--trace): this worker stamps each
         # ring round trip (submit -> completion, pid/worker-id tagged)
         # into a per-process segment file under the ring dir; the
@@ -904,6 +963,7 @@ class RingClient:
             try:
                 from raftsql_tpu.runtime.shm import ShmSnapshotReader
                 self._shm = ShmSnapshotReader(dirname)
+                self._shm.stages = self.stages
             except Exception:                           # noqa: BLE001
                 self._shm = None
         self._consumer = threading.Thread(
@@ -973,6 +1033,13 @@ class RingClient:
                 req_id, status, leader, body = decode_completion(view)
                 self._cpl.pop_commit()
                 worked = True
+                if status == ST_PART:
+                    self._parts.setdefault(req_id, []).append(body)
+                    continue
+                parts = self._parts.pop(req_id, None)
+                if parts is not None:
+                    parts.append(body)
+                    body = b"".join(parts)
                 fut = self._pending.pop(req_id, None)
                 g = self._req_group.pop(req_id, None)
                 if status == ST_OK and g is not None:
@@ -1185,9 +1252,15 @@ class RingClient:
             doc["worker_stages"] = self.stages.stages_doc()
         return doc
 
+    # A document the engine renders on a read-pool thread, behind the
+    # interpreter its tick thread holds: /healthz at 100,000 groups is
+    # ~9.6 MB made group by group, so the wait is the HTTP client's.
+    DOC_TIMEOUT_S = 30.0
+
     def render_metrics(self) -> str:
         return json.dumps(
-            self._inject_reads(json.loads(self._doc("metrics"))),
+            self._inject_reads(json.loads(
+                self._doc("metrics", self.DOC_TIMEOUT_S))),
             sort_keys=True) + "\n"
 
     def render_metrics_prom(self) -> str:
@@ -1196,10 +1269,11 @@ class RingClient:
         RaftDB.render_metrics_prom, no new ring op."""
         from raftsql_tpu.utils.metrics import prom_render
         return prom_render(
-            self._inject_reads(json.loads(self._doc("metrics"))))
+            self._inject_reads(json.loads(
+                self._doc("metrics", self.DOC_TIMEOUT_S))))
 
     def render_health(self) -> str:
-        return self._doc("health")
+        return self._doc("health", self.DOC_TIMEOUT_S)
 
     def render_members(self) -> str:
         return self._doc("members")

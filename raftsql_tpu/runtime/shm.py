@@ -36,7 +36,11 @@ Concurrency design
 One writer (the engine's apply thread + a refresh thread, serialized
 by a lock), many reader processes.  The header + per-group table are
 guarded by a SEQLOCK: the writer bumps `seq` to odd, writes, bumps to
-even; a reader snapshots seq, copies, re-checks (retry on odd/changed).
+even; a reader snapshots seq, copies the header and the ONE row it
+needs, re-checks (retry on odd/changed).  Neither side's work grows
+with G where it need not: the writer keeps the table as a numpy view of
+the mapping and writes a delta run's rows alone, or the three refreshed
+columns in bulk; a read unpacks one row.
 The delta log is APPEND-ONLY and never rewritten below `log_head`, so
 readers copy log bytes WITHOUT the seqlock — a torn table read retries
 in microseconds, while log consumption can never livelock behind a
@@ -75,6 +79,8 @@ import time
 from array import array
 from typing import Callable, Dict, List, Optional, Tuple
 
+import numpy as np
+
 # Header: magic, version, flags, num_groups, epoch, seq, log_head,
 # log_cap, pub_ns, keymap_epoch.  64 bytes with padding to keep the
 # group table aligned.  keymap_epoch (hdr[9]) is the elastic-keyspace
@@ -90,6 +96,10 @@ _HDR_SIZE = 64
 # leader (1-based, 0 unknown), pad.
 _ROW = struct.Struct("<QQQQIi")
 _ROW_SIZE = _ROW.size                    # 40 bytes
+# The same row as the publisher's numpy view of the table.
+_ROW_DTYPE = np.dtype([("applied", "<u8"), ("commit", "<u8"),
+                       ("base", "<u8"), ("lease_ns", "<u8"),
+                       ("leader", "<u4"), ("pad", "<i4")])
 # Log record header: length of payload, kind, group, index.
 _REC = struct.Struct("<IBIQ")
 KIND_DELTA = 1                           # payload = one SQL statement
@@ -116,9 +126,13 @@ class ShmSnapshotPublisher:
 
     publish_deltas runs on the apply thread (runtime/db.py _apply_run,
     before acks fire — a worker can then always reach an acked PUT's
-    watermark); refresh() runs on a short-interval thread owned by the
+    watermark); refresh_columns() (refresh() for a node without [G]
+    host arrays) runs on a short-interval thread owned by the
     RingServer and restamps commit/leader/lease columns + the
-    publisher heartbeat."""
+    publisher heartbeat.
+
+    `_rows` IS the mapped table (a structured numpy view): every store
+    into it happens inside the seqlock's critical section."""
 
     def __init__(self, ring_dir: str, num_groups: int,
                  size: Optional[int] = None):
@@ -152,8 +166,8 @@ class ShmSnapshotPublisher:
         self._full = False
         self.epoch = secrets.randbits(63) | 1    # never 0
         self.keymap_epoch = 0      # elastic-keyspace mapping version
-        self._rows = [[0, 0, 0, 0, 0] for _ in range(num_groups)]
-        #             applied, commit, base_index, lease_ns, leader
+        self._rows = np.frombuffer(self._mm, _ROW_DTYPE, count=num_groups,
+                                   offset=self._table_off)
         # Stream tee (replica/publisher.py): called under _lock with
         # ("deltas", per_g) / ("base", group, index, blob) /
         # ("keymap", epoch) the instant a record lands — and, unlike
@@ -169,7 +183,7 @@ class ShmSnapshotPublisher:
         # replay a delta stream whose prefix it is missing.
         self._pending: Optional[List[Dict[int, list]]] = []
         self._write_header(pub_ns=time.monotonic_ns())
-        self._write_table()
+        self._rows[...] = 0
 
     # -- writer internals (callers hold _lock) --------------------------
 
@@ -179,13 +193,6 @@ class ShmSnapshotPublisher:
             _MAGIC, _VERSION, flags, self.num_groups, self.epoch,
             self._seq, self._log_head, self._log_cap, pub_ns,
             self.keymap_epoch)
-
-    def _write_table(self) -> None:
-        off = self._table_off
-        for row in self._rows:
-            self._mm[off:off + _ROW_SIZE] = _ROW.pack(
-                row[0], row[1], row[2], row[3], row[4], 0)
-            off += _ROW_SIZE
 
     def _publish_locked(self, writes: Callable[[], None]) -> None:  # raftlint: seqlock
         """Seqlock write protocol: odd → mutate → even.  The log bytes
@@ -212,16 +219,20 @@ class ShmSnapshotPublisher:
 
     def _run_locked(self, per_g: Dict[int, list]) -> None:
         """Append one applied run's deltas (caller holds _lock, inside
-        the seqlock critical section)."""
+        the seqlock critical section); only the run's rows are
+        written."""
+        applied = self._rows["applied"]
         for group, items in per_g.items():
-            row = self._rows[group]
+            top = int(applied[group])
             for (sql, index) in items:
-                if index <= row[0]:
+                if index <= top:
                     continue                 # covered by base/duplicate
                 if not self._append_locked(KIND_DELTA, group, index,
                                            sql.encode("utf-8")):
+                    applied[group] = top
                     return
-                row[0] = index
+                top = index
+            applied[group] = top
 
     # -- engine-facing API ----------------------------------------------
 
@@ -252,12 +263,9 @@ class ShmSnapshotPublisher:
             def writes():
                 for g, (idx, blob) in bases.items():
                     if self._append_locked(KIND_BASE, g, idx, blob):
-                        row = self._rows[g]
-                        row[0] = max(row[0], idx)
-                        row[2] = idx
+                        self._set_base(g, idx)
                 for per_g in (self._pending or ()):
                     self._run_locked(per_g)
-                self._write_table()
             self._publish_locked(writes)
             self._pending = None
 
@@ -272,11 +280,15 @@ class ShmSnapshotPublisher:
 
             def writes():
                 if self._append_locked(KIND_BASE, group, index, blob):
-                    row = self._rows[group]
-                    row[0] = max(row[0], index)
-                    row[2] = index
-                    self._write_table()
+                    self._set_base(group, index)
             self._publish_locked(writes)
+
+    def _set_base(self, group: int, index: int) -> None:
+        """A base image of `group` at `index` landed in the log (caller
+        inside the critical section)."""
+        row = self._rows[group]
+        row["applied"] = max(int(row["applied"]), index)
+        row["base"] = index
 
     def publish_deltas(self, per_g: Dict[int, List[Tuple[str, int]]]
                        ) -> None:
@@ -290,27 +302,56 @@ class ShmSnapshotPublisher:
             self._tee_locked("deltas", per_g)
             if self._full:
                 return
-            def writes():
-                self._run_locked(per_g)
-                self._write_table()
-            self._publish_locked(writes)
+            self._publish_locked(lambda: self._run_locked(per_g))
 
     def refresh(self, commit_of, leader_of, lease_deadline_s) -> None:
         """Restamp the watermark/leader/lease columns + heartbeat from
-        the engine's host caches (RingServer refresh thread).  Lease
-        deadlines convert monotonic seconds → ns; 0.0 stays 0 (no
-        lease)."""
+        per-group callables: the form for a node without [G] host arrays
+        (refresh_columns is the same in bulk).  Lease deadlines convert
+        monotonic seconds → ns; 0.0 stays 0 (no lease); a group whose
+        call raises keeps what it had and gets no lease."""
         with self._lock:
+            rows = self._rows
+            commit = rows["commit"].tolist()
+            leader = rows["leader"].tolist()
+            lease = rows["lease_ns"].tolist()
             for g in range(self.num_groups):
-                row = self._rows[g]
                 try:
-                    row[1] = max(row[1], int(commit_of(g)))
-                    row[4] = int(leader_of(g)) + 1
+                    commit[g] = max(commit[g], int(commit_of(g)))
+                    leader[g] = int(leader_of(g)) + 1
                     d = lease_deadline_s(g)
-                    row[3] = int(d * 1e9) if d > 0 else 0
+                    lease[g] = int(d * 1e9) if d > 0 else 0
                 except Exception:            # noqa: BLE001
-                    row[3] = 0               # fail closed, keep going
-            self._publish_locked(self._write_table)
+                    lease[g] = 0             # fail closed, keep going
+
+            def writes():
+                rows["commit"] = commit
+                rows["leader"] = leader
+                rows["lease_ns"] = lease
+            self._publish_locked(writes)
+
+    def refresh_columns(self, commit, leader, lease_deadline_s) -> None:
+        """refresh() in bulk, from [G] columns the node keeps: the
+        commit watermark, the 0-based leader (-1 unknown) and the lease
+        deadline in monotonic seconds, where anything not above 0 (NaN
+        for a lease that could not be read) is no lease.  The commit
+        column never decreases."""
+        commit = np.asarray(commit).astype(np.uint64)
+        leader = (np.asarray(leader) + 1).astype(np.uint32)
+        d = np.asarray(lease_deadline_s, np.float64)
+        live = d > 0
+        lease = np.zeros(len(d), np.uint64)
+        if live.any():
+            lease[live] = (d[live] * 1e9).astype(np.uint64)
+        with self._lock:
+            rows = self._rows
+
+            def writes():
+                np.maximum(rows["commit"], commit, out=commit)
+                rows["commit"] = commit
+                rows["leader"] = leader
+                rows["lease_ns"] = lease
+            self._publish_locked(writes)
 
     def set_keymap_epoch(self, epoch: int) -> None:
         """Publish a new elastic-keyspace mapping version (reshard
@@ -386,11 +427,14 @@ class ShmSnapshotPublisher:
         (applied, commit, base_index, lease_deadline_ns, leader) — the
         stream server's TABLE heartbeat source."""
         with self._lock:
+            cols = [self._rows[k].tolist() for k in
+                    ("applied", "commit", "base", "lease_ns", "leader")]
             return (self.epoch, self.keymap_epoch, self._full,
-                    [tuple(r) for r in self._rows])
+                    list(zip(*cols)))
 
     def close(self) -> None:
         with self._lock:
+            self._rows = None        # the view pins the mapping open
             try:
                 self._mm.close()
             except (BufferError, ValueError):
@@ -466,6 +510,9 @@ class ShmSnapshotReader:
         self._table_off = _HDR_SIZE
         self._log_off = _HDR_SIZE + self.num_groups * _ROW_SIZE
         self._replicas: Dict[int, _GroupReplica] = {}
+        # The worker's StageSet (obs/prof.py), set by its RingClient:
+        # try_read times its snapshot as `get.shm_snapshot`.
+        self.stages = None
         # Where each group's records lie in the log (offsets from its
         # start, in log order), for the bytes walked so far.
         self._records: Dict[int, array] = {}
@@ -479,13 +526,16 @@ class ShmSnapshotReader:
         except (ValueError, struct.error):
             return None
 
-    def _snapshot_table(self):  # raftlint: seqlock fail-closed
-        """Seqlock read of header + group table: (header, rows) or
-        None after bounded retries / on any fail-closed condition.
-        The epoch check pins the attachment: a restarted engine's
-        fresh region (new epoch) permanently kills this reader."""
+    def _snapshot_row(self, group: int):  # raftlint: seqlock fail-closed
+        """Seqlock read of the header and `group`'s row: (header, row),
+        row None for a group outside the mapping, or None after bounded
+        retries / on any fail-closed condition.  The epoch check pins
+        the attachment: a restarted engine's fresh region (new epoch)
+        permanently kills this reader."""
         if self._dead:
             return None
+        inside = 0 <= group < self.num_groups
+        off = self._table_off + group * _ROW_SIZE
         for _ in range(64):
             h1 = self._read_header_raw()
             if h1 is None:
@@ -497,14 +547,12 @@ class ShmSnapshotReader:
             if h1[5] & 1:                    # writer mid-update
                 time.sleep(0)
                 continue
-            raw = bytes(self._mm[self._table_off:self._log_off])
+            row = _ROW.unpack_from(self._mm, off) if inside else None
             h2 = self._read_header_raw()
             if h2 is None or h2[5] != h1[5] or h2[4] != self.epoch:
                 time.sleep(0)
                 continue                     # torn: retry
-            rows = [_ROW.unpack_from(raw, i * _ROW_SIZE)
-                    for i in range(self.num_groups)]
-            return h1, rows
+            return h1, row
         return None
 
     def _index_log(self, log_head: int) -> None:
@@ -570,13 +618,16 @@ class ShmSnapshotReader:
         if not is_select(query):
             return miss("not_select")   # engine's 400 class — and NEVER
             #                      let a write mutate the worker's replica
-        snap = self._snapshot_table()
+        t0 = time.monotonic()
+        snap = self._snapshot_row(group)
+        if self.stages is not None:
+            self.stages.stage("get.shm_snapshot", time.monotonic() - t0)
         if snap is None:
             # A reader that is out for good keeps giving the reason
             # that put it out (a restarted engine's epoch counts as
             # no_snapshot).
             return miss(self._dead_why if self._dead else "no_snapshot")
-        hdr, rows = snap
+        hdr, row = snap
         if hdr[2] & _FLAG_LOG_FULL:
             self._dead = True                # overflow: permanently out
             self._dead_why = "log_full"
@@ -587,9 +638,9 @@ class ShmSnapshotReader:
             # the engine routes by the CURRENT mapping — until the
             # worker refreshes and calls note_keymap_epoch.
             return miss("keymap_epoch")
-        if not 0 <= group < self.num_groups:
+        if row is None:
             return miss("group_range")
-        applied, commit, _base, lease_ns, _leader, _pad = rows[group]
+        applied, commit, _base, lease_ns, _leader, _pad = row
         if mode == "local":
             target = applied
         elif mode == "session":
@@ -644,10 +695,10 @@ class ShmSnapshotReader:
     def leader_of(self, group: int) -> int:  # raftlint: fail-closed
         """Published 1-based leader hint (0 unknown), for worker-side
         421 redirects without a ring trip; -0 fail-open to 0."""
-        snap = self._snapshot_table()
-        if snap is None or not 0 <= group < self.num_groups:
+        snap = self._snapshot_row(group)
+        if snap is None or snap[1] is None:
             return 0
-        return int(snap[1][group][4])
+        return int(snap[1][4])
 
     def keymap_epoch(self) -> int:
         """The publisher's CURRENT elastic-keyspace mapping version

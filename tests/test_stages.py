@@ -164,8 +164,17 @@ def test_metrics_document_holds_the_new_keys(served):
         <= set(doc["phase_profile"])
     assert set(doc["worker_stages"]["put"]) == {"edge_in", "ring_rtt",
                                                 "edge_out"}
-    assert set(doc["worker_stages"]["get"]) == {"ring_rtt"}
+    assert set(doc["worker_stages"]["get"]) == {"ring_rtt", "shm_snapshot"}
     assert "shm_fallback_reasons" in doc["reads"]
+    # The serving plane's own: the shm refresh, the documents the
+    # engine made and the ring records their replies took, and the
+    # engine's boot on its own clock.
+    assert set(doc["stages"]["shm"]) == {"refresh"}
+    assert set(doc["stages"]["doc"]) == {"render"}
+    assert doc["stages"]["doc"]["render"]["n"] >= 1
+    assert doc["doc"]["records"] >= doc["stages"]["doc"]["render"]["n"]
+    assert doc["stages"]["shm"]["refresh"]["n"] > 0
+    assert 0 < doc["boot"]["replay_s"] <= doc["boot"]["all_led_s"]
     for pair in doc["stages"]["put"].values():
         assert set(pair) == {"total_ms", "n", "max_ms"}
     assert doc["phase_profile"]["sample"] == 1
